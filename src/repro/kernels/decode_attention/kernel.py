@@ -9,7 +9,9 @@ so the gather happens in the BlockSpec index_map (pipelined HBM->VMEM DMAs)
 instead of a materialized [B, L, H, D] gather in HBM.
 
 Layout: q [B, Hkv, G, D] (G = Hq/Hkv query heads per KV head); k/v pools
-[P, page_size, Hkv, D]; page_table [B, max_pages]; seq_lens [B]. Grid
+[P, page_size, Hkv, D], viewed (a free reshape) as [P, page_size, Hkv * D]
+so one KV head of one page is a [page_size, D] block the TPU tiling accepts
+(D a multiple of 128); page_table [B, max_pages]; seq_lens [B]. Grid
 (B, Hkv, max_pages): the page loop is the innermost grid dim, carrying fp32
 online-softmax accumulators (acc, m, l) in VMEM scratch. Pages at or past
 seq_len are skipped with ``pl.when`` (their table entries point at the null
@@ -49,9 +51,9 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
     # sl == 0, whose rows stay zero after the final normalization)
     @pl.when(j * page_size < sl)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale           # [G, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale            # [G, D]
+        k = k_ref[...].astype(jnp.float32)                    # [page, D]
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [G, page]
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page_size
         s = jnp.where(cols < sl, s, NEG_INF)
@@ -65,8 +67,8 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] /
+                      jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _paged_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
@@ -85,10 +87,9 @@ def _paged_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
     # visible cache at the chunk's last valid position (total - 1)
     @pl.when(j * page_size < total)
     def _compute():
-        c = q_ref.shape[0]
-        q = q_ref[:, 0].astype(jnp.float32).reshape(c * g, -1) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale            # [C*G, D]
+        k = k_ref[...].astype(jnp.float32)                    # [page, D]
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [C*G, page]
         rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page_size
@@ -105,9 +106,18 @@ def _paged_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        c = q_ref.shape[0]
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[:, 0] = out.reshape(c, g, -1).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] /
+                      jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _pool_view(k_pages, v_pages, d, interpret):
+    """[P, page, Hkv, D] pools as [P, page, Hkv * D] (free): a [page, D]
+    block per (page, KV head) is tile-aligned when D is a multiple of 128
+    (every servable config); interpret mode takes any D."""
+    assert interpret or d % 128 == 0, f"head_dim {d} is not a multiple of 128"
+    p, page_size, hkv, _ = k_pages.shape
+    return (k_pages.reshape(p, page_size, hkv * d),
+            v_pages.reshape(p, page_size, hkv * d))
 
 
 def paged_prefill_attention_fwd(q, k_pages, v_pages, page_row, start,
@@ -127,38 +137,36 @@ def paged_prefill_attention_fwd(q, k_pages, v_pages, page_row, start,
     assert hq == g * hkv, (hq, hkv)
     max_pages = page_row.shape[0]
     scale = 1.0 / (d ** 0.5)
+    k_view, v_view = _pool_view(k_pages, v_pages, d, interpret)
 
-    qg = q.reshape(c, hkv, g, d)
+    # [Hkv, C*G, D]: row r of head h is query (r // G) of group member r % G
+    qg = q.reshape(c, hkv, g, d).transpose(1, 0, 2, 3).reshape(hkv, c * g, d)
     pt = page_row.astype(jnp.int32)
     meta = jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(total_len, jnp.int32)])
 
     kern = functools.partial(_paged_prefill_kernel, page_size=page_size,
                              g=g, scale=scale)
+    rows = pl.BlockSpec((None, c * g, d), lambda h, j, pt, meta: (h, 0, 0))
+    page = pl.BlockSpec((None, page_size, d),
+                        lambda h, j, pt, meta: (pt[j], 0, h))
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(hkv, max_pages),
-            in_specs=[
-                pl.BlockSpec((c, 1, g, d), lambda h, j, pt, meta: (0, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, d),
-                             lambda h, j, pt, meta: (pt[j], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, d),
-                             lambda h, j, pt, meta: (pt[j], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((c, 1, g, d),
-                                   lambda h, j, pt, meta: (0, h, 0, 0)),
+            in_specs=[rows, page, page],
+            out_specs=rows,
             scratch_shapes=[
                 pltpu.VMEM((c * g, d), jnp.float32),
                 pltpu.VMEM((c * g, 1), jnp.float32),
                 pltpu.VMEM((c * g, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((c, hkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hkv, c * g, d), q.dtype),
         interpret=interpret,
-    )(pt, meta, qg, k_pages, v_pages)
-    return out.reshape(c, hq, d)
+    )(pt, meta, qg, k_view, v_view)
+    return out.reshape(hkv, c, g, d).transpose(1, 0, 2, 3).reshape(c, hq, d)
 
 
 def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
@@ -171,6 +179,7 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
     assert hq == g * hkv, (hq, hkv)
     max_pages = page_table.shape[1]
     scale = 1.0 / (d ** 0.5)
+    k_view, v_view = _pool_view(k_pages, v_pages, d, interpret)
 
     qg = q.reshape(b, hkv, g, d)
     pt = page_table.astype(jnp.int32)
@@ -178,20 +187,17 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
 
     kern = functools.partial(_paged_decode_kernel, page_size=page_size,
                              scale=scale)
+    heads = pl.BlockSpec((None, None, g, d),
+                         lambda bi, h, j, pt, sl: (bi, h, 0, 0))
+    page = pl.BlockSpec((None, page_size, d),
+                        lambda bi, h, j, pt, sl: (pt[bi, j], 0, h))
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, max_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, g, d), lambda bi, h, j, pt, sl: (bi, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, d),
-                             lambda bi, h, j, pt, sl: (pt[bi, j], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, d),
-                             lambda bi, h, j, pt, sl: (pt[bi, j], 0, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, g, d),
-                                   lambda bi, h, j, pt, sl: (bi, h, 0, 0)),
+            in_specs=[heads, page, page],
+            out_specs=heads,
             scratch_shapes=[
                 pltpu.VMEM((g, d), jnp.float32),
                 pltpu.VMEM((g, 1), jnp.float32),
@@ -200,5 +206,5 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
-    )(pt, sl, qg, k_pages, v_pages)
+    )(pt, sl, qg, k_view, v_view)
     return out.reshape(b, hq, d)

@@ -118,7 +118,11 @@ def decode_residual_norm(y, x, scale, bias=None, *, eps=1e-5,
 def _gated_kernel(y_ref, z_ref, scale_ref, o_ref, *, eps):
     y = y_ref[...]
     z = z_ref[...]
-    yf = (y * (z * jax.nn.sigmoid(z))).astype(jnp.float32)
+    # jax.nn.sigmoid spelled out with a constant of the input dtype: Mosaic
+    # lowers a bf16 logistic with an f32 one (a verifier error). XLA expands
+    # its logistic to these same ops, each rounded to the input dtype.
+    one = jnp.ones((), z.dtype)
+    yf = (y * (z * (one / (one + jnp.exp(-z))))).astype(jnp.float32)
     var = jnp.mean(jnp.square(yf), axis=-1, keepdims=True)
     o_ref[...] = (yf * jax.lax.rsqrt(var + eps)
                   * scale_ref[...].astype(jnp.float32)).astype(o_ref.dtype)

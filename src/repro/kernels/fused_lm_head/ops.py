@@ -18,8 +18,8 @@ construction, not tolerance:
 * integer predicates (top-k counts, argmax/first-hit index compares) are
   order-exact;
 * every float mass is summed as the canonical RED_TILE partials folded
-  left-to-right (``fused_sampling.ref``), and the draw's within-tile cumsum
-  runs on an ``[S, RED_TILE]`` block in both implementations.
+  left-to-right (``fused_sampling.ref``), and the draw's within-tile prefix
+  is ``ref.tile_cumsum`` in every implementation.
 
 Tensor-parallel (``axis_name`` set): each shard slices its own contiguous
 vocab columns from the REPLICATED head weight (the sharding layer keeps
@@ -250,7 +250,7 @@ def _head_tokens_jnp(x, w, rs, temps, top_k, top_p, *, sampled, filtered,
             g = t * t128 + j + local_base                # global 128-tile
             acc = lax.dynamic_index_in_dim(accs, g, axis=1, keepdims=False)
             tile = lax.dynamic_index_in_dim(u3, j, axis=1, keepdims=False)
-            cs = acc[:, None] + jnp.cumsum(tile, axis=-1)
+            cs = acc[:, None] + ref.tile_cumsum(tile)
             hit = cs > target[:, None]
             idx = (jnp.argmax(hit, axis=-1).astype(jnp.int32)
                    + g.astype(jnp.int32) * RED_TILE)

@@ -25,9 +25,11 @@ Given final filtered scaled logits ``lg_f`` [S, V] and per-row uniforms
 4. ``target = rs * Z``.
 5. The token is the FIRST index ``j`` (global index order) whose inclusive
    prefix mass exceeds ``target``, where the prefix at lane ``l`` of tile
-   ``t`` is ``acc_t + cumsum(u_tile)[l]`` — ``acc_t`` the sequential fold of
-   the *partials* of tiles ``0..t-1`` (the same adds as step 3) and the
-   cumsum evaluated on an ``[S, RED_TILE]`` block in every implementation.
+   ``t`` is ``acc_t + tile_cumsum(u_tile)[l]`` — ``acc_t`` the sequential
+   fold of the *partials* of tiles ``0..t-1`` (the same adds as step 3) and
+   :func:`tile_cumsum` the within-tile prefix in its fixed log-step
+   association (``jnp.cumsum`` leaves the association to the backend, and
+   has no lowering inside a TPU kernel).
 6. If no lane ever exceeds ``target`` the token is 0. That covers both the
    degenerate all-``-inf`` row (``Z == 0``, ``u == 0`` everywhere) and the
    measure-zero rounding edge where ``rs * Z`` lands at or above the final
@@ -87,6 +89,21 @@ def pad_tiles(u: jax.Array) -> jax.Array:
     return u.reshape(s, (v + pad) // RED_TILE, RED_TILE)
 
 
+def tile_cumsum(u: jax.Array, roll=jnp.roll) -> jax.Array:
+    """Inclusive prefix sum along the last axis in the canonical log-step
+    (Hillis-Steele) association: step ``s = 1, 2, 4, ...`` adds to every
+    lane the running value ``s`` lanes to its left (zero where there is
+    none). ``roll`` is ``jnp.roll`` or, inside a kernel, ``pltpu.roll``;
+    both move data exactly, so every implementation performs the same
+    float adds."""
+    lane = lax.broadcasted_iota(jnp.int32, u.shape, u.ndim - 1)
+    s = 1
+    while s < u.shape[-1]:
+        u = u + jnp.where(lane >= s, roll(u, s, u.ndim - 1), 0.0)
+        s *= 2
+    return u
+
+
 def draw_tokens(lg_f: jax.Array, rs: jax.Array) -> jax.Array:
     """Canonical inverse-CDF draw: filtered scaled logits ``lg_f`` [S, V] +
     uniforms ``rs`` [S] -> int32 tokens [S]. See the module docstring for
@@ -101,7 +118,7 @@ def draw_tokens(lg_f: jax.Array, rs: jax.Array) -> jax.Array:
     def body(i, carry):
         acc, tok = carry
         tile = lax.dynamic_index_in_dim(u, i, axis=1, keepdims=False)
-        cs = acc[:, None] + jnp.cumsum(tile, axis=-1)    # [S, RED_TILE]
+        cs = acc[:, None] + tile_cumsum(tile)            # [S, RED_TILE]
         hit = cs > target[:, None]
         idx = (jnp.argmax(hit, axis=-1).astype(jnp.int32)
                + i.astype(jnp.int32) * RED_TILE)

@@ -133,8 +133,6 @@ def lower_cell(run: RunConfig, mesh, rules, donate: bool = True):
 def analyze_cell(run: RunConfig, compiled, mesh, compile_s: float) -> dict:
     n_dev = mesh.devices.size
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     ma = compiled.memory_analysis()
     text = compiled.as_text()
     # full call-graph cost engine: multiplies while-loop bodies by trip count

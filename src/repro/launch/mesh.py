@@ -8,16 +8,13 @@ Defined as functions so importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def _make(shape, axes):
-    # jax >= 0.5 takes axis_types (and needs Auto for pjit-style tracing);
-    # older releases have neither the kwarg nor jax.sharding.AxisType.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    # Auto axes: shardings propagate pjit-style from the constraints
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -38,6 +38,8 @@ from ..configs import get_config, smoke_config
 from ..models import build_model
 from ..serving.sampling import (SamplingParams, fused_sampling_enabled,
                                 sample_tokens)
+from ..train.steps import serve_params
+from .compile_cache import enable_compile_cache
 
 
 def _fused(args) -> bool:
@@ -151,6 +153,9 @@ def _run_continuous(model, params, args, arch) -> dict:
     t0 = time.perf_counter()
     results = engine.run(reqs)
     wall = time.perf_counter() - t0
+    errors = {i: r["error"] for i, r in results.items() if "error" in r}
+    if errors:
+        raise RuntimeError(f"requests failed: {errors}")
     out = np.stack([np.asarray(results[i]["tokens"]) for i in range(b)])
     total_tokens = out.size
     print(f"[serve/continuous] {arch.name}: {b} requests x {glen} tokens in "
@@ -160,7 +165,8 @@ def _run_continuous(model, params, args, arch) -> dict:
           f"{engine.cached_prefill_tokens} from prefix cache)")
     print(f"[serve/continuous] sample generations (first 8 ids/row): "
           f"{out[:2, :8].tolist()}")
-    stats = {"tokens": out, "wall": wall, "steps": engine.steps,
+    stats = {"tokens": out, "wall": wall, "engine": engine,
+             "steps": engine.steps,
              "prefills": engine.prefills,
              "decode_dispatches": engine.decode_dispatches,
              "decode_exits": dict(engine.decode_exits),
@@ -296,9 +302,9 @@ def main(argv=None) -> dict:
                  "rerun without --prefix-cache")
     if args.prefix_cache is None:
         args.prefix_cache = True
+    enable_compile_cache()
     model = build_model(arch)
-    params = model.init(jax.random.key(args.seed))
-    params = jax.tree.map(lambda p: p.astype(jnp.dtype(arch.dtype)), params)
+    params = serve_params(model, arch, args.seed)
 
     if args.engine == "continuous":
         return _run_continuous(model, params, args, arch)

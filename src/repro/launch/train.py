@@ -18,6 +18,7 @@ from ..data import DataConfig, SyntheticPipeline
 from ..checkpoint import CheckpointManager
 from ..train.loop import LoopConfig, train_loop
 from ..train.steps import build_train_step
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> dict:
@@ -36,6 +37,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--no-master-weights", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     shape = ShapeConfig("cli", seq_len=args.seq, global_batch=args.batch,

@@ -58,12 +58,9 @@ def pipeline_apply(stage_fn: Callable, stage_params: PyTree, x: jax.Array,
         return (buf_next, out), None
 
     # mark the carries as varying over the pipe axis (they depend on
-    # axis_index inside the loop); pvary only exists once shard_map has
-    # varying-manual-axes semantics (jax >= 0.6) — older versions don't
-    # track replication, so identity is correct there
-    pvary = getattr(jax.lax, "pvary", lambda x, _: x)
-    buf0 = pvary(jnp.zeros_like(micro[0]), axis_name)
-    out0 = pvary(jnp.zeros_like(micro), axis_name)
+    # axis_index inside the loop)
+    buf0 = jax.lax.pcast(jnp.zeros_like(micro[0]), axis_name, to="varying")
+    out0 = jax.lax.pcast(jnp.zeros_like(micro), axis_name, to="varying")
     (_, out), _ = jax.lax.scan(tick, (buf0, out0), jnp.arange(ticks))
     # only the last stage ever wrote into `out` (zeros elsewhere): a psum
     # broadcasts the finished micro-batches to every stage, with a

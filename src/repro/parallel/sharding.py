@@ -31,12 +31,8 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-if hasattr(jax, "shard_map"):            # jax >= 0.6
-    shard_map = jax.shard_map
-else:                                    # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 Rules = Dict[str, Optional[object]]
 
@@ -86,12 +82,9 @@ def activate(mesh: Mesh, rules: Rules):
     def _ctx():
         prev = getattr(_state, "ctx", None)
         _state.ctx = (mesh, rules)
-        # jax >= 0.6 also wants the mesh ambient for sharding-in-types;
-        # constrain() itself builds explicit NamedShardings, so older
-        # versions need no global state
-        set_mesh = getattr(jax, "set_mesh", contextlib.nullcontext)
+        # the mesh is also ambient for sharding-in-types
         try:
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 yield
         finally:
             _state.ctx = prev
@@ -411,18 +404,13 @@ def paged_pool_pspecs(pools) -> object:
 
 
 def shard_map_tp(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions.
+    """``shard_map`` with varying-manual-axes checking off.
 
     The TP serving steps return psum-replicated values (token ids) under a
-    ``P()``/``P(None)`` out_spec; the replication checker cannot always prove
-    that through the sampler's PRNG ops, and its keyword changed name
-    (check_rep -> check_vma) across the versions this repo supports."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    ``P()``/``P(None)`` out_spec; the checker cannot always prove that
+    through the sampler's PRNG ops."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 def _cache_leaf_spec(path: Tuple[str, ...], leaf) -> P:

@@ -96,10 +96,16 @@ def build_train_step(run: RunConfig) -> StepBundle:
 
 # ----------------------------------------------------------------------- serve ----
 
-def _serve_params(model, arch: ArchConfig, seed: int) -> PyTree:
-    """Serving uses inference-dtype (bf16) checkpoints."""
-    params = model.init(jax.random.key(seed))
-    return jax.tree.map(lambda p: p.astype(jnp.dtype(arch.dtype)), params)
+def serve_params(model, arch: ArchConfig, seed: int) -> PyTree:
+    """Serving uses inference-dtype (bf16) checkpoints, built inside one
+    jit so the float32 init values never sit in memory beside the cast copy
+    (for a 3B model that pair alone would overflow a 16 GB chip)."""
+    dt = jnp.dtype(arch.dtype)
+
+    def init(key):
+        return jax.tree.map(lambda p: p.astype(dt), model.init(key))
+
+    return jax.jit(init)(jax.random.key(seed))
 
 
 def build_prefill_step(run: RunConfig) -> StepBundle:
@@ -110,7 +116,7 @@ def build_prefill_step(run: RunConfig) -> StepBundle:
         return model.prefill(params, caches, batch)
 
     def init(seed: int = 0):
-        params = _serve_params(model, arch, seed)
+        params = serve_params(model, arch, seed)
         caches = model.init_caches(None, shape.global_batch, shape.seq_len)
         return params, caches
 
@@ -131,7 +137,7 @@ def build_serve_step(run: RunConfig) -> StepBundle:
         return model.decode_step(params, caches, batch)
 
     def init(seed: int = 0):
-        params = _serve_params(model, arch, seed)
+        params = serve_params(model, arch, seed)
         caches = model.init_caches(None, shape.global_batch, shape.seq_len)
         return params, caches
 

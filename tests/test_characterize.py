@@ -13,10 +13,7 @@ def _cost(fn, *args):
 
 
 def _xla_flops(compiled):
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # jax 0.4.x returns [dict]
-        ca = ca[0]
-    return ca["flops"]
+    return compiled.cost_analysis()["flops"]
 
 
 def test_dot_flops_exact():
