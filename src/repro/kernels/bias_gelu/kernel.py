@@ -38,10 +38,10 @@ def bias_gelu(x, bias=None, *, interpret: bool = False):
             _bias_gelu_kernel, grid=(r // tile,),
             in_specs=[row, vec], out_specs=row,
             out_shape=jax.ShapeDtypeStruct((r, f), x.dtype),
-            interpret=interpret)(x, bias)
+            name="bias_gelu", interpret=interpret)(x, bias)
     return pl.pallas_call(
         # jaxlint: allow[pallas-grid-floordiv] r % tile asserted above
         lambda xr, yr: _bias_gelu_kernel(xr, None, yr), grid=(r // tile,),
         in_specs=[row], out_specs=row,
         out_shape=jax.ShapeDtypeStruct((r, f), x.dtype),
-        interpret=interpret)(x)
+        name="gelu", interpret=interpret)(x)

@@ -164,6 +164,7 @@ def paged_prefill_attention_fwd(q, k_pages, v_pages, page_row, start,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((hkv, c * g, d), q.dtype),
+        name="paged_prefill_attention",
         interpret=interpret,
     )(pt, meta, qg, k_view, v_view)
     return out.reshape(hkv, c, g, d).transpose(1, 0, 2, 3).reshape(c, hq, d)
@@ -205,6 +206,7 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        name="paged_decode_attention",
         interpret=interpret,
     )(pt, sl, qg, k_view, v_view)
     return out.reshape(b, hq, d)

@@ -93,6 +93,7 @@ def flash_attention_fwd(q, k, v, kv_len, *, causal, q_offset=0, window=0,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda n, i: (n, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
+        name="flash_attention",
         interpret=interpret,
     )(q4, k4, v4, kvl)
     return out.reshape(b, hq, sq, d)

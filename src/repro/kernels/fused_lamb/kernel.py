@@ -70,6 +70,7 @@ def lamb_stage1(w, g, m, v, scalars, *, beta1, beta2, eps, weight_decay,
             jax.ShapeDtypeStruct((r, tiles), jnp.float32),
             jax.ShapeDtypeStruct((r, tiles), jnp.float32),
         ],
+        name="lamb_moments",
         interpret=interpret,
     )(w, g, m, v, scalars)
     return m_new, v_new, u, wsq, usq
@@ -87,5 +88,6 @@ def lamb_stage2(w, u, rr, *, lr, interpret: bool = False):
         in_specs=[row_tile, row_tile, rspec],
         out_specs=row_tile,
         out_shape=jax.ShapeDtypeStruct((r, f), jnp.float32),
+        name="lamb_update",
         interpret=interpret,
     )(w, u, rr)

@@ -59,6 +59,7 @@ def fused_residual_layernorm(x, residual, scale, bias=None, *, eps=1e-5,
         in_specs=in_specs,
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
+        name="residual_layernorm",
         interpret=interpret,
     )(*args)
 
@@ -111,6 +112,7 @@ def decode_residual_norm(y, x, scale, bias=None, *, eps=1e-5,
         out_specs=[row, row],
         out_shape=[jax.ShapeDtypeStruct((r, d), x.dtype),
                    jax.ShapeDtypeStruct((r, d), x.dtype)],
+        name="decode_residual_norm",
         interpret=interpret,
     )(*args)
 
@@ -143,5 +145,6 @@ def gated_rmsnorm(y, z, scale, *, eps=1e-5, interpret: bool = False):
         in_specs=[row, row, vec],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((r, d), y.dtype),
+        name="gated_rms_norm",
         interpret=interpret,
     )(y, z, scale)

@@ -137,6 +137,7 @@ def head_tokens(x: jax.Array, w: jax.Array, rs: jax.Array, temps: jax.Array,
         scratch_shapes=[pltpu.VMEM((s, n, LANES), jnp.float32),
                         pltpu.VMEM((n_fold, LANES), jnp.float32),
                         pltpu.VMEM((n_fold, LANES), jnp.float32)],
+        name="fused_lm_head",
         interpret=interpret,
     )(x, w, rs.astype(jnp.float32), temps.astype(jnp.float32),
       top_k.astype(jnp.int32), top_p.astype(jnp.float32))

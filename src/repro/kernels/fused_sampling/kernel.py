@@ -165,6 +165,7 @@ def filter_logits(lg: jax.Array, top_k: jax.Array, top_p: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((s, n, LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((fold_scratch_rows(n), LANES),
                                    jnp.float32)],
+        name="fused_sampling_filter",
         interpret=interpret,
     )(top_k.astype(jnp.int32), top_p.astype(jnp.float32),
       lg.reshape(s, n, LANES))

@@ -45,5 +45,6 @@ def scale_mask_softmax(s, *, scale: float, causal: bool, q_offset: int = 0,
         in_specs=[spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, sq, sk), s.dtype),
+        name="scale_mask_softmax",
         interpret=interpret,
     )(s)

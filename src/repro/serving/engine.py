@@ -121,6 +121,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..analysis.sanitize import (check_engine, check_finite_probe,
                                  sanitize_enabled)
+from ..core.spans import span
 from ..kernels.fused_lm_head import ops as head_ops
 from ..kernels.fused_lm_head import ref as head_ref
 from ..models import transformer as tf
@@ -136,6 +137,16 @@ from .scheduler import Request, Scheduler, SequenceState
 SERVABLE_FAMILIES = ("dense", "moe", "vlm", "ssm", "hybrid")
 
 TP_AXIS = "model"
+
+
+def _named(impl, name: str):
+    """``impl`` under ``name``: jit calls the program it compiles
+    ``jit_<name>``, which is the module's name in HLO and its events' name on
+    a profiler trace's ``XLA Modules`` line (a ``functools.partial`` of a
+    method would compile as ``jit__unknown``)."""
+    impl = functools.partial(impl)
+    impl.__name__ = name
+    return impl
 
 
 def _split_fused_qkv(params, arch):
@@ -420,9 +431,9 @@ class ContinuousEngine:
         fused = self.fused_sampling and filtered
         key = ("decode", sampled, filtered, fused, self.fused_decode)
         if key not in self._jit_cache:
-            impl = functools.partial(self._decode_impl, sampled=sampled,
-                                     filtered=filtered, fused=fused,
-                                     fd=self.fused_decode)
+            impl = _named(functools.partial(
+                self._decode_impl, sampled=sampled, filtered=filtered,
+                fused=fused, fd=self.fused_decode), "serve_decode_step")
             in_specs = (self._param_specs, self._pool_specs, P(None, None),
                         P(None), P(None), P(None), P(None), P(None), P(None))
             out_specs = (P(None), self._pool_specs)
@@ -441,10 +452,10 @@ class ContinuousEngine:
         key = ("decode", sampled, filtered, fused, self.fused_decode,
                self.decode_steps)
         if key not in self._jit_cache:
-            impl = functools.partial(self._decode_multi_impl, sampled=sampled,
-                                     filtered=filtered, fused=fused,
-                                     fd=self.fused_decode,
-                                     horizon=self.decode_steps)
+            impl = _named(functools.partial(
+                self._decode_multi_impl, sampled=sampled, filtered=filtered,
+                fused=fused, fd=self.fused_decode,
+                horizon=self.decode_steps), "serve_decode_loop")
             in_specs = (self._param_specs, self._pool_specs, P(None, None)) \
                 + (P(None),) * 10
             out_specs = (P(None, None), P(), P(None), self._pool_specs)
@@ -458,9 +469,10 @@ class ContinuousEngine:
         fused = self.fused_sampling and filtered
         key = ("prefill", final, sampled, filtered, fused, self.fused_decode)
         if key not in self._jit_cache:
-            impl = functools.partial(self._prefill_impl, final=final,
-                                     sampled=sampled, filtered=filtered,
-                                     fused=fused, fd=self.fused_decode)
+            impl = _named(functools.partial(
+                self._prefill_impl, final=final, sampled=sampled,
+                filtered=filtered, fused=fused, fd=self.fused_decode),
+                "serve_prefill_chunk")
             in_specs = (self._param_specs, self._pool_specs, P(None, None),
                         P(None), P(), P(), P(), P(), P(), P(), P(), P())
             out_specs = (P(), self._pool_specs)
@@ -475,8 +487,9 @@ class ContinuousEngine:
         if key not in self._jit_cache:
             # pools are argument 0 here, not 1
             self._jit_cache[key] = self._build(
-                self._copy_page_impl, (self._pool_specs, P(), P()),
-                self._pool_specs, donate=(0,), key=key)
+                _named(self._copy_page_impl, "serve_copy_page"),
+                (self._pool_specs, P(), P()), self._pool_specs, donate=(0,),
+                key=key)
         return self._jit_cache[key]
 
     def _tp_collective_bytes(self, positions: int) -> int:
@@ -716,14 +729,15 @@ class ContinuousEngine:
     # --------------------------------------------------------------- prefill ----
     def _start_prefill(self, seq: SequenceState) -> None:
         """Execute the admission's CoW copy (if any) and queue the suffix."""
-        if seq.cow is not None:
-            src, dst = seq.cow
-            self.pools = self._copy_page_fn()(self.pools, jnp.int32(src),
-                                              jnp.int32(dst))
-            self.scheduler.cow_done(seq)
-            self.cow_copies += 1
-        self.cached_prefill_tokens += seq.cached_len
-        self._prefilling.append(seq)
+        with span("engine.admit", uid=seq.request.uid):
+            if seq.cow is not None:
+                src, dst = seq.cow
+                self.pools = self._copy_page_fn()(
+                    self.pools, jnp.int32(src), jnp.int32(dst))
+                self.scheduler.cow_done(seq)
+                self.cow_copies += 1
+            self.cached_prefill_tokens += seq.cached_len
+            self._prefilling.append(seq)
 
     def _advance_prefill(self, now) -> None:
         """Run ONE chunk of the oldest pending prefill; on the final chunk,
@@ -739,44 +753,53 @@ class ContinuousEngine:
             ctx = seq.context
             start = seq.prefilled
             end = min(start + self.prefill_chunk, seq.prefill_target)
-            chunk = np.zeros((1, self.prefill_chunk), np.int32)
-            chunk[0, :end - start] = ctx[start:end]
-            page_row = jnp.asarray(sched.cache.page_table[seq.slot])
-            sp = seq.request.sampling
             final = end == seq.prefill_target
-            # `sampled`/`filtered` only matter on the final chunk; pin
-            # them False otherwise so non-final chunks share one variant
-            prefill = self._prefill_fn(final, final and not sp.greedy,
-                                       final and not sp.greedy and sp.filtered)
-            # full-context MoE capacity, computed host-side with the exact
-            # math the static engine's dispatch uses (capacity_per_row)
-            moe_cap = capacity_per_row(seq.prefill_target, self.arch.moe) \
-                if self.arch.moe is not None else 0
-            out = prefill(
-                self.params, self.pools, jnp.asarray(chunk), page_row,
-                jnp.int32(seq.slot), jnp.int32(start), jnp.int32(end),
-                jnp.int32(moe_cap),
-                jnp.uint32(sp.seed), jnp.float32(sp.temperature),
-                jnp.int32(sp.top_k), jnp.float32(sp.top_p))
-            if self.sanitize:
-                tok, self.pools, probe = out
-                check_finite_probe(
-                    probe, f"prefill chunk [{start}:{end}) of request "
-                           f"{seq.request.uid} (final={final})")
-            else:
-                tok, self.pools = out
-            seq.prefilled = end
-            self.prefill_tokens += end - start
-            self.collective_bytes += self._tp_collective_bytes(
-                self.prefill_chunk)
-            if end == seq.prefill_target:
-                self._prefilling.popleft()
-                self.prefills += 1
-                sched.register_prefix(seq.slot, ctx)
-                # jaxlint: allow[hot-host-sync] the scheduler must see the
-                # chunk's token before it can admit/close the sequence —
-                # one designed sync per prefill chunk, not per model step
-                seq.generated.append(int(tok))
+            with span("engine.prefill", uid=seq.request.uid,
+                      tokens=end - start, final=int(final)):
+                chunk = np.zeros((1, self.prefill_chunk), np.int32)
+                chunk[0, :end - start] = ctx[start:end]
+                page_row = jnp.asarray(sched.cache.page_table[seq.slot])
+                sp = seq.request.sampling
+                # `sampled`/`filtered` only matter on the final chunk; pin
+                # them False otherwise so non-final chunks share one variant
+                prefill = self._prefill_fn(
+                    final, final and not sp.greedy,
+                    final and not sp.greedy and sp.filtered)
+                # full-context MoE capacity, computed host-side with the
+                # exact math the static engine's dispatch uses
+                # (capacity_per_row)
+                moe_cap = capacity_per_row(seq.prefill_target,
+                                           self.arch.moe) \
+                    if self.arch.moe is not None else 0
+                out = prefill(
+                    self.params, self.pools, jnp.asarray(chunk), page_row,
+                    jnp.int32(seq.slot), jnp.int32(start), jnp.int32(end),
+                    jnp.int32(moe_cap),
+                    jnp.uint32(sp.seed), jnp.float32(sp.temperature),
+                    jnp.int32(sp.top_k), jnp.float32(sp.top_p))
+                if self.sanitize:
+                    tok, self.pools, probe = out
+                    check_finite_probe(
+                        probe, f"prefill chunk [{start}:{end}) of request "
+                               f"{seq.request.uid} (final={final})")
+                else:
+                    tok, self.pools = out
+                seq.prefilled = end
+                self.prefill_tokens += end - start
+                self.collective_bytes += self._tp_collective_bytes(
+                    self.prefill_chunk)
+                if final:
+                    self._prefilling.popleft()
+                    self.prefills += 1
+                    sched.register_prefix(seq.slot, ctx)
+            if final:
+                with span("engine.prefill_wait"):
+                    # jaxlint: allow[hot-host-sync] the scheduler must see
+                    # the chunk's token before it can admit/close the
+                    # sequence — one designed sync per prefill chunk, not
+                    # per model step
+                    token = int(tok)
+                seq.generated.append(token)
                 seq.token_times.append(now())
             return
 
@@ -821,227 +844,270 @@ class ContinuousEngine:
                 # leak/desync raises naming the request that exposed it
                 check_engine(self)
 
+        # one `engine.step` span per iteration, holding the phase spans
+        # (`engine.admit`, `.prefill`, `.prefill_wait`, `.schedule`,
+        # `.decode`, `.decode_wait`, `.emit`), which never overlap
+        step = 0
         while pending or sched.has_work:
-            while pending and pending[0].arrival <= now():
-                sched.submit(pending.popleft())
+            with span("engine.step", i=step):
+                step += 1
+                while pending and pending[0].arrival <= now():
+                    sched.submit(pending.popleft())
 
-            # a prefill whose sequence was preempted must not gate admission
-            # (or trip the stall check below against an admittable queue)
-            while self._prefilling and sched.running.get(
-                    self._prefilling[0].slot) is not self._prefilling[0]:
-                self._prefilling.popleft()
-            # with the prefix cache on, admit only while no prefill is in
-            # flight (one admission per iteration): serializing admission
-            # behind the running prefill lets a later request prefix-match
-            # the pages the current one is about to register, which
-            # same-wave admission would miss. With it off there is nothing
-            # to match — admit everything that fits, PR-1 style
-            while sched.prefix is None or not self._prefilling:
-                seq = sched.admit_next()
-                if seq is None:
-                    break
-                self._start_prefill(seq)
-            for req in sched.take_rejected():
-                results[req.uid] = {
-                    "tokens": [], "token_times": [],
-                    "prompt_len": len(req.prompt),
-                    "error": "context exceeds max_seq_len "
-                             f"({self.max_pages_per_seq} pages/seq)",
-                }
-
-            # one prompt chunk per iteration: decode steps interleave between
-            # a long prompt's chunks instead of stalling behind it. The token
-            # emitted on the final chunk is always a *new* token: the first
-            # generation for a fresh request, the continuation for a resumed
-            # preemption (whose prompt + generated context is re-prefilled
-            # forced — replay never re-decides an already-emitted token).
-            self._advance_prefill(now)
-            for slot in list(sched.running):
-                seq = sched.running[slot]
-                if seq.done and not self._prefill_pending(slot):
-                    finish(seq)
-
-            if not sched.running:
-                if pending:
-                    wait = max(0.0, pending[0].arrival - now())
-                    before = now()
-                    time.sleep(min(1e-3, wait))
-                    if now() <= before:
-                        # injected clock that doesn't advance with real time:
-                        # fast-forward the trace instead of spinning forever
-                        skip += max(wait, 1e-9)
-                    continue
-                if sched.queue:
-                    # not necessarily a stall: if the last running sequence
-                    # finished THIS iteration (a preemption replay whose
-                    # final chunk completed it), admission ran earlier while
-                    # still gated behind that prefill — retry before
-                    # declaring the pool dead
+                # a prefill whose sequence was preempted must not gate
+                # admission (or trip the stall check below against an
+                # admittable queue)
+                while self._prefilling and sched.running.get(
+                        self._prefilling[0].slot) is not self._prefilling[0]:
+                    self._prefilling.popleft()
+                # with the prefix cache on, admit only while no prefill is
+                # in flight (one admission per iteration): serializing
+                # admission behind the running prefill lets a later request
+                # prefix-match the pages the current one is about to
+                # register, which same-wave admission would miss. With it
+                # off there is nothing to match — admit everything that
+                # fits, PR-1 style
+                while sched.prefix is None or not self._prefilling:
                     seq = sched.admit_next()
                     if seq is None:
-                        raise RuntimeError(
-                            "queue stalled: page pool cannot admit any "
-                            "request")
+                        break
                     self._start_prefill(seq)
+                for req in sched.take_rejected():
+                    results[req.uid] = {
+                        "tokens": [], "token_times": [],
+                        "prompt_len": len(req.prompt),
+                        "error": "context exceeds max_seq_len "
+                                 f"({self.max_pages_per_seq} pages/seq)",
+                    }
+
+                # one prompt chunk per iteration: decode steps interleave
+                # between a long prompt's chunks instead of stalling behind
+                # it. The token emitted on the final chunk is always a *new*
+                # token: the first generation for a fresh request, the
+                # continuation for a resumed preemption (whose prompt +
+                # generated context is re-prefilled forced — replay never
+                # re-decides an already-emitted token).
+                self._advance_prefill(now)
+
+                batch = None
+                with span("engine.schedule"):
+                    for slot in list(sched.running):
+                        seq = sched.running[slot]
+                        if seq.done and not self._prefill_pending(slot):
+                            finish(seq)
+                    if sched.running:
+                        # may preempt, never the last running sequence;
+                        # victims re-enter later
+                        sched.ensure_capacity()
+                        # decode the slots whose prefill is complete;
+                        # mid-prefill slots are masked to the null page so
+                        # the fixed-shape step stays hot
+                        slots = [s for s in sched.running_slots()
+                                 if not self._prefill_pending(s)]
+                        if slots:
+                            batch = self._decode_inputs(slots)
+
+                if not sched.running:
+                    if pending:
+                        wait = max(0.0, pending[0].arrival - now())
+                        before = now()
+                        time.sleep(min(1e-3, wait))
+                        if now() <= before:
+                            # injected clock that doesn't advance with real
+                            # time: fast-forward the trace instead of
+                            # spinning forever
+                            skip += max(wait, 1e-9)
+                        continue
+                    if sched.queue:
+                        # not necessarily a stall: if the last running
+                        # sequence finished THIS iteration (a preemption
+                        # replay whose final chunk completed it), admission
+                        # ran earlier while still gated behind that prefill
+                        # — retry before declaring the pool dead
+                        seq = sched.admit_next()
+                        if seq is None:
+                            raise RuntimeError(
+                                "queue stalled: page pool cannot admit any "
+                                "request")
+                        self._start_prefill(seq)
+                        continue
+                    break
+                if batch is None:
                     continue
-                break
 
-            sched.ensure_capacity()     # may preempt; victims re-enter later
+                host_args, kv_tokens, sampled, filtered, sampling_args = batch
+                horizon = self.decode_steps
+                with span("engine.decode", slots=len(slots),
+                          kv_tokens=kv_tokens, horizon=horizon):
+                    fn = self._decode_fn if horizon == 1 \
+                        else self._decode_multi_fn
+                    out = fn(sampled, filtered)(
+                        self.params, self.pools,
+                        *(jnp.asarray(a) for a in host_args), *sampling_args)
+                    if self.sanitize:
+                        *out, self.pools, probe = out
+                    else:
+                        *out, self.pools = out
+                if horizon == 1:
+                    with span("engine.decode_wait"):
+                        if self.sanitize:
+                            check_finite_probe(probe,
+                                               f"decode step {self.steps}")
+                        # jaxlint: allow[hot-host-sync] THE per-step sync:
+                        # continuous batching is host-driven — stop checks
+                        # and slot reuse need this step's tokens before the
+                        # next batch can be scheduled
+                        next_np = np.asarray(out[0])
+                    with span("engine.emit"):
+                        self.steps += 1
+                        self.decode_dispatches += 1
+                        self.collective_bytes += \
+                            self._tp_collective_bytes(self.num_slots)
+                        t_tok = now()
+                        for slot in slots:
+                            seq = sched.running[slot]
+                            sched.cache.seq_lens[slot] += 1  # input cached
+                            seq.generated.append(int(next_np[slot]))
+                            seq.token_times.append(t_tok)
+                            if seq.done:
+                                finish(seq)
+                    continue
 
-            # decode the slots whose prefill is complete; mid-prefill slots
-            # are masked to the null page so the fixed-shape step stays hot
-            slots = [s for s in sched.running_slots()
-                     if not self._prefill_pending(s)]
-            if not slots:
-                continue
-            cache = sched.cache
-            horizon = self.decode_steps
-            if horizon > 1:
-                # per-slot loop predicates for the multi-step dispatch,
-                # built BEFORE snapshotting the page table: extend_capacity
-                # appends best-effort horizon pages the compiled loop must
-                # see. budget is the host scheduler's remaining allowance
-                # (max-new and page-table capacity), restated as the
-                # in-loop EXIT_BUDGET predicate; >= 1 because done
-                # sequences were finished above.
-                h_active = np.zeros((self.num_slots,), bool)
-                h_budget = np.ones((self.num_slots,), np.int32)
-                h_pages = np.zeros((self.num_slots,), np.int32)
-                h_eos = np.full((self.num_slots,), -1, np.int32)
-                for slot in slots:
-                    seq = sched.running[slot]
-                    req = seq.request
-                    h_active[slot] = True
-                    left = min(
-                        req.max_new_tokens - len(seq.generated),
-                        seq.max_context - len(req.prompt)
-                        - len(seq.generated))
-                    h_budget[slot] = left
-                    h_pages[slot] = sched.extend_capacity(
-                        slot, min(horizon, left))
-                    if req.eos_id is not None:
-                        h_eos[slot] = req.eos_id
-            page_table, seq_lens = cache.page_table, cache.seq_lens
-            if len(slots) != len(sched.running):
-                page_table = page_table.copy()
-                seq_lens = seq_lens.copy()
-                for s in sched.running:
-                    if self._prefill_pending(s):
-                        page_table[s] = 0
-                        seq_lens[s] = 0
-            tokens = np.zeros((self.num_slots,), np.int32)
-            for slot in slots:
-                tokens[slot] = sched.running[slot].generated[-1]
-            active = [sched.running[s].request.sampling for s in slots]
-            sampled = any(not sp.greedy for sp in active)
-            # skip the sampler's [S, V] filter sorts when no co-batched
-            # request constrains the distribution (disabled filters are
-            # exact no-ops, so variant choice never changes a draw)
-            filtered = any(not sp.greedy and sp.filtered for sp in active)
-            if sampled:
-                # per-slot sampling params are constant while a request
-                # occupies its slot; only rebuild + re-transfer the arrays
-                # when the decoding composition changes (admission, finish,
-                # preemption) — positions come from seq_lens on device
-                comp = tuple((s, sched.running[s].request.sampling)
-                             for s in slots)
-                if comp != self._sampling_key:
-                    seeds = np.zeros((self.num_slots,), np.uint32)
-                    temps = np.zeros((self.num_slots,), np.float32)
-                    top_ks = np.zeros((self.num_slots,), np.int32)
-                    top_ps = np.ones((self.num_slots,), np.float32)
+                # multi-step dispatch: up to `horizon` decode iterations ran
+                # as one compiled while_loop; the host resyncs once per
+                # dispatch and replays the loop's effects (seq_lens advance,
+                # emitted tokens, finish events) from the returned exit state
+                buf, n_steps, reasons = out
+                with span("engine.decode_wait"):
+                    if self.sanitize:
+                        check_finite_probe(
+                            probe, f"multi-step decode dispatch "
+                                   f"{self.decode_dispatches} (horizon "
+                                   f"{horizon})")
+                    # THE per-horizon sync — the one intentional host
+                    # round-trip every `horizon` decode steps: the scheduler
+                    # must replay the loop's exit state (steps executed,
+                    # tokens emitted, per-slot exit reasons) before it can
+                    # admit, preempt, or allocate pages. max(1, ...) is for
+                    # the recompile auditor's recorder, which replays
+                    # all-zero outputs; the real loop always executes >= 1
+                    # iteration because the host guaranteed iteration 0's
+                    # predicates (ensure_capacity allocated the next page
+                    # and done sequences never reach the dispatch).
+                    # jaxlint: allow[hot-host-sync] designed per-horizon sync
+                    k = max(1, int(n_steps))
+                    # jaxlint: allow[hot-host-sync] same per-horizon sync
+                    buf_np = np.asarray(buf)
+                    # jaxlint: allow[hot-host-sync] same per-horizon sync
+                    reasons_np = np.asarray(reasons)
+                with span("engine.emit"):
+                    self.steps += k
+                    self.decode_dispatches += 1
+                    self.collective_bytes += \
+                        k * self._tp_collective_bytes(self.num_slots)
+                    for name, bit in (("eos", tf.EXIT_EOS),
+                                      ("token_budget", tf.EXIT_BUDGET),
+                                      ("page_budget", tf.EXIT_PAGES)):
+                        self.decode_exits[name] += \
+                            int(((reasons_np[slots] & bit) != 0).sum())
+                    if k == horizon and not reasons_np[slots].any():
+                        self.decode_exits["horizon"] += 1
+                    t_tok = now()
                     for slot in slots:
-                        sp = sched.running[slot].request.sampling
-                        seeds[slot] = sp.seed
-                        temps[slot] = sp.temperature
-                        top_ks[slot] = sp.top_k
-                        top_ps[slot] = sp.top_p
-                    self._sampling_args = tuple(jnp.asarray(a) for a in (
-                        seeds, temps, top_ks, top_ps))
-                    self._sampling_key = comp
-                sampling_args = self._sampling_args
-            else:
-                sampling_args = self._null_sampling
-            if horizon == 1:
-                out = self._decode_fn(sampled, filtered)(
-                    self.params, self.pools, jnp.asarray(page_table),
-                    jnp.asarray(seq_lens), jnp.asarray(tokens),
-                    *sampling_args)
-                if self.sanitize:
-                    next_tokens, self.pools, probe = out
-                    check_finite_probe(probe, f"decode step {self.steps}")
-                else:
-                    next_tokens, self.pools = out
-                self.steps += 1
-                self.decode_dispatches += 1
-                self.collective_bytes += \
-                    self._tp_collective_bytes(self.num_slots)
-                # jaxlint: allow[hot-host-sync] THE per-step sync:
-                # continuous batching is host-driven — stop checks and slot
-                # reuse need this step's tokens before the next batch can
-                # be scheduled
-                next_np = np.asarray(next_tokens)
-                t_tok = now()
-                for slot in slots:
-                    seq = sched.running[slot]
-                    cache.seq_lens[slot] += 1    # input token now cached
-                    seq.generated.append(int(next_np[slot]))
-                    seq.token_times.append(t_tok)
-                    if seq.done:
-                        finish(seq)
-                continue
+                        seq = sched.running[slot]
+                        sched.cache.seq_lens[slot] += k  # k inputs cached
+                        seq.generated.extend(int(t) for t in buf_np[:k, slot])
+                        seq.token_times.extend([t_tok] * k)
+                        if seq.done:
+                            finish(seq)
+        return results
 
-            # multi-step dispatch: up to `horizon` decode iterations run as
-            # one compiled while_loop; the host resyncs once per dispatch
-            # and replays the loop's effects (seq_lens advance, emitted
-            # tokens, finish events) from the returned exit state
-            out = self._decode_multi_fn(sampled, filtered)(
-                self.params, self.pools, jnp.asarray(page_table),
-                jnp.asarray(seq_lens), jnp.asarray(tokens),
-                jnp.asarray(h_active), jnp.asarray(h_budget),
-                jnp.asarray(h_pages), jnp.asarray(h_eos), *sampling_args)
-            if self.sanitize:
-                buf, n_steps, reasons, self.pools, probe = out
-                check_finite_probe(
-                    probe, f"multi-step decode dispatch "
-                           f"{self.decode_dispatches} (horizon {horizon})")
-            else:
-                buf, n_steps, reasons, self.pools = out
-            # THE per-horizon sync — the one intentional host round-trip
-            # every `horizon` decode steps: the scheduler must replay the
-            # loop's exit state (steps executed, tokens emitted, per-slot
-            # exit reasons) before it can admit, preempt, or allocate
-            # pages. max(1, ...) is for the recompile auditor's recorder,
-            # which replays all-zero outputs; the real loop always executes
-            # >= 1 iteration because the host guaranteed iteration 0's
-            # predicates (ensure_capacity allocated the next page and done
-            # sequences never reach the dispatch).
-            # jaxlint: allow[hot-host-sync] the designed per-horizon sync
-            k = max(1, int(n_steps))
-            # jaxlint: allow[hot-host-sync] same designed per-horizon sync
-            buf_np = np.asarray(buf)
-            # jaxlint: allow[hot-host-sync] same designed per-horizon sync
-            reasons_np = np.asarray(reasons)
-            self.steps += k
-            self.decode_dispatches += 1
-            self.collective_bytes += \
-                k * self._tp_collective_bytes(self.num_slots)
-            for name, bit in (("eos", tf.EXIT_EOS),
-                              ("token_budget", tf.EXIT_BUDGET),
-                              ("page_budget", tf.EXIT_PAGES)):
-                self.decode_exits[name] += \
-                    int(((reasons_np[slots] & bit) != 0).sum())
-            if k == horizon and not reasons_np[slots].any():
-                self.decode_exits["horizon"] += 1
-            t_tok = now()
+    def _decode_inputs(self, slots):
+        """Host inputs of one decode dispatch over ``slots`` (running, their
+        prefill complete) -> ``(host_args, kv_tokens, sampled, filtered,
+        sampling_args)``.
+
+        ``host_args`` are the NumPy arrays the compiled step takes after the
+        params and pools: the page table, the sequence lengths (mid-prefill
+        slots masked to the null page), each slot's last token and, at
+        ``decode_steps > 1``, the loop's per-slot predicates. ``kv_tokens``
+        is the tokens the decoded slots hold in the cache (the sum of their
+        ``seq_lens``), which the step reads. ``sampling_args`` are device
+        arrays, rebuilt only when the decoding composition changes."""
+        sched = self.scheduler
+        cache = sched.cache
+        horizon = self.decode_steps
+        loop_args = ()
+        if horizon > 1:
+            # per-slot loop predicates for the multi-step dispatch,
+            # built BEFORE snapshotting the page table: extend_capacity
+            # appends best-effort horizon pages the compiled loop must
+            # see. budget is the host scheduler's remaining allowance
+            # (max-new and page-table capacity), restated as the
+            # in-loop EXIT_BUDGET predicate; >= 1 because done
+            # sequences were finished above.
+            h_active = np.zeros((self.num_slots,), bool)
+            h_budget = np.ones((self.num_slots,), np.int32)
+            h_pages = np.zeros((self.num_slots,), np.int32)
+            h_eos = np.full((self.num_slots,), -1, np.int32)
             for slot in slots:
                 seq = sched.running[slot]
-                cache.seq_lens[slot] += k        # k input tokens now cached
-                seq.generated.extend(int(t) for t in buf_np[:k, slot])
-                seq.token_times.extend([t_tok] * k)
-                if seq.done:
-                    finish(seq)
-        return results
+                req = seq.request
+                h_active[slot] = True
+                left = min(
+                    req.max_new_tokens - len(seq.generated),
+                    seq.max_context - len(req.prompt)
+                    - len(seq.generated))
+                h_budget[slot] = left
+                h_pages[slot] = sched.extend_capacity(
+                    slot, min(horizon, left))
+                if req.eos_id is not None:
+                    h_eos[slot] = req.eos_id
+            loop_args = (h_active, h_budget, h_pages, h_eos)
+        page_table, seq_lens = cache.page_table, cache.seq_lens
+        if len(slots) != len(sched.running):
+            page_table = page_table.copy()
+            seq_lens = seq_lens.copy()
+            for s in sched.running:
+                if self._prefill_pending(s):
+                    page_table[s] = 0
+                    seq_lens[s] = 0
+        kv_tokens = int(seq_lens[slots].sum())
+        tokens = np.zeros((self.num_slots,), np.int32)
+        for slot in slots:
+            tokens[slot] = sched.running[slot].generated[-1]
+        active = [sched.running[s].request.sampling for s in slots]
+        sampled = any(not sp.greedy for sp in active)
+        # skip the sampler's [S, V] filter sorts when no co-batched
+        # request constrains the distribution (disabled filters are
+        # exact no-ops, so variant choice never changes a draw)
+        filtered = any(not sp.greedy and sp.filtered for sp in active)
+        if sampled:
+            # per-slot sampling params are constant while a request
+            # occupies its slot; only rebuild + re-transfer the arrays
+            # when the decoding composition changes (admission, finish,
+            # preemption) — positions come from seq_lens on device
+            comp = tuple((s, sched.running[s].request.sampling)
+                         for s in slots)
+            if comp != self._sampling_key:
+                seeds = np.zeros((self.num_slots,), np.uint32)
+                temps = np.zeros((self.num_slots,), np.float32)
+                top_ks = np.zeros((self.num_slots,), np.int32)
+                top_ps = np.ones((self.num_slots,), np.float32)
+                for slot in slots:
+                    sp = sched.running[slot].request.sampling
+                    seeds[slot] = sp.seed
+                    temps[slot] = sp.temperature
+                    top_ks[slot] = sp.top_k
+                    top_ps[slot] = sp.top_p
+                self._sampling_args = tuple(jnp.asarray(a) for a in (
+                    seeds, temps, top_ks, top_ps))
+                self._sampling_key = comp
+            sampling_args = self._sampling_args
+        else:
+            sampling_args = self._null_sampling
+        host_args = (page_table, seq_lens, tokens) + loop_args
+        return host_args, kv_tokens, sampled, filtered, sampling_args
 
     # ----------------------------------------------------------------- stats ----
     @property

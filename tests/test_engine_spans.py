@@ -1,0 +1,216 @@
+"""The serving engine's host spans and program names, on the CPU at a tiny
+size: a few requests served under ``jax.profiler`` and the trace read back
+with ``ProfileData``.
+
+Every loop iteration is one ``engine.step`` span holding non-overlapping
+phase spans; each decode dispatch is one ``engine.decode`` whose ``slots``
+and ``kv_tokens`` are the scheduler's own counts; the prefill path and the
+multi-step path record theirs; each jitted program compiles under its own
+name; and the token streams are the same with the profiler on and off.
+"""
+import glob
+
+import jax
+import numpy as np
+import pytest
+from jax.profiler import ProfileData
+
+from repro.configs import smoke_config
+from repro.models import build_model
+from repro.serving import ContinuousEngine, Request
+from repro.serving.sampling import SamplingParams
+
+PHASES = ("engine.admit", "engine.prefill", "engine.prefill_wait",
+          "engine.schedule", "engine.decode", "engine.decode_wait",
+          "engine.emit")
+HORIZONS = (1, 4)
+
+
+def _requests(vocab):
+    """Five requests over three slots: ragged prompts (one of three 16-token
+    chunks), mixed sampling, and one prompt that extends another past a
+    partial page, so its admission copies that page."""
+    rng = np.random.default_rng(3)
+
+    def prompt(n):
+        return [int(t) for t in rng.integers(5, vocab, n)]
+
+    base = prompt(10)
+    prompts = [base, prompt(40), base + prompt(3), prompt(7), prompt(21)]
+    samplings = [SamplingParams(), SamplingParams(temperature=0.8, seed=11),
+                 SamplingParams(temperature=0.9, top_k=8, top_p=0.9, seed=12)]
+    return [Request(uid=i, prompt=p, max_new_tokens=4 + i,
+                    sampling=samplings[i % 3])
+            for i, p in enumerate(prompts)]
+
+
+def _engine(model, params, horizon):
+    return ContinuousEngine(model, params, num_slots=3, num_pages=64,
+                            page_size=4, max_seq_len=64,
+                            decode_steps=horizon)
+
+
+def _record_dispatches(engine):
+    """(decoded slots, their cached tokens) from the scheduler at every
+    decode dispatch."""
+    calls = []
+
+    def wrap(make):
+        def make_recorded(sampled, filtered):
+            fn = make(sampled, filtered)
+
+            def call(*args):
+                sched = engine.scheduler
+                slots = [s for s in sched.running
+                         if not engine._prefill_pending(s)]
+                calls.append((len(slots), int(sum(
+                    int(sched.cache.seq_lens[s]) for s in slots))))
+                return fn(*args)
+            return call
+        return make_recorded
+
+    engine._decode_fn = wrap(engine._decode_fn)
+    engine._decode_multi_fn = wrap(engine._decode_multi_fn)
+    return calls
+
+
+def _record_modules(engine):
+    """jit-cache key -> the ``HloModule`` line of the program compiled
+    under it, taken at its first call."""
+    modules = {}
+    build = engine._build
+
+    def build_recorded(impl, in_specs, out_specs, donate, key=()):
+        fn = build(impl, in_specs, out_specs, donate, key=key)
+
+        def call(*args):
+            if key not in modules:
+                text = fn.lower(*args).compile().as_text()
+                modules[key] = text.split(",", 1)[0]
+            return fn(*args)
+        return call
+
+    engine._build = build_recorded
+    return modules
+
+
+def _engine_spans(trace_dir):
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    spans.append((e.name, int(e.start_ns),
+                                  int(e.start_ns + e.duration_ns),
+                                  dict(e.stats)))
+    return sorted(spans, key=lambda s: s[1])
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    arch = smoke_config("llama3.2-3b")
+    model = build_model(arch)
+    params = model.init(jax.random.key(0))
+    reqs = _requests(arch.vocab_size)
+    out = {}
+    for horizon in HORIZONS:
+        engine = _engine(model, params, horizon)
+        calls = _record_dispatches(engine)
+        trace_dir = tmp_path_factory.mktemp(f"trace{horizon}")
+        jax.profiler.start_trace(str(trace_dir))
+        try:
+            traced = engine.run(list(reqs))
+        finally:
+            jax.profiler.stop_trace()
+        plain = _engine(model, params, horizon)
+        modules = _record_modules(plain)
+        untraced = plain.run(list(reqs))
+        out[horizon] = dict(
+            engine=engine, calls=calls, spans=_engine_spans(trace_dir),
+            traced={u: r["tokens"] for u, r in traced.items()},
+            untraced={u: r["tokens"] for u, r in untraced.items()},
+            modules=modules, uids=sorted(r.uid for r in reqs))
+    return out
+
+
+def _named(spans, name):
+    return [s for s in spans if s[0] == name]
+
+
+@pytest.mark.parametrize("horizon", HORIZONS)
+def test_every_span_appears_and_phases_nest_in_steps(served, horizon):
+    spans = served[horizon]["spans"]
+    steps = _named(spans, "engine.step")
+    assert [s[3]["i"] for s in steps] == list(range(len(steps)))
+    assert {s[0] for s in spans} == {"engine.step", *PHASES}
+    for name, start, end, _ in spans:
+        if name == "engine.step":
+            continue
+        holders = [s for s in steps if s[1] <= start and end <= s[2]]
+        assert len(holders) == 1, (name, start)
+    # the phases of one step never overlap
+    for step in steps:
+        phases = [s for s in spans if s[0] != "engine.step"
+                  and step[1] <= s[1] and s[2] <= step[2]]
+        for a, b in zip(phases, phases[1:]):
+            assert a[2] <= b[1], (a, b)
+
+
+@pytest.mark.parametrize("horizon", HORIZONS)
+def test_one_decode_span_per_dispatch(served, horizon):
+    s = served[horizon]
+    engine, spans = s["engine"], s["spans"]
+    assert len(_named(spans, "engine.decode")) == engine.decode_dispatches
+    assert len(_named(spans, "engine.decode_wait")) == \
+        engine.decode_dispatches
+    assert len(_named(spans, "engine.emit")) == engine.decode_dispatches
+    assert engine.decode_dispatches > 0
+    if horizon > 1:
+        assert engine.steps > engine.decode_dispatches
+
+
+@pytest.mark.parametrize("horizon", HORIZONS)
+def test_decode_span_args_are_the_schedulers_counts(served, horizon):
+    s = served[horizon]
+    decodes = _named(s["spans"], "engine.decode")
+    got = [(d[3]["slots"], d[3]["kv_tokens"]) for d in decodes]
+    assert got == s["calls"]
+    assert {d[3]["horizon"] for d in decodes} == {horizon}
+    assert max(slots for slots, _ in got) >= 2      # batches of several
+
+
+@pytest.mark.parametrize("horizon", HORIZONS)
+def test_prefill_and_admission_spans_match_the_counters(served, horizon):
+    s = served[horizon]
+    engine, spans = s["engine"], s["spans"]
+    prefills = _named(spans, "engine.prefill")
+    assert sum(p[3]["tokens"] for p in prefills) == engine.prefill_tokens
+    finals = [p for p in prefills if p[3]["final"]]
+    assert len(finals) == engine.prefills == len(s["uids"])
+    assert len(prefills) > len(finals)          # the 40-token prompt
+    assert len(_named(spans, "engine.prefill_wait")) == engine.prefills
+    assert sorted(p[3]["uid"] for p in finals) == s["uids"]
+    admits = _named(spans, "engine.admit")
+    assert sorted(a[3]["uid"] for a in admits) == s["uids"]
+    assert engine.cow_copies == 1
+
+
+@pytest.mark.parametrize("horizon", HORIZONS)
+def test_tokens_identical_with_profiler_on_and_off(served, horizon):
+    s = served[horizon]
+    assert s["traced"] == s["untraced"]
+    assert all(len(t) == 4 + u for u, t in s["traced"].items())
+
+
+def test_engine_programs_compile_under_their_names(served):
+    names = {}
+    for horizon in HORIZONS:
+        for key, module in served[horizon]["modules"].items():
+            kind = "loop" if key[0] == "decode" and len(key) == 6 \
+                else key[0]
+            names.setdefault(kind, set()).add(module)
+    assert names == {"decode": {"HloModule jit_serve_decode_step"},
+                     "loop": {"HloModule jit_serve_decode_loop"},
+                     "prefill": {"HloModule jit_serve_prefill_chunk"},
+                     "copy": {"HloModule jit_serve_copy_page"}}

@@ -8,7 +8,11 @@ primitives Mosaic has no lowering for, verifier errors — and it costs about
 two seconds a kernel. The topology is described inside a fixture, never at
 import time, because only one process may hold the TPU library.
 """
+import importlib.util
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -91,10 +95,70 @@ CASES = {
 }
 
 
+# the ``name=`` each case's pallas_call passes: its instruction's name in
+# the compiled HLO and on a profiler trace's ``XLA Ops`` line
+KERNEL_NAMES = {
+    "paged_decode_attention": "paged_decode_attention",
+    "paged_prefill_attention": "paged_prefill_attention",
+    "fused_lm_head_greedy": "fused_lm_head",
+    "fused_lm_head_sampled": "fused_lm_head",
+    "fused_lm_head_filtered": "fused_lm_head",
+    "fused_sampling_filter": "fused_sampling_filter",
+    "gated_rmsnorm": "gated_rms_norm",
+    "decode_residual_norm_r8": "decode_residual_norm",
+    "decode_residual_norm_r64": "decode_residual_norm",
+}
+_CUSTOM_CALL = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*"
+                          r'custom_call_target="tpu_custom_call"', re.M)
+
+
+@pytest.fixture(scope="module")
+def compiled_text(one_chip, no_persistent_cache):
+    """case -> the compiled HLO text of its kernel, compiled once."""
+    texts = {}
+
+    def get(name):
+        if name not in texts:
+            fn, shapes = CASES[name]
+            args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                    for s, d in shapes]
+            texts[name] = jax.jit(fn).lower(*args).compile().as_text()
+        return texts[name]
+    return get
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
-    fn, shapes = CASES[name]
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), \
+def test_kernel_compiles_for_v5e(name, compiled_text):
+    assert "tpu_custom_call" in compiled_text(name), \
         f"{name} compiled without its Pallas kernel"
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_instruction_is_named(name, compiled_text):
+    """The kernel's ``tpu_custom_call`` instruction takes its ``name=``
+    (``%paged_decode_attention.1``), not the enclosing scope's."""
+    want = KERNEL_NAMES[name]
+    found = _CUSTOM_CALL.findall(compiled_text(name))
+    assert found and all(re.fullmatch(rf"{want}(\.\d+)?", f)
+                         for f in found), found
+
+
+def test_benchmark_reads_kernel_functions_through_the_names(compiled_text):
+    """The chip benchmark's ``kernel_names`` still maps the named decode
+    attention and LM head instructions to the kernel functions its readers
+    look up: a ``name=`` ending in ``_kernel`` would displace them."""
+    path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "lib" / "trace.py")
+    spec = importlib.util.spec_from_file_location("chipbench_lib_trace",
+                                                  path)
+    trace = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = trace      # its dataclasses look it up
+    spec.loader.exec_module(trace)
+    for case, kernel in (("paged_decode_attention", "_paged_decode_kernel"),
+                         ("fused_lm_head_sampled", "_head_kernel")):
+        names = trace.kernel_names(compiled_text(case))
+        assert names, case
+        for key, found in names.items():
+            assert key.startswith(KERNEL_NAMES[case]), key
+            assert found == kernel, (case, found)
+    assert not [n for n in KERNEL_NAMES.values() if n.endswith("_kernel")]
