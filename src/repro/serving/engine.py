@@ -122,6 +122,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..analysis.sanitize import (check_engine, check_finite_probe,
                                  sanitize_enabled)
 from ..core.spans import span
+from ..kernels.decode_attention import ops as attn_ops
 from ..kernels.fused_lm_head import ops as head_ops
 from ..kernels.fused_lm_head import ref as head_ref
 from ..models import transformer as tf
@@ -372,6 +373,15 @@ class ContinuousEngine:
             self.tp_axis = None
             self._param_specs = self._pool_specs = None
         self.params = params
+        # tokens one block of the paged decode-attention walk covers, at
+        # the shard's local KV heads: the kernel's own rule, for kv_blocks
+        self.decode_block_tokens = 0
+        if self.has_attn:
+            row_bytes = (arch.num_kv_heads * self.kv_rep // tp
+                         * arch.resolved_head_dim
+                         * jnp.dtype(arch.dtype).itemsize)
+            self.decode_block_tokens = page_size * attn_ops.pages_per_block(
+                page_size, row_bytes, self.max_pages_per_seq)
 
         self.steps = 0                  # decode steps executed (for stats)
         self.decode_dispatches = 0      # host round-trips those steps cost
@@ -935,7 +945,8 @@ class ContinuousEngine:
                 if batch is None:
                     continue
 
-                host_args, kv_tokens, sampled, filtered, sampling_args = batch
+                (host_args, kv_tokens, kv_blocks, sampled, filtered,
+                 sampling_args) = batch
                 horizon = self.decode_steps
                 with span("engine.decode", slots=len(slots),
                           kv_tokens=kv_tokens, horizon=horizon):
@@ -949,7 +960,7 @@ class ContinuousEngine:
                     else:
                         *out, self.pools = out
                 if horizon == 1:
-                    with span("engine.decode_wait"):
+                    with span("engine.decode_wait", kv_blocks=kv_blocks):
                         if self.sanitize:
                             check_finite_probe(probe,
                                                f"decode step {self.steps}")
@@ -978,7 +989,7 @@ class ContinuousEngine:
                 # dispatch and replays the loop's effects (seq_lens advance,
                 # emitted tokens, finish events) from the returned exit state
                 buf, n_steps, reasons = out
-                with span("engine.decode_wait"):
+                with span("engine.decode_wait", kv_blocks=kv_blocks):
                     if self.sanitize:
                         check_finite_probe(
                             probe, f"multi-step decode dispatch "
@@ -1024,16 +1035,18 @@ class ContinuousEngine:
 
     def _decode_inputs(self, slots):
         """Host inputs of one decode dispatch over ``slots`` (running, their
-        prefill complete) -> ``(host_args, kv_tokens, sampled, filtered,
-        sampling_args)``.
+        prefill complete) -> ``(host_args, kv_tokens, kv_blocks, sampled,
+        filtered, sampling_args)``.
 
         ``host_args`` are the NumPy arrays the compiled step takes after the
         params and pools: the page table, the sequence lengths (mid-prefill
         slots masked to the null page), each slot's last token and, at
         ``decode_steps > 1``, the loop's per-slot predicates. ``kv_tokens``
         is the tokens the decoded slots hold in the cache (the sum of their
-        ``seq_lens``), which the step reads. ``sampling_args`` are device
-        arrays, rebuilt only when the decoding composition changes."""
+        ``seq_lens``), which the step reads; ``kv_blocks`` the blocks the
+        paged decode-attention kernel walks for them in each layer of the
+        first step. ``sampling_args`` are device arrays, rebuilt only when
+        the decoding composition changes."""
         sched = self.scheduler
         cache = sched.cache
         horizon = self.decode_steps
@@ -1073,6 +1086,10 @@ class ContinuousEngine:
                     page_table[s] = 0
                     seq_lens[s] = 0
         kv_tokens = int(seq_lens[slots].sum())
+        # the step attends over each slot's cache plus the token it writes
+        kv_blocks = attn_ops.kv_blocks(seq_lens[slots] + 1,
+                                       self.decode_block_tokens) \
+            if self.has_attn else 0
         tokens = np.zeros((self.num_slots,), np.int32)
         for slot in slots:
             tokens[slot] = sched.running[slot].generated[-1]
@@ -1107,7 +1124,8 @@ class ContinuousEngine:
         else:
             sampling_args = self._null_sampling
         host_args = (page_table, seq_lens, tokens) + loop_args
-        return host_args, kv_tokens, sampled, filtered, sampling_args
+        return (host_args, kv_tokens, kv_blocks, sampled, filtered,
+                sampling_args)
 
     # ----------------------------------------------------------------- stats ----
     @property

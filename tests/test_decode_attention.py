@@ -1,12 +1,15 @@
 """Paged decode-attention: Pallas kernel (interpret) vs pure-JAX ref vs a
 direct dense computation, across GQA ratios, page sizes, ragged lengths, and
 a fragmented page table."""
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.decode_attention import kernel, ref
+from repro.kernels.decode_attention import kernel, ops, ref
 from repro.models.attention import naive_attention
 
 
@@ -24,15 +27,80 @@ def _paged_case(seed, b, hq, hkv, d, page_size, num_pages, max_pages,
     return q, k_pages, v_pages, page_table, jnp.asarray(seq_lens, jnp.int32)
 
 
-@pytest.mark.parametrize("page_size,hq,hkv", [(4, 4, 1), (8, 4, 2),
-                                              (16, 4, 4), (8, 6, 2)])
-def test_kernel_matches_ref(page_size, hq, hkv):
-    max_pages = 4
-    case = _paged_case(0, 3, hq, hkv, 16, page_size, 16, max_pages,
-                       seq_lens=[1, page_size * 2 + 3, page_size * max_pages])
-    o_ref = ref.paged_decode_attention(*case)
-    o_k = kernel.paged_decode_attention_fwd(*case, interpret=True)
-    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_ref), atol=1e-5)
+def _block_edges(page_size, hkv, d, max_pages, dtype):
+    """Live lengths at every edge of the decode walk's blocks (one token,
+    a page less one, a page, a block, a block and one, the full context),
+    with empty slots among them."""
+    block = page_size * ops.pages_per_block(
+        page_size, hkv * d * jnp.dtype(dtype).itemsize, max_pages)
+    return [1, 0, page_size - 1, page_size, block, 0, block + 1,
+            max_pages * page_size]
+
+
+def _small(page_size, hq, hkv):
+    return pytest.param(page_size, hq, hkv, 16, 4, 16, jnp.float32,
+                        [1, page_size * 2 + 3, page_size * 4], False,
+                        id=f"{page_size}-{hq}-{hkv}")
+
+
+def _edges(hq, hkv, dtype, shared=False):
+    name = f"edges-hkv{hkv}-{jnp.dtype(dtype).name}" + \
+        ("-shared" if shared else "")
+    return pytest.param(16, hq, hkv, 128, 40, 8 * 40 + 1, dtype,
+                        _block_edges(16, hkv, 128, 40, dtype), shared,
+                        id=name)
+
+
+@pytest.mark.parametrize(
+    "page_size,hq,hkv,d,max_pages,num_pages,dtype,seq_lens,shared",
+    [_small(4, 4, 1), _small(8, 4, 2), _small(16, 4, 4), _small(8, 6, 2),
+     _edges(4, 1, jnp.float32), _edges(4, 2, jnp.float32),
+     _edges(16, 8, jnp.float32), _edges(16, 8, jnp.bfloat16),
+     _edges(16, 8, jnp.bfloat16, shared=True)])
+def test_kernel_matches_ref(page_size, hq, hkv, d, max_pages, num_pages,
+                            dtype, seq_lens, shared):
+    """Live slots match the ref and empty ones are zero, on fragmented page
+    tables (shuffled physical ids); ``shared`` has the last slot reuse the
+    scattered pages of the one before it (a cached prefix) for half its
+    table."""
+    q, kp, vp, pt, sl = _paged_case(0, len(seq_lens), hq, hkv, d, page_size,
+                                    num_pages, max_pages, seq_lens, dtype)
+    if shared:
+        pt = pt.at[-1, :max_pages // 2].set(pt[-2, :max_pages // 2])
+    o_ref = np.asarray(ref.paged_decode_attention(q, kp, vp, pt, sl),
+                       np.float32)
+    o_k = np.asarray(kernel.paged_decode_attention_fwd(
+        q, kp, vp, pt, sl, interpret=True), np.float32)
+    live = np.asarray(seq_lens) > 0
+    atol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(o_k[live], o_ref[live], atol=atol)
+    assert not o_k[~live].any()
+
+
+@pytest.mark.parametrize("hkv,dtype", [(1, jnp.float32), (2, jnp.float32),
+                                       (8, jnp.float32), (8, jnp.bfloat16)])
+def test_kv_blocks_counts_the_kernels_walk(hkv, dtype):
+    """The kernel's K and V buffers hold the block the shared rule gives,
+    and ``ops.kv_blocks`` counts the blocks its walk takes: one for every
+    started block of each sequence, none for an empty one."""
+    page, d, max_pages = 16, 128, 40
+    lens = _block_edges(page, hkv, d, max_pages, dtype)
+    case = _paged_case(0, len(lens), 2 * hkv, hkv, d, page,
+                       len(lens) * max_pages + 1, max_pages, lens, dtype)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        kernel.paged_decode_attention_fwd, interpret=True))(*case).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    # K and V: [2 buffers, block tokens x Hkv rows, D]
+    bufs = [v.aval.shape for v in call.params["jaxpr"].invars
+            if len(v.aval.shape) == 3 and v.aval.shape[0] == 2]
+    assert len(bufs) == 2 and bufs[0] == bufs[1], bufs
+    block = bufs[0][1] // hkv
+    assert block == page * ops.pages_per_block(
+        page, hkv * d * jnp.dtype(dtype).itemsize, max_pages)
+    assert block < max_pages * page
+    assert ops.kv_blocks(lens, block) == sum(math.ceil(n / block)
+                                             for n in lens)
+    assert ops.kv_blocks([0, 1, block, block + 1], block) == 4
 
 
 def test_kernel_zeroes_inactive_slots():

@@ -4,18 +4,23 @@ with ``ProfileData``.
 
 Every loop iteration is one ``engine.step`` span holding non-overlapping
 phase spans; each decode dispatch is one ``engine.decode`` whose ``slots``
-and ``kv_tokens`` are the scheduler's own counts; the prefill path and the
+and ``kv_tokens`` are the scheduler's own counts, and its
+``engine.decode_wait`` carries the blocks the decode-attention kernel walks
+(``kv_blocks``); the prefill path and the
 multi-step path record theirs; each jitted program compiles under its own
 name; and the token streams are the same with the profiler on and off.
 """
 import glob
+import math
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
 from repro.configs import smoke_config
+from repro.kernels.decode_attention import ops
 from repro.models import build_model
 from repro.serving import ContinuousEngine, Request
 from repro.serving.sampling import SamplingParams
@@ -52,8 +57,8 @@ def _engine(model, params, horizon):
 
 def _record_dispatches(engine):
     """(decoded slots, their cached tokens) from the scheduler at every
-    decode dispatch."""
-    calls = []
+    decode dispatch, and the cached tokens of each decoded slot."""
+    calls, lens = [], []
 
     def wrap(make):
         def make_recorded(sampled, filtered):
@@ -63,15 +68,15 @@ def _record_dispatches(engine):
                 sched = engine.scheduler
                 slots = [s for s in sched.running
                          if not engine._prefill_pending(s)]
-                calls.append((len(slots), int(sum(
-                    int(sched.cache.seq_lens[s]) for s in slots))))
+                lens.append([int(sched.cache.seq_lens[s]) for s in slots])
+                calls.append((len(slots), sum(lens[-1])))
                 return fn(*args)
             return call
         return make_recorded
 
     engine._decode_fn = wrap(engine._decode_fn)
     engine._decode_multi_fn = wrap(engine._decode_multi_fn)
-    return calls
+    return calls, lens
 
 
 def _record_modules(engine):
@@ -116,7 +121,7 @@ def served(tmp_path_factory):
     out = {}
     for horizon in HORIZONS:
         engine = _engine(model, params, horizon)
-        calls = _record_dispatches(engine)
+        calls, lens = _record_dispatches(engine)
         trace_dir = tmp_path_factory.mktemp(f"trace{horizon}")
         jax.profiler.start_trace(str(trace_dir))
         try:
@@ -127,7 +132,8 @@ def served(tmp_path_factory):
         modules = _record_modules(plain)
         untraced = plain.run(list(reqs))
         out[horizon] = dict(
-            engine=engine, calls=calls, spans=_engine_spans(trace_dir),
+            engine=engine, calls=calls, lens=lens,
+            spans=_engine_spans(trace_dir),
             traced={u: r["tokens"] for u, r in traced.items()},
             untraced={u: r["tokens"] for u, r in untraced.items()},
             modules=modules, uids=sorted(r.uid for r in reqs))
@@ -178,6 +184,23 @@ def test_decode_span_args_are_the_schedulers_counts(served, horizon):
     assert got == s["calls"]
     assert {d[3]["horizon"] for d in decodes} == {horizon}
     assert max(slots for slots, _ in got) >= 2      # batches of several
+
+
+@pytest.mark.parametrize("horizon", HORIZONS)
+def test_decode_wait_carries_the_attention_walk(served, horizon):
+    """Each dispatch's ``engine.decode_wait`` carries ``kv_blocks``: the
+    blocks the paged decode-attention kernel walks in each layer of the
+    dispatch's first step, one for every started block of a decoded slot's
+    cache plus the token it writes, at the kernel's own block size."""
+    s = served[horizon]
+    engine, arch = s["engine"], s["engine"].arch
+    block = engine.page_size * ops.pages_per_block(
+        engine.page_size, arch.num_kv_heads * arch.resolved_head_dim
+        * jnp.dtype(arch.dtype).itemsize, engine.max_pages_per_seq)
+    assert engine.decode_block_tokens == block
+    waits = _named(s["spans"], "engine.decode_wait")
+    assert [w[3]["kv_blocks"] for w in waits] == [
+        sum(math.ceil((n + 1) / block) for n in lens) for lens in s["lens"]]
 
 
 @pytest.mark.parametrize("horizon", HORIZONS)

@@ -68,11 +68,18 @@ def _head(sampled, filtered):
 
 
 POOL_SHAPE = ((POOL, PAGE, HKV, D), BF)
+# the offline-decode benchmark cell: 16 slots, 2048-token contexts
+CELL_SLOTS, CELL_MAX_PAGES, CELL_POOL = 16, 128, 2050
+CELL_POOL_SHAPE = ((CELL_POOL, PAGE, HKV, D), BF)
 CASES = {
     "paged_decode_attention": (
         attn.paged_decode_attention_fwd,
         [((SLOTS, HQ, D), BF), POOL_SHAPE, POOL_SHAPE,
          ((SLOTS, MAX_PAGES), I32), ((SLOTS,), I32)]),
+    "paged_decode_attention_cell": (
+        attn.paged_decode_attention_fwd,
+        [((CELL_SLOTS, HQ, D), BF), CELL_POOL_SHAPE, CELL_POOL_SHAPE,
+         ((CELL_SLOTS, CELL_MAX_PAGES), I32), ((CELL_SLOTS,), I32)]),
     "paged_prefill_attention": (
         attn.paged_prefill_attention_fwd,
         [((CHUNK, HQ, D), BF), POOL_SHAPE, POOL_SHAPE, ((MAX_PAGES,), I32),
@@ -99,6 +106,7 @@ CASES = {
 # the compiled HLO and on a profiler trace's ``XLA Ops`` line
 KERNEL_NAMES = {
     "paged_decode_attention": "paged_decode_attention",
+    "paged_decode_attention_cell": "paged_decode_attention",
     "paged_prefill_attention": "paged_prefill_attention",
     "fused_lm_head_greedy": "fused_lm_head",
     "fused_lm_head_sampled": "fused_lm_head",
@@ -155,6 +163,8 @@ def test_benchmark_reads_kernel_functions_through_the_names(compiled_text):
     sys.modules[spec.name] = trace      # its dataclasses look it up
     spec.loader.exec_module(trace)
     for case, kernel in (("paged_decode_attention", "_paged_decode_kernel"),
+                         ("paged_decode_attention_cell",
+                          "_paged_decode_kernel"),
                          ("fused_lm_head_sampled", "_head_kernel")):
         names = trace.kernel_names(compiled_text(case))
         assert names, case
