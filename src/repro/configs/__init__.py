@@ -19,6 +19,8 @@ _MODULES = (
 )
 
 REGISTRY: Dict[str, ArchConfig] = {m.CONFIG.name: m.CONFIG for m in _MODULES}
+# one chip's share of a deployment, beside the whole model it is cut from
+REGISTRY[deepseek_moe_16b.EP4.name] = deepseek_moe_16b.EP4
 
 # the 10 assigned archs (bert-large is the paper's own model, listed separately)
 ASSIGNED: List[str] = [m.CONFIG.name for m in _MODULES[:-1]]
@@ -58,7 +60,8 @@ def smoke_config(name: str) -> ArchConfig:
         kw["moe"] = dataclasses.replace(
             full.moe, num_experts=4,
             top_k=min(2, full.moe.top_k),
-            expert_ff=256 if full.moe.expert_ff else 0)
+            expert_ff=256 if full.moe.expert_ff else 0,
+            held_count=2 if full.moe.held_count else 0)
     if full.ssm is not None:
         kw["ssm"] = dataclasses.replace(
             full.ssm, state_dim=16, head_dim=16, chunk=16)

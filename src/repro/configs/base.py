@@ -36,6 +36,20 @@ class MoEConfig:
     first: int = 0
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # top-k router weights renormalised to sum to 1 (False: the raw softmax
+    # probabilities over all experts, as DeepSeekMoE weights them)
+    norm_topk: bool = True
+    # serving computes every assignment (no capacity, nothing dropped);
+    # the training forward keeps capacity dispatch at ``capacity_factor``
+    dropless: bool = False
+    # routed experts whose weights this layer holds: the first
+    # ``held_count`` (0 = all ``num_experts``). The router still scores all
+    # of them; assignments to experts held elsewhere add nothing here.
+    held_count: int = 0
+
+    @property
+    def num_held(self) -> int:
+        return self.held_count or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +80,7 @@ class ArchConfig:
     # --- nonlinearity / block style ------------------------------------------------
     mlp: str = "swiglu"             # swiglu | gelu
     norm: str = "rmsnorm"           # rmsnorm | layernorm
+    norm_eps: float = 1e-5          # added to the variance in every norm
     pos_emb: str = "rope"           # rope | mrope | learned | sinusoidal | none
     rope_theta: float = 10_000.0
     use_bias: bool = False

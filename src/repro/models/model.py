@@ -85,10 +85,10 @@ class Model:
         # fp32 [B,S,V] logit cotangent (33 GB/device for command-r) instead of
         # the small [B,S,D] activations when forming the head weight grad.
         x = constrain(x, "batch", None, None)
-        x = apply_norm(arch.norm, p["final_norm"], x)
+        x = apply_norm(arch.norm, p["final_norm"], x, arch.norm_eps)
         if arch.mlm_transform:
             x = gelu(dense(x, p["mlm"]["dense"], p["mlm"]["bias"]))
-            x = apply_norm(arch.norm, p["mlm"]["ln"], x)
+            x = apply_norm(arch.norm, p["mlm"]["ln"], x, arch.norm_eps)
         tied = p["embed"]["embedding"] if arch.tie_embeddings else None
         return unembed(p.get("out", {}), x, tied, arch.logit_softcap)
 
@@ -101,7 +101,7 @@ class Model:
         x = x + sinusoidal_positions(s, arch.d_model, dtype)
         positions = jnp.arange(s)[None]
         x, _ = tf.apply_stack(arch, p["enc_blocks"], x, positions, causal=False)
-        return apply_norm(arch.norm, p["enc_final_norm"], x)
+        return apply_norm(arch.norm, p["enc_final_norm"], x, arch.norm_eps)
 
     # ----------------------------------------------------------------- train ----
     def forward(self, p: PyTree, batch: Batch) -> Tuple[jax.Array, jax.Array]:

@@ -8,15 +8,22 @@ Stacked parameters have a leading [num_periods, ...] axis and are consumed by
 ``jax.lax.scan`` (compile-time: one period lowered once — essential for 88-layer
 models on the 512-device dry-run). ``arch.remat`` wraps the period body in
 ``jax.checkpoint`` so live activations are one [B, S, D] residual per period.
+
+Leading dense layers (deepseek-moe's layer 0, ahead of ``moe.first``) are no
+part of any period: such a stack is ``{"lead": <dense stack>, "main":
+<period stack>}`` in parameters, caches and serving state alike, and every
+stack function runs the lead stack, then the main one (``split_lead``).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import ArchConfig
 from ..kernels.fused_layernorm import ops as ln_ops
@@ -27,6 +34,8 @@ from . import ssm as ssm_lib
 from .layers import PyTree, apply_mlp, apply_norm, init_mlp, init_norm
 
 REMAT_POLICY = jax.checkpoint_policies.nothing_saveable
+# the expert counters of a step without MoE layers (``moe.expert_counts``)
+NO_COUNTS = np.zeros((2,), np.int32)
 
 
 # --------------------------------------------------------------------- periods ----
@@ -39,12 +48,35 @@ def period_length(arch: ArchConfig) -> int:
     return 1
 
 
+def lead_layers(arch: ArchConfig) -> int:
+    """Dense layers ahead of the first period: those before ``moe.first``,
+    in whole periods (0 where the first MoE layer lies in the first
+    period, as in an interleaved stack)."""
+    if arch.moe is None:
+        return 0
+    plen = period_length(arch)
+    return arch.moe.first // plen * plen
+
+
+def split_lead(arch: ArchConfig) -> Tuple[ArchConfig, ArchConfig]:
+    """(lead stack's arch: its dense layers; main stack's arch: the
+    layers from the first period on) of a stack with leading layers."""
+    n = lead_layers(arch)
+    lead = dataclasses.replace(arch, num_layers=n, moe=None)
+    main = dataclasses.replace(
+        arch, num_layers=arch.num_layers - n,
+        moe=dataclasses.replace(arch.moe, first=arch.moe.first - n))
+    return lead, main
+
+
 def layer_kinds(arch: ArchConfig) -> Tuple[Tuple[str, bool], ...]:
-    """Per layer within one period: (mixer kind, has_moe)."""
+    """Per layer within one period (of the main stack, past any leading
+    layers): (mixer kind, has_moe)."""
+    lead = lead_layers(arch)
     out = []
     for i in range(period_length(arch)):
-        mixer = "attn" if arch.is_attention_layer(i) else "mamba"
-        out.append((mixer, arch.is_moe_layer(i)))
+        mixer = "attn" if arch.is_attention_layer(lead + i) else "mamba"
+        out.append((mixer, arch.is_moe_layer(lead + i)))
     return tuple(out)
 
 
@@ -84,6 +116,8 @@ def init_period(key, arch: ArchConfig, fuse_qkv: bool, dtype,
 
 def init_stack(key, arch: ArchConfig, fuse_qkv: bool, dtype,
                num_layers: Optional[int] = None, cross: bool = False) -> PyTree:
+    if lead_layers(arch) and num_layers is None:
+        return _init_lead_stack(key, arch, fuse_qkv, dtype, cross)
     plen = period_length(arch) if not cross else 1
     nl = num_layers if num_layers is not None else arch.num_layers
     assert nl % plen == 0, (arch.name, nl, plen)
@@ -94,6 +128,14 @@ def init_stack(key, arch: ArchConfig, fuse_qkv: bool, dtype,
             lambda k: init_period(k, arch, fuse_qkv, dtype, cross))(keys)
     return {f"period_{z}": init_period(keys[z], arch, fuse_qkv, dtype, cross)
             for z in range(nper)}
+
+
+def _init_lead_stack(key, arch: ArchConfig, fuse_qkv: bool, dtype,
+                     cross: bool) -> PyTree:
+    lead, main = split_lead(arch)
+    k1, k2 = jax.random.split(key)
+    return {"lead": init_stack(k1, lead, fuse_qkv, dtype, cross=cross),
+            "main": init_stack(k2, main, fuse_qkv, dtype, cross=cross)}
 
 
 # ------------------------------------------------------------------ block apply ---
@@ -144,18 +186,20 @@ def apply_block(arch: ArchConfig, p: PyTree, x: jax.Array, mixer: str,
         y = mix(x)
         if fused:
             x = ln_ops.fused_residual_layernorm(
-                y, x, p["ln1"]["scale"], p["ln1"].get("bias"), rms=rms)
+                y, x, p["ln1"]["scale"], p["ln1"].get("bias"),
+                eps=arch.norm_eps, rms=rms)
         else:
-            x = apply_norm(arch.norm, p["ln1"], x + y)
+            x = apply_norm(arch.norm, p["ln1"], x + y, arch.norm_eps)
     elif fuse_pre_ln2:
-        y = mix(apply_norm(arch.norm, p["ln1"], x))
+        y = mix(apply_norm(arch.norm, p["ln1"], x, arch.norm_eps))
         h, x = ln_ops.decode_residual_norm(
-            y, x, p["ln2"]["scale"], p["ln2"].get("bias"), kind=arch.norm)
+            y, x, p["ln2"]["scale"], p["ln2"].get("bias"), kind=arch.norm,
+            eps=arch.norm_eps)
     else:
-        x = x + mix(apply_norm(arch.norm, p["ln1"], x))
+        x = x + mix(apply_norm(arch.norm, p["ln1"], x, arch.norm_eps))
 
     if enc_out is not None and "xattn" in p:
-        h = apply_norm(arch.norm, p["ln_x"], x)
+        h = apply_norm(arch.norm, p["ln_x"], x, arch.norm_eps)
         enc_kv = attn_lib.project_enc_kv(arch, p["xattn"], enc_out)
         x = x + attn_lib.apply_cross_attention(arch, p["xattn"], h, enc_kv)
         h = None
@@ -170,12 +214,13 @@ def apply_block(arch: ArchConfig, p: PyTree, x: jax.Array, mixer: str,
             y = apply_mlp(arch.mlp, p["mlp"], x, fused=fused)
         if fused:
             x = ln_ops.fused_residual_layernorm(
-                y, x, p["ln2"]["scale"], p["ln2"].get("bias"), rms=rms)
+                y, x, p["ln2"]["scale"], p["ln2"].get("bias"),
+                eps=arch.norm_eps, rms=rms)
         else:
-            x = apply_norm(arch.norm, p["ln2"], x + y)
+            x = apply_norm(arch.norm, p["ln2"], x + y, arch.norm_eps)
     else:
         if h is None:
-            h = apply_norm(arch.norm, p["ln2"], x)
+            h = apply_norm(arch.norm, p["ln2"], x, arch.norm_eps)
         if "moe" in p:
             y, aux = moe_lib.apply_moe(arch, p["moe"], h)
         else:
@@ -209,6 +254,13 @@ def apply_period(arch: ArchConfig, p: PyTree, x: jax.Array,
 def apply_stack(arch: ArchConfig, stacked: PyTree, x: jax.Array,
                 positions: jax.Array, causal: bool, mrope_positions=None,
                 enc_out=None) -> Tuple[jax.Array, jax.Array]:
+    if lead_layers(arch):
+        lead, main = split_lead(arch)
+        x, aux0 = apply_stack(lead, stacked["lead"], x, positions, causal,
+                              mrope_positions, enc_out)
+        x, aux1 = apply_stack(main, stacked["main"], x, positions, causal,
+                              mrope_positions, enc_out)
+        return x, aux0 + aux1
     body = functools.partial(apply_period, arch, positions=positions,
                              causal=causal, mrope_positions=mrope_positions,
                              enc_out=enc_out)
@@ -233,6 +285,10 @@ def apply_stack(arch: ArchConfig, stacked: PyTree, x: jax.Array,
 
 def init_caches(arch: ArchConfig, batch: int, max_len: int, dtype) -> PyTree:
     """Stacked per-period decode caches (incl. whisper cross-KV)."""
+    if lead_layers(arch):
+        return {part: init_caches(a, batch, max_len, dtype)
+                for part, a in zip(("lead", "main"), split_lead(arch))}
+
     def one_period():
         c: PyTree = {}
         for i, (mixer, _) in enumerate(layer_kinds(arch)):
@@ -272,6 +328,10 @@ def init_serving_state(arch: ArchConfig, num_pages: int, page_size: int,
       slot, not per page, and costs nothing as context grows.
     """
     assert arch.family != "encdec", "paged path has no cross-attention cache"
+    if lead_layers(arch):
+        return {part: init_serving_state(a, num_pages, page_size, num_slots,
+                                         dtype)
+                for part, a in zip(("lead", "main"), split_lead(arch))}
     kinds = layer_kinds(arch)
 
     def layer_state(mixer):
@@ -306,30 +366,42 @@ def _decode_block_mix(arch: ArchConfig, blk: PyTree, x: jax.Array, mix_fn
                       ) -> Tuple[jax.Array, PyTree]:
     """Shared pre/post-norm residual wrapping of a decode mixer.
     ``mix_fn(h) -> (y, new_cache)``."""
-    h = x if arch.post_norm else apply_norm(arch.norm, blk["ln1"], x)
+    eps = arch.norm_eps
+    h = x if arch.post_norm else apply_norm(arch.norm, blk["ln1"], x, eps)
     y, new_c = mix_fn(h)
-    x = apply_norm(arch.norm, blk["ln1"], x + y) if arch.post_norm else x + y
+    x = apply_norm(arch.norm, blk["ln1"], x + y, eps) if arch.post_norm \
+        else x + y
     return x, new_c
 
 
 def _decode_block_ffn(arch: ArchConfig, blk: PyTree, x: jax.Array,
                       tp_axis: Optional[str] = None,
-                      moe_eff_cap: Optional[jax.Array] = None) -> jax.Array:
+                      moe_eff_cap: Optional[jax.Array] = None,
+                      rows: Optional[jax.Array] = None
+                      ) -> Tuple[jax.Array, jax.Array]:
     """Shared MoE/MLP tail of a decode block (no-op for mamba2 blocks).
     ``tp_axis``: serving tensor parallelism — the MLP runs on Megatron
     shards and psums its row-parallel output; a MoE block runs its
     expert-parallel path (experts sharded on the leading axis, one psum on
     the combine). ``moe_eff_cap`` (prefill chunks): the full prompt's
     capacity, so drops match the static engine's full-prompt dispatch
-    rather than a bucket inflated by the chunk's padded shape."""
+    rather than a bucket inflated by the chunk's padded shape. ``rows``
+    [B, S]: the rows that hold a token (MoE layers route only those).
+    Returns ``(x, counts)``: the MoE layer's expert counters
+    (``moe.serve_moe``), zeros for any other block."""
+    counts = NO_COUNTS
     if arch.family == "ssm":
-        return x
-    h = x if arch.post_norm else apply_norm(arch.norm, blk["ln2"], x)
+        return x, counts
+    eps = arch.norm_eps
+    h = x if arch.post_norm else apply_norm(arch.norm, blk["ln2"], x, eps)
     if "moe" in blk:
-        y, _ = moe_lib.apply_moe(arch, blk["moe"], h, tp_axis, moe_eff_cap)
+        y, counts = moe_lib.serve_moe(arch, blk["moe"], h, tp_axis,
+                                      moe_eff_cap, rows)
     else:
         y = apply_mlp(arch.mlp, blk["mlp"], h, tp_axis)
-    return apply_norm(arch.norm, blk["ln2"], x + y) if arch.post_norm else x + y
+    x = apply_norm(arch.norm, blk["ln2"], x + y, eps) if arch.post_norm \
+        else x + y
+    return x, counts
 
 
 def _fused_residual_norm(arch: ArchConfig, ln: PyTree, d: jax.Array,
@@ -338,26 +410,30 @@ def _fused_residual_norm(arch: ArchConfig, ln: PyTree, d: jax.Array,
     one fused pass: ``x += d; h = norm(x)`` -> ``(h, x_new)``. Bit-identical
     to the unfused two-op sequence (see ``kernels.fused_layernorm.ref``)."""
     return ln_ops.decode_residual_norm(d, x, ln["scale"], ln.get("bias"),
-                                       kind=arch.norm)
+                                       kind=arch.norm, eps=arch.norm_eps)
 
 
 def _fused_block_delta(arch: ArchConfig, blk: PyTree, h: jax.Array,
                        tp_axis: Optional[str] = None,
-                       moe_eff_cap: Optional[jax.Array] = None) -> jax.Array:
+                       moe_eff_cap: Optional[jax.Array] = None,
+                       rows: Optional[jax.Array] = None
+                       ) -> Tuple[jax.Array, jax.Array]:
     """MoE/MLP tail of a fused decode block: returns the residual *delta*
     (the add is deferred into the next in-period layer's fused pre-norm, or
-    into the period-end boundary add for the last layer)."""
+    into the period-end boundary add for the last layer) and the expert
+    counters, as ``_decode_block_ffn``."""
     if "moe" in blk:
-        y, _ = moe_lib.apply_moe(arch, blk["moe"], h, tp_axis, moe_eff_cap)
-        return y
-    return apply_mlp(arch.mlp, blk["mlp"], h, tp_axis)
+        return moe_lib.serve_moe(arch, blk["moe"], h, tp_axis, moe_eff_cap,
+                                 rows)
+    return apply_mlp(arch.mlp, blk["mlp"], h, tp_axis), NO_COUNTS
 
 
 def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                         x: jax.Array, page_table: jax.Array,
                         seq_lens: jax.Array, mrope_positions=None,
                         tp_axis: Optional[str] = None,
-                        fused: bool = False) -> Tuple[jax.Array, PyTree]:
+                        fused: bool = False
+                        ) -> Tuple[jax.Array, PyTree, jax.Array]:
     """One period of single-token decode. ``fused=True`` carries the
     residual stream through the period as an ``(x, pending-delta)`` pair:
     every residual-add + pre-norm pair collapses into one
@@ -370,14 +446,18 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
     sequence exactly, and the boundary add sits at the same graph position
     as the unfused path's, which keeps XLA's context-sensitive fusion
     choices identical across the two variants) matches ``fused=False``.
-    Pre-norm stacks only."""
+    Pre-norm stacks only. Returns ``(x, new cache, expert counters)``: the
+    period's MoE counters summed (``moe.serve_moe``; empty slots route
+    nowhere)."""
     if fused:
         assert not arch.post_norm, (arch.name, "fused decode is pre-norm only")
     new_cache: PyTree = {}
+    counts = NO_COUNTS
     d: Optional[jax.Array] = None     # pending in-period residual delta
     # a slot with seq_len 0 is empty or mid-prefill: attention routes its
     # writes to the null page; mamba layers must instead keep their state row
     active = seq_lens > 0
+    rows = active[:, None]
     for i, (mixer, _) in enumerate(layer_kinds(arch)):
         x = constrain(x, "batch", None, None)
         blk = p[f"layer_{i}"]
@@ -391,7 +471,7 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                 arch, blk["mamba"], h, cache[f"layer_{i}"], active)
         if fused:
             if d is None:
-                h = apply_norm(arch.norm, blk["ln1"], x)
+                h = apply_norm(arch.norm, blk["ln1"], x, arch.norm_eps)
             else:
                 h, x = _fused_residual_norm(arch, blk["ln1"], d, x)
             y, new_cache[f"layer_{i}"] = mix(h)
@@ -399,13 +479,15 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                 d = y  # mamba2 blocks have no MLP: y is the pending delta
             else:
                 h2, x = _fused_residual_norm(arch, blk["ln2"], y, x)
-                d = _fused_block_delta(arch, blk, h2, tp_axis)
+                d, c = _fused_block_delta(arch, blk, h2, tp_axis, rows=rows)
+                counts = counts + c
         else:
             x, new_cache[f"layer_{i}"] = _decode_block_mix(arch, blk, x, mix)
-            x = _decode_block_ffn(arch, blk, x, tp_axis)
+            x, c = _decode_block_ffn(arch, blk, x, tp_axis, rows=rows)
+            counts = counts + c
     if fused:
         x = x + d
-    return x, new_cache
+    return x, new_cache, counts
 
 
 def paged_decode_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
@@ -418,27 +500,43 @@ def paged_decode_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
     plain completed residual either way (bit-identical to ``fused=False`` —
     the fused body keeps every residual add at the same graph position, so
     XLA's context-sensitive lowering of the norm reductions matches).
-    Pre-norm stacks only."""
+    Pre-norm stacks only. Returns ``(x, new caches, expert counters)``:
+    int32 [2], the token-to-held-expert assignments of the step's MoE
+    layers and, summed over layers, the held experts with at least one
+    (zeros without MoE layers)."""
     if fused:
         assert not arch.post_norm, (arch.name, "fused decode is pre-norm only")
+    if lead_layers(arch):
+        lead, main = split_lead(arch)
+        x, lc, c0 = paged_decode_stack(lead, stacked["lead"], caches["lead"],
+                                       x, page_table, seq_lens,
+                                       mrope_positions, tp_axis, fused)
+        x, mc, c1 = paged_decode_stack(main, stacked["main"], caches["main"],
+                                       x, page_table, seq_lens,
+                                       mrope_positions, tp_axis, fused)
+        return x, {"lead": lc, "main": mc}, c0 + c1
     if isinstance(stacked, dict) and any(k.startswith("period_") for k in stacked):
         new_caches: PyTree = {}
+        counts = NO_COUNTS
         for z in range(len(stacked)):
-            x, nc = paged_decode_period(
+            x, nc, c = paged_decode_period(
                 arch, stacked[f"period_{z}"], caches[f"period_{z}"],
                 x, page_table, seq_lens, mrope_positions,
                 tp_axis, fused=fused)
             new_caches[f"period_{z}"] = nc
-        return x, new_caches
+            counts = counts + c
+        return x, new_caches, counts
 
-    def scan_body(h, inputs):
+    def scan_body(carry, inputs):
+        h, counts = carry
         period_params, cache = inputs
-        h, new_cache = paged_decode_period(
+        h, new_cache, c = paged_decode_period(
             arch, period_params, cache, h, page_table,
             seq_lens, mrope_positions, tp_axis, fused=fused)
-        return h, new_cache
-    x, new_caches = jax.lax.scan(scan_body, x, (stacked, caches))
-    return x, new_caches
+        return (h, counts + c), new_cache
+    (x, counts), new_caches = jax.lax.scan(scan_body, (x, NO_COUNTS),
+                                           (stacked, caches))
+    return x, new_caches, counts
 
 
 # ---- multi-step compiled decode -------------------------------------------
@@ -514,7 +612,7 @@ def paged_decode_loop(arch: ArchConfig, stacked: PyTree, caches: PyTree,
         i, tok, lens, caches, buf, reasons, ok = carry
         x = embed(tok[:, None])
         if fused_head is not None:
-            x, caches = paged_decode_stack(
+            x, caches, _ = paged_decode_stack(
                 arch, stacked, caches, x, page_table, lens, tp_axis=tp_axis,
                 fused=True)
             new, ok_rows = fused_head(x, lens + 1)
@@ -523,8 +621,9 @@ def paged_decode_loop(arch: ArchConfig, stacked: PyTree, caches: PyTree,
                 # — boolean-identical to scanning the full logits row
                 ok = ok & jnp.all(ok_rows | ~active)
         else:
-            x, caches = paged_decode_stack(arch, stacked, caches, x,
-                                           page_table, lens, tp_axis=tp_axis)
+            x, caches, _ = paged_decode_stack(arch, stacked, caches, x,
+                                              page_table, lens,
+                                              tp_axis=tp_axis)
             logits = unembed(x)
             new = select(logits, lens + 1)
             if probe:
@@ -570,6 +669,8 @@ def paged_prefill_period(arch: ArchConfig, p: PyTree, cache: PyTree,
     # exactly what a full-prompt dispatch would. Longer prompts still
     # re-bucket per chunk (documented caveat).
     moe_eff_cap = moe_cap if arch.moe is not None else None
+    # the chunk's padding past the prompt routes nowhere
+    rows = (start + jnp.arange(x.shape[1]) < total_len)[None]
     for i, (mixer, _) in enumerate(layer_kinds(arch)):
         x = constrain(x, "batch", None, None)
         blk = p[f"layer_{i}"]
@@ -584,7 +685,7 @@ def paged_prefill_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                 total_len)
         if fused:
             if d is None:
-                h = apply_norm(arch.norm, blk["ln1"], x)
+                h = apply_norm(arch.norm, blk["ln1"], x, arch.norm_eps)
             else:
                 h, x = _fused_residual_norm(arch, blk["ln1"], d, x)
             y, new_cache[f"layer_{i}"] = mix(h)
@@ -592,11 +693,12 @@ def paged_prefill_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                 d = y
             else:
                 h2, x = _fused_residual_norm(arch, blk["ln2"], y, x)
-                d = _fused_block_delta(arch, blk, h2, tp_axis, moe_eff_cap)
+                d, _ = _fused_block_delta(arch, blk, h2, tp_axis, moe_eff_cap,
+                                          rows)
         else:
             x, new_cache[f"layer_{i}"] = _decode_block_mix(arch, blk, x, mix)
-            x = _decode_block_ffn(arch, blk, x, tp_axis,
-                                  moe_eff_cap=moe_eff_cap)
+            x, _ = _decode_block_ffn(arch, blk, x, tp_axis,
+                                     moe_eff_cap=moe_eff_cap, rows=rows)
     if fused:
         x = x + d
     return x, new_cache
@@ -632,6 +734,15 @@ def paged_prefill_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
         slot = jnp.zeros((), jnp.int32)
     if fused:
         assert not arch.post_norm, (arch.name, "fused prefill is pre-norm only")
+    if lead_layers(arch):
+        lead, main = split_lead(arch)
+        x, lc = paged_prefill_stack(lead, stacked["lead"], caches["lead"], x,
+                                    page_row, start, total_len, slot, moe_cap,
+                                    mrope_positions, tp_axis, fused)
+        x, mc = paged_prefill_stack(main, stacked["main"], caches["main"], x,
+                                    page_row, start, total_len, slot, moe_cap,
+                                    mrope_positions, tp_axis, fused)
+        return x, {"lead": lc, "main": mc}
     if isinstance(stacked, dict) and any(k.startswith("period_") for k in stacked):
         new_caches: PyTree = {}
         for z in range(len(stacked)):
@@ -674,17 +785,24 @@ def decode_period(arch: ArchConfig, p: PyTree, cache: PyTree, x: jax.Array,
         x, new_cache[f"layer_{i}"] = _decode_block_mix(arch, blk, x, mix)
 
         if "xattn" in blk:
-            h = apply_norm(arch.norm, blk["ln_x"], x)
+            h = apply_norm(arch.norm, blk["ln_x"], x, arch.norm_eps)
             enc_kv = (layer_cache["cross_k"], layer_cache["cross_v"])
             x = x + attn_lib.apply_cross_attention(arch, blk["xattn"], h, enc_kv)
 
-        x = _decode_block_ffn(arch, blk, x)
+        x, _ = _decode_block_ffn(arch, blk, x)
     return x, new_cache
 
 
 def decode_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree, x: jax.Array,
                  positions: jax.Array, mrope_positions=None
                  ) -> Tuple[jax.Array, PyTree]:
+    if lead_layers(arch):
+        lead, main = split_lead(arch)
+        x, lc = decode_stack(lead, stacked["lead"], caches["lead"], x,
+                             positions, mrope_positions)
+        x, mc = decode_stack(main, stacked["main"], caches["main"], x,
+                             positions, mrope_positions)
+        return x, {"lead": lc, "main": mc}
     if isinstance(stacked, dict) and any(k.startswith("period_") for k in stacked):
         new_caches: PyTree = {}
         for z in range(len(stacked)):

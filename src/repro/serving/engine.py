@@ -230,6 +230,8 @@ class ContinuousEngine:
         kinds = tf.layer_kinds(arch)
         self.has_attn = any(m == "attn" for m, _ in kinds)
         self.has_ssm = any(m == "mamba" for m, _ in kinds)
+        # MoE stacks' decode steps also return their expert counters
+        self.has_moe = any(moe for _, moe in kinds)
         if self.has_attn:
             assert arch.num_heads > 0
             assert arch.pos_emb in ("rope", "mrope", "none"), \
@@ -325,9 +327,9 @@ class ContinuousEngine:
             for mixer, _ in kinds) * (arch.num_layers // len(kinds))
         if tp > 1:
             if arch.moe is not None:
-                assert arch.moe.num_experts % tp == 0, \
-                    (f"tp={tp} must divide the expert count "
-                     f"({arch.moe.num_experts}) — expert-parallel layout")
+                assert arch.moe.num_held % tp == 0, \
+                    (f"tp={tp} must divide the held expert count "
+                     f"({arch.moe.num_held}) — expert-parallel layout")
                 if arch.moe.num_shared_experts:
                     shared_ff = (arch.moe.expert_ff or arch.d_ff) \
                         * arch.moe.num_shared_experts
@@ -540,7 +542,7 @@ class ContinuousEngine:
         equality holds because both sides are whole graphs)."""
         arch = self.arch
         x = shardlib.constrain(x, "batch", None, None)
-        hidden = apply_norm(arch.norm, params["final_norm"], x)
+        hidden = apply_norm(arch.norm, params["final_norm"], x, arch.norm_eps)
         if not head_ops.supported():
             tied = params["embed"]["embedding"] if arch.tie_embeddings \
                 else None
@@ -569,7 +571,11 @@ class ContinuousEngine:
     def _decode_impl(self, params, pools, page_table, seq_lens, tokens,
                      seeds, temps, top_ks, top_ps, *, sampled, filtered,
                      fused, fd):
-        """tokens [S] -> (next token [S], new pools). S == num_slots.
+        """tokens [S] -> (next token [S], new pools). S == num_slots. For a
+        MoE stack the token vector carries the step's two expert counters
+        after the S tokens (``[S + 2]``: token-to-held-expert assignments
+        and held experts with at least one, summed over MoE layers), so
+        they reach the host with the tokens.
 
         Selection stays on device — greedy slots take a raw argmax, sampled
         slots a per-slot (seed, position)-keyed categorical draw — so only
@@ -588,18 +594,20 @@ class ContinuousEngine:
         logits round-trip."""
         x = self.model._embed(params, tokens[:, None])
         if fd:
-            x, pools = tf.paged_decode_stack(
+            x, pools, counts = tf.paged_decode_stack(
                 self.arch, params["blocks"], pools, x, page_table, seq_lens,
                 tp_axis=self.tp_axis, fused=True)
             tok, ok = self._fused_head(params, x, seq_lens + 1, seeds,
                                        temps, top_ks, top_ps, sampled=sampled,
                                        filtered=filtered, fused=fused)
+            if self.has_moe:
+                tok = jnp.concatenate([tok, counts])
             if self.sanitize:
                 return tok, pools, jnp.all(ok | (seq_lens == 0))
             return tok, pools
-        x, pools = tf.paged_decode_stack(self.arch, params["blocks"], pools,
-                                         x, page_table, seq_lens,
-                                         tp_axis=self.tp_axis)
+        x, pools, counts = tf.paged_decode_stack(
+            self.arch, params["blocks"], pools, x, page_table, seq_lens,
+            tp_axis=self.tp_axis)
         logits = self.model._logits(params, x)[:, 0]
         if not sampled:
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -614,6 +622,8 @@ class ContinuousEngine:
             positions = seq_lens + 1
             tok = sample_tokens(logits, seeds, positions, temps, top_ks,
                                 top_ps, filtered=filtered, fused=fused)
+        if self.has_moe:
+            tok = jnp.concatenate([tok, counts])
         if self.sanitize:
             # inactive slots read the null page and may legitimately produce
             # junk — probe only rows with at least one real token resident
@@ -969,7 +979,11 @@ class ContinuousEngine:
                         # and slot reuse need this step's tokens before the
                         # next batch can be scheduled
                         next_np = np.asarray(out[0])
-                    with span("engine.emit"):
+                    # a MoE step's expert counters ride after the tokens
+                    moe_args = {} if not self.has_moe else {
+                        "expert_rows": int(next_np[self.num_slots]),
+                        "experts_touched": int(next_np[self.num_slots + 1])}
+                    with span("engine.emit", **moe_args):
                         self.steps += 1
                         self.decode_dispatches += 1
                         self.collective_bytes += \

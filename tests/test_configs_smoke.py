@@ -55,7 +55,7 @@ def test_full_config_matches_assignment(name):
         "command-r-35b": (40, 8192, 64, 8, 22528, 256000),
         "internlm2-1.8b": (24, 2048, 16, 8, 8192, 92544),
         "llama3.2-3b": (28, 3072, 24, 8, 8192, 128256),
-        "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
+        "deepseek-moe-16b": (28, 2048, 16, 16, 10944, 102400),
         "llama4-maverick-400b-a17b": (48, 5120, 40, 8, 8192, 202048),
         "whisper-base": (6, 512, 8, 8, 2048, 51865),
         "mamba2-1.3b": (48, 2048, 0, 0, 0, 50280),

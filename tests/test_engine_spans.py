@@ -8,8 +8,11 @@ and ``kv_tokens`` are the scheduler's own counts, and its
 ``engine.decode_wait`` carries the blocks the decode-attention kernel walks
 (``kv_blocks``); the prefill path and the
 multi-step path record theirs; each jitted program compiles under its own
-name; and the token streams are the same with the profiler on and off.
+name; and the token streams are the same with the profiler on and off. A
+MoE stack's ``engine.emit`` carries the step's expert counters
+(``expert_rows``, ``experts_touched``); a dense stack's carries none.
 """
+import dataclasses
 import glob
 import math
 
@@ -22,6 +25,7 @@ from jax.profiler import ProfileData
 from repro.configs import smoke_config
 from repro.kernels.decode_attention import ops
 from repro.models import build_model
+from repro.models import moe as moe_lib
 from repro.serving import ContinuousEngine, Request
 from repro.serving.sampling import SamplingParams
 
@@ -237,3 +241,72 @@ def test_engine_programs_compile_under_their_names(served):
                      "loop": {"HloModule jit_serve_decode_loop"},
                      "prefill": {"HloModule jit_serve_prefill_chunk"},
                      "copy": {"HloModule jit_serve_copy_page"}}
+
+
+def test_decode_span_args_stay_pinned(served):
+    """``engine.decode`` carries exactly ``slots``, ``kv_tokens`` and
+    ``horizon`` (the benchmark's trace readers pin them), and a dense
+    stack's ``engine.emit`` no expert counters."""
+    for horizon in HORIZONS:
+        spans = served[horizon]["spans"]
+        for d in _named(spans, "engine.decode"):
+            assert set(d[3]) == {"slots", "kv_tokens", "horizon"}, d
+        for e in _named(spans, "engine.emit"):
+            assert not {"expert_rows", "experts_touched"} & set(e[3]), e
+
+
+def test_moe_emit_carries_the_routers_expert_counts(tmp_path, monkeypatch):
+    """For a MoE smoke stack that holds 2 of its 4 routed experts, each
+    ``engine.emit`` carries the step's token-to-held-expert assignments and
+    the held experts with at least one: a host recount from the router's
+    top-k of the slots the step decoded (empty slots route nowhere)."""
+    arch = smoke_config("deepseek-moe-16b-ep4")
+    arch = dataclasses.replace(arch, dtype="float32", param_dtype="float32")
+    moe = arch.moe
+    assert (moe.held_count, moe.num_experts) == (2, 4)
+    model = build_model(arch)
+    params = model.init(jax.random.key(0))
+    engine = _engine(model, params, 1)
+    decoded = []
+    make = engine._decode_fn
+
+    def make_recorded(sampled, filtered):
+        fn = make(sampled, filtered)
+
+        def call(*args):
+            decoded.append([s for s in engine.scheduler.running
+                            if not engine._prefill_pending(s)])
+            return fn(*args)
+        return call
+    engine._decode_fn = make_recorded
+    routed = []
+    route = moe_lib.route
+
+    def route_recorded(logits, moe_cfg):
+        top_w, top_ids = route(logits, moe_cfg)
+        if logits.shape[:2] == (engine.num_slots, 1):     # a decode step
+            jax.debug.callback(lambda ids: routed.append(np.asarray(ids)),
+                               top_ids, ordered=True)
+        return top_w, top_ids
+    monkeypatch.setattr(moe_lib, "route", route_recorded)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.run(_requests(arch.vocab_size))
+    finally:
+        jax.profiler.stop_trace()
+    emits = _named(_engine_spans(tmp_path), "engine.emit")
+    n_moe = arch.num_layers - moe.first
+    assert len(routed) == n_moe * len(decoded) == n_moe * len(emits)
+    want = []
+    for i, slots in enumerate(decoded):
+        rows, touched = 0, 0
+        for ids in routed[i * n_moe:(i + 1) * n_moe]:
+            ids = ids[slots, 0]
+            held = ids[ids < moe.held_count]
+            rows += held.size
+            touched += len(np.unique(held))
+        want.append((rows, touched))
+    got = [(e[3]["expert_rows"], e[3]["experts_touched"]) for e in emits]
+    assert got == want
+    assert sum(r for r, _ in got) > 0
+    assert any(r < len(s) * moe.top_k for (r, _), s in zip(got, decoded))

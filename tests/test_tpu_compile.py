@@ -1,7 +1,8 @@
 """The serving-path Pallas kernels compile for a TPU v5e chip.
 
 Nothing here runs on a chip: each kernel is lowered and compiled at
-internlm2-1.8b serving widths for one chip of a *described* ``v5e:2x2``
+internlm2-1.8b (and, for the expert kernel and MHA decode attention,
+deepseek-moe-16b) serving widths for one chip of a *described* ``v5e:2x2``
 topology (the TPU compiler is installed, no chip is attached). Interpret-mode
 parity tests cannot see what this catches — blocks off the (8, 128) tiling,
 primitives Mosaic has no lowering for, verifier errors — and it costs about
@@ -23,6 +24,7 @@ from repro.kernels.decode_attention import kernel as attn
 from repro.kernels.fused_layernorm import kernel as norm
 from repro.kernels.fused_lm_head import kernel as head
 from repro.kernels.fused_sampling import kernel as filt
+from repro.kernels.moe_experts import ops as experts
 
 # internlm2-1.8b serving widths: 16 query / 8 KV heads of 128, d_model 2048,
 # vocab 92544, 8 decode slots, 16-token pages, 64-token prefill chunks
@@ -71,6 +73,19 @@ POOL_SHAPE = ((POOL, PAGE, HKV, D), BF)
 # the offline-decode benchmark cell: 16 slots, 2048-token contexts
 CELL_SLOTS, CELL_MAX_PAGES, CELL_POOL = 16, 128, 2050
 CELL_POOL_SHAPE = ((CELL_POOL, PAGE, HKV, D), BF)
+# deepseek-moe-16b's share in its cell: MHA (16 KV heads, one query head
+# each), 16 held experts of 2048 x 1408, top 6
+MHA_POOL_SHAPE = ((CELL_POOL, PAGE, HQ, D), BF)
+EXPERTS, EXPERT_FF, TOP_K = 16, 1408, 6
+
+
+def _experts(tokens):
+    return (experts.grouped,
+            [((tokens, DM), BF), ((tokens, TOP_K), I32),
+             ((tokens, TOP_K), F32), ((EXPERTS, DM, EXPERT_FF), BF),
+             ((EXPERTS, DM, EXPERT_FF), BF), ((EXPERTS, EXPERT_FF, DM), BF)])
+
+
 CASES = {
     "paged_decode_attention": (
         attn.paged_decode_attention_fwd,
@@ -80,6 +95,12 @@ CASES = {
         attn.paged_decode_attention_fwd,
         [((CELL_SLOTS, HQ, D), BF), CELL_POOL_SHAPE, CELL_POOL_SHAPE,
          ((CELL_SLOTS, CELL_MAX_PAGES), I32), ((CELL_SLOTS,), I32)]),
+    "paged_decode_attention_mha_cell": (
+        attn.paged_decode_attention_fwd,
+        [((CELL_SLOTS, HQ, D), BF), MHA_POOL_SHAPE, MHA_POOL_SHAPE,
+         ((CELL_SLOTS, CELL_MAX_PAGES), I32), ((CELL_SLOTS,), I32)]),
+    "moe_experts_decode": _experts(CELL_SLOTS),
+    "moe_experts_prefill": _experts(CHUNK),
     "paged_prefill_attention": (
         attn.paged_prefill_attention_fwd,
         [((CHUNK, HQ, D), BF), POOL_SHAPE, POOL_SHAPE, ((MAX_PAGES,), I32),
@@ -107,6 +128,9 @@ CASES = {
 KERNEL_NAMES = {
     "paged_decode_attention": "paged_decode_attention",
     "paged_decode_attention_cell": "paged_decode_attention",
+    "paged_decode_attention_mha_cell": "paged_decode_attention",
+    "moe_experts_decode": "moe_experts",
+    "moe_experts_prefill": "moe_experts",
     "paged_prefill_attention": "paged_prefill_attention",
     "fused_lm_head_greedy": "fused_lm_head",
     "fused_lm_head_sampled": "fused_lm_head",
@@ -151,10 +175,8 @@ def test_kernel_instruction_is_named(name, compiled_text):
                          for f in found), found
 
 
-def test_benchmark_reads_kernel_functions_through_the_names(compiled_text):
-    """The chip benchmark's ``kernel_names`` still maps the named decode
-    attention and LM head instructions to the kernel functions its readers
-    look up: a ``name=`` ending in ``_kernel`` would displace them."""
+def _bench_trace():
+    """The chip benchmark's ``lib/trace.py``."""
     path = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
             / "lib" / "trace.py")
     spec = importlib.util.spec_from_file_location("chipbench_lib_trace",
@@ -162,9 +184,21 @@ def test_benchmark_reads_kernel_functions_through_the_names(compiled_text):
     trace = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = trace      # its dataclasses look it up
     spec.loader.exec_module(trace)
+    return trace
+
+
+def test_benchmark_reads_kernel_functions_through_the_names(compiled_text):
+    """The chip benchmark's ``kernel_names`` still maps the named decode
+    attention, LM head and expert instructions to the kernel functions its
+    readers look up: a ``name=`` ending in ``_kernel`` would displace
+    them."""
+    trace = _bench_trace()
     for case, kernel in (("paged_decode_attention", "_paged_decode_kernel"),
                          ("paged_decode_attention_cell",
                           "_paged_decode_kernel"),
+                         ("paged_decode_attention_mha_cell",
+                          "_paged_decode_kernel"),
+                         ("moe_experts_decode", "_moe_experts_kernel"),
                          ("fused_lm_head_sampled", "_head_kernel")):
         names = trace.kernel_names(compiled_text(case))
         assert names, case
@@ -172,3 +206,49 @@ def test_benchmark_reads_kernel_functions_through_the_names(compiled_text):
             assert key.startswith(KERNEL_NAMES[case]), key
             assert found == kernel, (case, found)
     assert not [n for n in KERNEL_NAMES.values() if n.endswith("_kernel")]
+
+
+def test_moe_decode_step_names_its_kernels(one_chip, no_persistent_cache,
+                                           monkeypatch):
+    """The continuous engine's decode step for deepseek-moe-16b's share
+    (sampled with filters, as the benchmark compiles it; one dense and two
+    MoE layers at the cell's widths), compiled for a v5e with every kernel
+    on: ``kernel_names`` maps its expert instruction to
+    ``_moe_experts_kernel`` and its attention instruction to
+    ``_paged_decode_kernel``, each kernel's own source names and no other
+    kernel's (traces JAX caches can carry those across kernels)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import ops as attn_ops
+    from repro.kernels.fused_layernorm import ops as norm_ops
+    from repro.kernels.fused_lm_head import ops as head_ops
+    from repro.kernels.fused_sampling import ops as filt_ops
+    from repro.models import build_model
+    from repro.serving import ContinuousEngine
+    for mod in (attn_ops, norm_ops, head_ops, filt_ops, experts):
+        monkeypatch.setattr(mod, "supported", lambda: True)
+    arch = dataclasses.replace(get_config("deepseek-moe-16b-ep4"),
+                               num_layers=3)
+    model = build_model(arch)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    engine = ContinuousEngine(model, params, num_slots=CELL_SLOTS,
+                              num_pages=8, page_size=PAGE,
+                              max_seq_len=CELL_MAX_PAGES * PAGE,
+                              sanitize=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    z = jax.ShapeDtypeStruct((CELL_SLOTS,), I32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((CELL_SLOTS, CELL_MAX_PAGES), I32,
+                                 sharding=one_chip)
+    text = engine._decode_fn(True, True).lower(
+        on_chip(params), on_chip(engine.pools), table, z, z,
+        *on_chip(engine._null_sampling)).compile().as_text()
+    names = _bench_trace().kernel_names(text)
+    found = {}
+    for key, kernel in names.items():
+        found.setdefault(key.split(".", 1)[0], set()).add(kernel)
+    assert found["moe_experts"] == {"_moe_experts_kernel"}, names
+    assert found["paged_decode_attention"] == {"_paged_decode_kernel"}, names
