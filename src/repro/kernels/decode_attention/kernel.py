@@ -7,13 +7,16 @@ rows of one sequence are scattered across fixed-size pages of a global pool;
 this kernel gathers them with its own DMAs through a scalar-prefetched page
 table instead of a materialized [B, L, H, D] gather in HBM.
 
-Layout: q [B, Hq, D]; k/v pools [P, page_size, Hkv, D], viewed as
-[P, page_size * Hkv, D] (token-major rows), which stay in HBM
+Layout: q [B, Hq, D]; k/v pools [L, P, page_size, Hkv, D], the stacked
+pools of L layers (one pool [P, page_size, Hkv, D] is L = 1), viewed as
+[L, P, page_size * Hkv, D] (token-major rows), which stay in HBM
 (``memory_space=pl.ANY``): one page of every KV head is one contiguous
 slab. On a TPU this view keeps the pool's own (Hkv, D) tiling, so it
 compiles to a bitcast, where the [P, page_size, Hkv * D] view the prefill
-kernel takes is a copy of the whole pool. page_table [B, max_pages];
-seq_lens [B].
+kernel takes is a copy of the whole pool. A scalar-prefetched layer index
+picks the pool a page is copied from, so the model step hands the kernel
+its whole stack and no layer's pool is ever sliced out of it.
+page_table [B, max_pages]; seq_lens [B].
 
 Walk: grid (B,), one sequence a step. An in-kernel loop takes the
 sequence's ``ceil(seq_len / block)`` blocks of ``block = pages_per_block *
@@ -54,14 +57,16 @@ from .ops import pages_per_block
 NEG_INF = -1e30
 
 
-def _paged_decode_kernel(pt_ref, sl_ref, q_ref, lo_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, acc_ref, m_ref, l_ref, slot_ref,
-                         *, page_size, pages_per_block, group, scale):
+def _paged_decode_kernel(pt_ref, sl_ref, layer_ref, q_ref, lo_ref, k_hbm,
+                         v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, m_ref,
+                         l_ref, slot_ref, *, page_size, pages_per_block,
+                         group, scale):
     b = pl.program_id(0)
     n_seqs = pl.num_programs(0)
     hq, d = q_ref.shape
     max_pages = pt_ref.shape[1]
-    page_rows = k_hbm.shape[1]                  # page_size * Hkv
+    layer = layer_ref[0]
+    page_rows = k_hbm.shape[2]                  # page_size * Hkv
     hkv = page_rows // page_size
     block = pages_per_block * page_size         # tokens
     rows = pages_per_block * page_rows          # buffer rows: token, head
@@ -78,7 +83,8 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, lo_ref, k_hbm, v_hbm, o_ref,
             dst = pl.ds(pl.multiple_of(p * page_rows, page_rows), page_rows)
             for kv, (hbm, buf) in enumerate(((k_hbm, k_buf),
                                              (v_hbm, v_buf))):
-                act(pltpu.make_async_copy(hbm.at[src], buf.at[slot, dst],
+                act(pltpu.make_async_copy(hbm.at[layer, src],
+                                          buf.at[slot, dst],
                                           sems.at[kv, slot]))
             return carry
 
@@ -264,12 +270,16 @@ def paged_prefill_attention_fwd(q, k_pages, v_pages, page_row, start,
     return out.reshape(hkv, c, g, d).transpose(1, 0, 2, 3).reshape(c, hq, d)
 
 
-def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
-                               interpret=False):
-    """q [B, Hq, D]; k/v_pages [P, page, Hkv, D]; page_table [B, max_pages];
-    seq_lens [B] -> [B, Hq, D]. Decode is forward-only: no VJP."""
+def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens,
+                               layer=0, *, interpret=False):
+    """q [B, Hq, D]; k/v_pages [L, P, page, Hkv, D] (or one pool
+    [P, page, Hkv, D], L = 1); page_table [B, max_pages]; seq_lens [B];
+    layer: the pool of the stack to attend to, a scalar in [0, L)
+    -> [B, Hq, D]. Decode is forward-only: no VJP."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
     b, hq, d = q.shape
-    p, page_size, hkv, _ = k_pages.shape
+    n_layers, p, page_size, hkv, _ = k_pages.shape
     g = hq // hkv
     assert hq == g * hkv, (hq, hkv)
     max_pages = page_table.shape[1]
@@ -277,22 +287,22 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
                           max_pages)
     rows = ppb * page_size * hkv
     scale = 1.0 / (d ** 0.5)
-    # [P, page * Hkv, D]: token-major rows, the pool's own tiled layout
-    k_rows = k_pages.reshape(p, page_size * hkv, d)
-    v_rows = v_pages.reshape(p, page_size * hkv, d)
+    # [L, P, page * Hkv, D]: token-major rows, the pool's own tiled layout
+    k_rows = k_pages.reshape(n_layers, p, page_size * hkv, d)
+    v_rows = v_pages.reshape(n_layers, p, page_size * hkv, d)
     # lo[r]: the first query head of row r's KV head
     lo = jnp.asarray((np.arange(rows) % hkv * g)[None], jnp.int32)
 
     kern = functools.partial(_paged_decode_kernel, page_size=page_size,
                              pages_per_block=ppb, group=g, scale=scale)
-    heads = pl.BlockSpec((None, hq, d), lambda bi, pt, sl: (bi, 0, 0))
-    whole = pl.BlockSpec((1, rows), lambda bi, pt, sl: (0, 0))
+    heads = pl.BlockSpec((None, hq, d), lambda bi, pt, sl, ly: (bi, 0, 0))
+    whole = pl.BlockSpec((1, rows), lambda bi, pt, sl, ly: (0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     buf = pltpu.VMEM((2, rows, d), k_pages.dtype)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[heads, whole, pool, pool],
             out_specs=heads,
@@ -311,5 +321,6 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, seq_lens, *,
             dimension_semantics=("arbitrary",)),
         name="paged_decode_attention",
         interpret=pltpu.InterpretParams() if interpret else False,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32), q, lo,
-      k_rows, v_rows)
+    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), q, lo, k_rows,
+      v_rows)

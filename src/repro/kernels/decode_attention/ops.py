@@ -19,7 +19,8 @@ import jax
 # Tokens of keys (and of values) one block of the paged decode walk
 # gathers. On a TPU v5e at internlm2-1.8b widths (8 KV heads of 128, 16-token
 # pages, 16 slots of 128 pages, 24 layer pools sliced per layer as the model
-# step does), the kernel added 46 / 43 us a layer to the slice's own 303 at
+# step did before it handed the kernel the whole stack; these timings date
+# from then), the kernel added 46 / 43 us a layer to the slice's own 303 at
 # 256 / 512 tokens a block with live lengths of 250-700, and 158 / 137 us
 # at full 2048-token contexts; smaller blocks were slower still (a walk
 # that sliced K/V per head took 196 / 119 us alone at 64 / 128 tokens
@@ -50,17 +51,19 @@ def kv_blocks(kv_lens, block_tokens: int) -> int:
     return sum(-(-int(n) // block_tokens) for n in kv_lens)
 
 
-def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens, *,
-                           interpret=False):
-    """q [B, Hq, D]; k/v_pages [P, page, Hkv, D]; page_table [B, max_pages];
-    seq_lens [B] -> [B, Hq, D]."""
+def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens,
+                           layer=0, *, interpret=False):
+    """q [B, Hq, D]; k/v_pages [L, P, page, Hkv, D], a stack of layers'
+    pools (or one pool [P, page, Hkv, D]); page_table [B, max_pages];
+    seq_lens [B]; layer: which pool of the stack -> [B, Hq, D]."""
     if supported() or interpret:
         from . import kernel
         return kernel.paged_decode_attention_fwd(
-            q, k_pages, v_pages, page_table, seq_lens, interpret=interpret)
+            q, k_pages, v_pages, page_table, seq_lens, layer,
+            interpret=interpret)
     from . import ref
     return ref.paged_decode_attention(q, k_pages, v_pages, page_table,
-                                      seq_lens)
+                                      seq_lens, layer)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, page_row, start, total_len,

@@ -36,18 +36,20 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_table: jax.Array,
-                           seq_lens: jax.Array) -> jax.Array:
+                           seq_lens: jax.Array, layer=0) -> jax.Array:
     """Single-query attention against a paged KV cache.
 
-    q [B, Hq, D]; k_pages/v_pages [P, page_size, Hkv, D];
-    page_table [B, max_pages] (physical page ids, 0 = null page);
-    seq_lens [B] = valid cache length per sequence (0 = inactive slot).
-    -> [B, Hq, D]
+    q [B, Hq, D]; k_pages/v_pages [L, P, page_size, Hkv, D], a stack of
+    layers' pools of which ``layer`` is read (or one pool
+    [P, page_size, Hkv, D]); page_table [B, max_pages] (physical page ids,
+    0 = null page); seq_lens [B] = valid cache length per sequence
+    (0 = inactive slot). -> [B, Hq, D]
     """
     b, hq, d = q.shape
-    _, page_size, hkv, _ = k_pages.shape
-    k = k_pages[page_table].reshape(b, -1, hkv, d)
-    v = v_pages[page_table].reshape(b, -1, hkv, d)
+    hkv = k_pages.shape[-2]
+    at = page_table if k_pages.ndim == 4 else (layer, page_table)
+    k = k_pages[at].reshape(b, -1, hkv, d)
+    v = v_pages[at].reshape(b, -1, hkv, d)
     o = naive_attention(q[:, None], k, v, causal=False,
                         kv_len=seq_lens.astype(jnp.int32))
     return o[:, 0]

@@ -460,7 +460,8 @@ def paged_decode_attention_layer(arch: ArchConfig, p: PyTree, x: jax.Array,
                                  cache: PyTree, page_table: jax.Array,
                                  seq_lens: jax.Array,
                                  mrope_positions=None,
-                                 tp_axis: Optional[str] = None
+                                 tp_axis: Optional[str] = None,
+                                 layer: Optional[jax.Array] = None
                                  ) -> Tuple[jax.Array, PyTree]:
     """One-token decode against a paged KV cache.
 
@@ -468,6 +469,13 @@ def paged_decode_attention_layer(arch: ArchConfig, p: PyTree, x: jax.Array,
     seq_lens [B] = tokens already in the cache (the new token's position).
     Inactive slots carry seq_len 0: their K/V lands in the null page and
     their attention output is garbage the engine never reads.
+
+    With ``layer`` (a scalar) the cache is a layer scan's whole stack
+    [L, P, page, Hkv, D] and this layer's pool is its ``layer``-th: the
+    step's rows are scattered at ``[layer, page, offset]`` and the kernel
+    reads the stack at ``layer``, so no pool is sliced out of the stack or
+    written back into it (the scan carries the stack, and XLA keeps the
+    scatter in place on it).
 
     With ``tp_axis`` set this body runs per shard_map shard (Megatron head
     parallelism): the weight shards project only the local Hq/tp query and
@@ -477,15 +485,17 @@ def paged_decode_attention_layer(arch: ArchConfig, p: PyTree, x: jax.Array,
     """
     b, s, _ = x.shape
     assert s == 1, "paged path is single-query decode only"
-    page_size = cache["k"].shape[1]
+    page_size = cache["k"].shape[-3]
     q, k, v = qkv_project(arch, p, x)                        # [B,1,H*,D]
     q, k = position_encode(arch, q, k, seq_lens[:, None], mrope_positions)
     pids = page_table[jnp.arange(b), seq_lens // page_size]  # [B]
     offs = seq_lens % page_size
-    new_k = cache["k"].at[pids, offs].set(k[:, 0])
-    new_v = cache["v"].at[pids, offs].set(v[:, 0])
+    at = (pids, offs) if layer is None else (layer, pids, offs)
+    new_k = cache["k"].at[at].set(k[:, 0])
+    new_v = cache["v"].at[at].set(v[:, 0])
     from ..kernels.decode_attention import ops as pd_ops
     o = pd_ops.paged_decode_attention(q[:, 0], new_k, new_v, page_table,
-                                      seq_lens + 1)
+                                      seq_lens + 1,
+                                      0 if layer is None else layer)
     y = _row_parallel_out(p, o.reshape(b, 1, -1), x.dtype, tp_axis)
     return y, {"k": new_k, "v": new_v}

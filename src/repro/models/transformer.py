@@ -432,7 +432,8 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                         x: jax.Array, page_table: jax.Array,
                         seq_lens: jax.Array, mrope_positions=None,
                         tp_axis: Optional[str] = None,
-                        fused: bool = False
+                        fused: bool = False,
+                        z: Optional[jax.Array] = None
                         ) -> Tuple[jax.Array, PyTree, jax.Array]:
     """One period of single-token decode. ``fused=True`` carries the
     residual stream through the period as an ``(x, pending-delta)`` pair:
@@ -448,7 +449,13 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
     choices identical across the two variants) matches ``fused=False``.
     Pre-norm stacks only. Returns ``(x, new cache, expert counters)``: the
     period's MoE counters summed (``moe.serve_moe``; empty slots route
-    nowhere)."""
+    nowhere).
+
+    With ``z`` (the layer scan's period index) ``cache`` is the scan's whole
+    stack of periods, every leaf with a leading period axis, and comes back
+    as that stack with period ``z``'s state updated: attention pools are
+    written and read at ``z`` in place, mamba slot state (a few KB a slot)
+    is read at ``z`` and set back there."""
     if fused:
         assert not arch.post_norm, (arch.name, "fused decode is pre-norm only")
     new_cache: PyTree = {}
@@ -463,12 +470,18 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
         blk = p[f"layer_{i}"]
 
         def mix(h, blk=blk, i=i, mixer=mixer):
+            c = cache[f"layer_{i}"]
             if mixer == "attn":
                 return attn_lib.paged_decode_attention_layer(
-                    arch, blk["attn"], h, cache[f"layer_{i}"], page_table,
-                    seq_lens, mrope_positions, tp_axis)
-            return ssm_lib.paged_decode_mamba_layer(
-                arch, blk["mamba"], h, cache[f"layer_{i}"], active)
+                    arch, blk["attn"], h, c, page_table, seq_lens,
+                    mrope_positions, tp_axis, layer=z)
+            if z is None:
+                return ssm_lib.paged_decode_mamba_layer(
+                    arch, blk["mamba"], h, c, active)
+            y, new = ssm_lib.paged_decode_mamba_layer(
+                arch, blk["mamba"], h, jax.tree.map(lambda a: a[z], c),
+                active)
+            return y, jax.tree.map(lambda a, n: a.at[z].set(n), c, new)
         if fused:
             if d is None:
                 h = apply_norm(arch.norm, blk["ln1"], x, arch.norm_eps)
@@ -527,16 +540,18 @@ def paged_decode_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
             counts = counts + c
         return x, new_caches, counts
 
-    def scan_body(carry, inputs):
-        h, counts = carry
-        period_params, cache = inputs
-        h, new_cache, c = paged_decode_period(
-            arch, period_params, cache, h, page_table,
-            seq_lens, mrope_positions, tp_axis, fused=fused)
-        return (h, counts + c), new_cache
-    (x, counts), new_caches = jax.lax.scan(scan_body, (x, NO_COUNTS),
-                                           (stacked, caches))
-    return x, new_caches, counts
+    # the stacked state rides in the carry, never in xs / ys: scanned, XLA
+    # would slice each period's pools out of the stack, write them back
+    # into a new one and copy that whole stack out of the loop every step
+    def scan_body(carry, period_params):
+        h, counts, z, caches = carry
+        h, caches, c = paged_decode_period(
+            arch, period_params, caches, h, page_table, seq_lens,
+            mrope_positions, tp_axis, fused=fused, z=z)
+        return (h, counts + c, z + 1, caches), None
+    (x, counts, _, caches), _ = jax.lax.scan(
+        scan_body, (x, NO_COUNTS, jnp.zeros((), jnp.int32), caches), stacked)
+    return x, caches, counts
 
 
 # ---- multi-step compiled decode -------------------------------------------

@@ -14,14 +14,14 @@ from repro.models.attention import naive_attention
 
 
 def _paged_case(seed, b, hq, hkv, d, page_size, num_pages, max_pages,
-                seq_lens, dtype=jnp.float32):
-    """Random q + pools; page table fragmented (shuffled physical ids)."""
+                seq_lens, dtype=jnp.float32, layers=()):
+    """Random q + pools; page table fragmented (shuffled physical ids).
+    ``layers=(L,)`` makes the pools a stack of L layers, each its own."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, hq, d)), dtype)
-    k_pages = jnp.asarray(rng.normal(size=(num_pages, page_size, hkv, d)),
-                          dtype)
-    v_pages = jnp.asarray(rng.normal(size=(num_pages, page_size, hkv, d)),
-                          dtype)
+    pool = tuple(layers) + (num_pages, page_size, hkv, d)
+    k_pages = jnp.asarray(rng.normal(size=pool), dtype)
+    v_pages = jnp.asarray(rng.normal(size=pool), dtype)
     ids = rng.permutation(np.arange(1, num_pages))[:b * max_pages]
     page_table = jnp.asarray(ids.reshape(b, max_pages).astype(np.int32))
     return q, k_pages, v_pages, page_table, jnp.asarray(seq_lens, jnp.int32)
@@ -51,6 +51,8 @@ def _edges(hq, hkv, dtype, shared=False):
                         id=name)
 
 
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["one-pool", "stack"])
 @pytest.mark.parametrize(
     "page_size,hq,hkv,d,max_pages,num_pages,dtype,seq_lens,shared",
     [_small(4, 4, 1), _small(8, 4, 2), _small(16, 4, 4), _small(8, 6, 2),
@@ -58,23 +60,56 @@ def _edges(hq, hkv, dtype, shared=False):
      _edges(16, 8, jnp.float32), _edges(16, 8, jnp.bfloat16),
      _edges(16, 8, jnp.bfloat16, shared=True)])
 def test_kernel_matches_ref(page_size, hq, hkv, d, max_pages, num_pages,
-                            dtype, seq_lens, shared):
+                            dtype, seq_lens, shared, stacked):
     """Live slots match the ref and empty ones are zero, on fragmented page
     tables (shuffled physical ids); ``shared`` has the last slot reuse the
     scattered pages of the one before it (a cached prefix) for half its
-    table."""
+    table. ``stacked`` hands the kernel the case's pools as layer 1 of a
+    two-layer stack whose layer 0 holds other values."""
     q, kp, vp, pt, sl = _paged_case(0, len(seq_lens), hq, hkv, d, page_size,
                                     num_pages, max_pages, seq_lens, dtype)
     if shared:
         pt = pt.at[-1, :max_pages // 2].set(pt[-2, :max_pages // 2])
     o_ref = np.asarray(ref.paged_decode_attention(q, kp, vp, pt, sl),
                        np.float32)
+    pools, layer = (kp, vp), 0
+    if stacked:
+        pools, layer = (jnp.stack([-vp, kp]), jnp.stack([kp, vp])), 1
     o_k = np.asarray(kernel.paged_decode_attention_fwd(
-        q, kp, vp, pt, sl, interpret=True), np.float32)
+        q, *pools, pt, sl, layer, interpret=True), np.float32)
     live = np.asarray(seq_lens) > 0
     atol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(o_k[live], o_ref[live], atol=atol)
     assert not o_k[~live].any()
+
+
+@pytest.mark.parametrize("hq,hkv", [(8, 2), (4, 4)], ids=["gqa", "mha"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_stacked_kernel_reads_its_layer(hq, hkv, layer):
+    """A three-layer stack whose layers hold different values: the kernel
+    (with the layer index traced, as the model step passes it) and the ref
+    both read the pool at ``layer`` and no other. Slots 1 and 3 are
+    inactive with null-page rows, and live rows past their pages hold the
+    null page too."""
+    lens = [9, 0, 16, 0, 27]
+    page, max_pages = 8, 4
+    q, kp, vp, pt, sl = _paged_case(4, len(lens), hq, hkv, 16, page, 24,
+                                    max_pages, lens, layers=(3,))
+    live_pages = -(-sl // page)
+    pt = jnp.where(jnp.arange(max_pages)[None] < live_pages[:, None], pt, 0)
+    want = [np.asarray(ref.paged_decode_attention(q, kp[i], vp[i], pt, sl))
+            for i in range(3)]
+    got = np.asarray(jax.jit(functools.partial(
+        kernel.paged_decode_attention_fwd, interpret=True))(
+            q, kp, vp, pt, sl, jnp.int32(layer)))
+    np.testing.assert_array_equal(
+        np.asarray(ref.paged_decode_attention(q, kp, vp, pt, sl, layer)),
+        want[layer])
+    live = np.asarray(sl) > 0
+    np.testing.assert_allclose(got[live], want[layer][live], atol=1e-5)
+    assert not got[~live].any()
+    for other in {0, 1, 2} - {layer}:
+        assert np.abs(got[live] - want[other][live]).max() > 0.1, other
 
 
 @pytest.mark.parametrize("hkv,dtype", [(1, jnp.float32), (2, jnp.float32),

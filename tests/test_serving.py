@@ -1,6 +1,7 @@
 """Continuous-batching engine: allocator invariants, scheduler recycling,
 and exact greedy parity with the static engine."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -364,3 +365,81 @@ def test_eos_stops_generation_early():
                               eos_id=eos)])
     assert res[0]["tokens"] == ref[:stop]
     assert engine.live_kv_tokens == 0
+
+
+# ------------------------------------------------------- decode-step writes ---
+
+def _unrolled(tree):
+    """A scanned stack (params or decode state, leaves [periods, ...]) as
+    the unrolled ``period_z`` dicts, whose layers hold single pools; a
+    leading dense stack is unrolled already."""
+    if "main" in tree:
+        return {"lead": tree["lead"], "main": _unrolled(tree["main"])}
+    n = jax.tree.leaves(tree)[0].shape[0]
+    return {f"period_{z}": jax.tree.map(lambda a, z=z: a[z], tree)
+            for z in range(n)}
+
+
+def _restacked(tree):
+    if "main" in tree:
+        return {"lead": tree["lead"], "main": _restacked(tree["main"])}
+    periods = [tree[f"period_{z}"] for z in range(len(tree))]
+    return jax.tree.map(lambda *a: jnp.stack(a), *periods)
+
+
+@pytest.mark.parametrize("name,layers", [("internlm2-1.8b", 3),
+                                         ("deepseek-moe-16b-ep4", 3),
+                                         ("jamba-v0.1-52b", 16)])
+def test_decode_step_writes_only_its_rows(name, layers):
+    """One decode step through a scanned stack, which carries the stacked
+    state and hands attention the stack and the period index: in every
+    layer's pool exactly the step's B rows change (an inactive slot's at
+    the null page), in mamba state exactly the active slots' rows, and the
+    hidden state, tokens and new state equal the unrolled stack's, whose
+    layers each read and write a pool of their own."""
+    from repro.models import transformer as tf
+    arch = dataclasses.replace(smoke_config(name), num_layers=layers,
+                               dtype="float32", param_dtype="float32")
+    model = build_model(arch)
+    params = model.init(jax.random.key(0))
+    stacked = params["blocks"]
+    pools = tf.init_serving_state(arch, 12, 4, 3, jnp.float32)
+    leaves, treedef = jax.tree.flatten(pools)
+    keys = jax.random.split(jax.random.key(1), len(leaves))
+    pools = jax.tree.unflatten(treedef, [
+        jax.random.normal(k, l.shape, l.dtype) for k, l in zip(keys, leaves)])
+    table = jnp.asarray([[3, 7, 1], [0, 0, 0], [5, 2, 9]], jnp.int32)
+    lens = jnp.asarray([5, 0, 8], jnp.int32)      # slot 1 inactive
+    x = model._embed(params, jnp.asarray([[11], [0], [42]], jnp.int32))
+
+    step = jax.jit(functools.partial(tf.paged_decode_stack, arch))
+    got_x, got, _ = step(stacked, pools, x, table, lens)
+    un_x, un, _ = step(_unrolled(stacked), _unrolled(pools), x, table, lens)
+    np.testing.assert_allclose(got_x, un_x, rtol=0, atol=1e-5)
+    np.testing.assert_array_equal(
+        jnp.argmax(model._logits(params, got_x), -1),
+        jnp.argmax(model._logits(params, un_x), -1))
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5),
+                 got, _restacked(un))
+
+    pids = np.asarray(table)[np.arange(3), np.asarray(lens) // 4]
+    offs = np.asarray(lens) % 4
+    active = np.asarray(lens) > 0
+    kinds = set()
+    for path, old in jax.tree_util.tree_leaves_with_path(pools):
+        new = functools.reduce(lambda t, k: t[k.key], path, got)
+        kind = path[-1].key
+        kinds.add(kind)
+        if kind in ("k", "v"):
+            # rows [(L,) P, page]: the step's (page, offset) of every layer
+            changed = np.asarray(jnp.any(new != old, axis=(-2, -1)))
+            want = np.zeros(old.shape[:-2], bool)
+            want[..., pids, offs] = True
+            np.testing.assert_array_equal(changed, want, str(path))
+        else:
+            # slot state [L, S, ...]: the active slots of every layer
+            changed = np.asarray(jnp.any(
+                new != old, axis=tuple(range(2, old.ndim))))
+            np.testing.assert_array_equal(
+                changed, np.broadcast_to(active, changed.shape), str(path))
+    assert {"k", "v"} <= kinds

@@ -6,8 +6,10 @@ deepseek-moe-16b) serving widths for one chip of a *described* ``v5e:2x2``
 topology (the TPU compiler is installed, no chip is attached). Interpret-mode
 parity tests cannot see what this catches — blocks off the (8, 128) tiling,
 primitives Mosaic has no lowering for, verifier errors — and it costs about
-two seconds a kernel. The topology is described inside a fixture, never at
-import time, because only one process may hold the TPU library.
+two seconds a kernel. The engine's decode step is compiled whole too, at
+the benchmark cell's sizes, to check that it moves no page pool. The
+topology is described inside a fixture, never at import time, because
+only one process may hold the TPU library.
 """
 import importlib.util
 import os
@@ -70,12 +72,16 @@ def _head(sampled, filtered):
 
 
 POOL_SHAPE = ((POOL, PAGE, HKV, D), BF)
-# the offline-decode benchmark cell: 16 slots, 2048-token contexts
+# decode attention reads a layer scan's stacked pools at a layer index
+STACK_SHAPE = ((3, POOL, PAGE, HKV, D), BF)
+LAYER = ((), I32)
+# the offline-decode benchmark cell: 16 slots, 2048-token contexts, 24
+# layers
 CELL_SLOTS, CELL_MAX_PAGES, CELL_POOL = 16, 128, 2050
-CELL_POOL_SHAPE = ((CELL_POOL, PAGE, HKV, D), BF)
+CELL_STACK_SHAPE = ((24, CELL_POOL, PAGE, HKV, D), BF)
 # deepseek-moe-16b's share in its cell: MHA (16 KV heads, one query head
-# each), 16 held experts of 2048 x 1408, top 6
-MHA_POOL_SHAPE = ((CELL_POOL, PAGE, HQ, D), BF)
+# each) in 11 scanned layers, 16 held experts of 2048 x 1408, top 6
+MHA_STACK_SHAPE = ((11, CELL_POOL, PAGE, HQ, D), BF)
 EXPERTS, EXPERT_FF, TOP_K = 16, 1408, 6
 
 
@@ -89,16 +95,16 @@ def _experts(tokens):
 CASES = {
     "paged_decode_attention": (
         attn.paged_decode_attention_fwd,
-        [((SLOTS, HQ, D), BF), POOL_SHAPE, POOL_SHAPE,
-         ((SLOTS, MAX_PAGES), I32), ((SLOTS,), I32)]),
+        [((SLOTS, HQ, D), BF), STACK_SHAPE, STACK_SHAPE,
+         ((SLOTS, MAX_PAGES), I32), ((SLOTS,), I32), LAYER]),
     "paged_decode_attention_cell": (
         attn.paged_decode_attention_fwd,
-        [((CELL_SLOTS, HQ, D), BF), CELL_POOL_SHAPE, CELL_POOL_SHAPE,
-         ((CELL_SLOTS, CELL_MAX_PAGES), I32), ((CELL_SLOTS,), I32)]),
+        [((CELL_SLOTS, HQ, D), BF), CELL_STACK_SHAPE, CELL_STACK_SHAPE,
+         ((CELL_SLOTS, CELL_MAX_PAGES), I32), ((CELL_SLOTS,), I32), LAYER]),
     "paged_decode_attention_mha_cell": (
         attn.paged_decode_attention_fwd,
-        [((CELL_SLOTS, HQ, D), BF), MHA_POOL_SHAPE, MHA_POOL_SHAPE,
-         ((CELL_SLOTS, CELL_MAX_PAGES), I32), ((CELL_SLOTS,), I32)]),
+        [((CELL_SLOTS, HQ, D), BF), MHA_STACK_SHAPE, MHA_STACK_SHAPE,
+         ((CELL_SLOTS, CELL_MAX_PAGES), I32), ((CELL_SLOTS,), I32), LAYER]),
     "moe_experts_decode": _experts(CELL_SLOTS),
     "moe_experts_prefill": _experts(CHUNK),
     "paged_prefill_attention": (
@@ -208,6 +214,86 @@ def test_benchmark_reads_kernel_functions_through_the_names(compiled_text):
     assert not [n for n in KERNEL_NAMES.values() if n.endswith("_kernel")]
 
 
+def _decode_step_text(config, one_chip, monkeypatch, *, horizon=1,
+                     num_pages=8, donate=False):
+    """The compiled HLO of the continuous engine's decode step (sampled
+    with filters, as the benchmark compiles it) for ``config`` cut to three
+    layers, at the offline-decode cell's slots and context and with every
+    kernel on; ``horizon`` > 1 compiles the multi-step loop. The pools are
+    shapes of ``num_pages`` pages, so no pool is made here. ``donate``
+    donates them, as an engine on a chip does."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import ops as attn_ops
+    from repro.kernels.fused_layernorm import ops as norm_ops
+    from repro.kernels.fused_lm_head import ops as head_ops
+    from repro.kernels.fused_sampling import ops as filt_ops
+    from repro.models import build_model
+    from repro.models import transformer as tf
+    from repro.serving import ContinuousEngine
+    for mod in (attn_ops, norm_ops, head_ops, filt_ops, experts):
+        monkeypatch.setattr(mod, "supported", lambda: True)
+    arch = dataclasses.replace(get_config(config), num_layers=3)
+    model = build_model(arch)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    engine = ContinuousEngine(model, params, num_slots=CELL_SLOTS,
+                              num_pages=8, page_size=PAGE,
+                              max_seq_len=CELL_MAX_PAGES * PAGE,
+                              sanitize=False, decode_steps=horizon)
+    engine._donate_pools = donate
+    pools = jax.eval_shape(lambda: tf.init_serving_state(
+        arch, num_pages, PAGE, CELL_SLOTS, jnp.dtype(arch.dtype)))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    z = jax.ShapeDtypeStruct((CELL_SLOTS,), I32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((CELL_SLOTS, CELL_MAX_PAGES), I32,
+                                 sharding=one_chip)
+    if horizon == 1:
+        fn, per_slot = engine._decode_fn(True, True), 2
+    else:
+        fn, per_slot = engine._decode_multi_fn(True, True), 6
+    text = fn.lower(on_chip(params), on_chip(pools), table,
+                    *(z,) * per_slot,
+                    *on_chip(engine._null_sampling)).compile().as_text()
+    return text, pools
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?) ([\w\-]+)\(", re.M)
+
+
+@pytest.mark.parametrize("horizon", [1, 4], ids=["step", "loop"])
+@pytest.mark.parametrize("config", ["internlm2-1.8b",
+                                    "deepseek-moe-16b-ep4"])
+def test_decode_step_moves_no_pool(config, horizon, one_chip,
+                                   no_persistent_cache, monkeypatch):
+    """At the cell's 2050 pages with the pools donated, the compiled decode
+    step (one step, and the four-step loop) copies, slices and writes back
+    no page pool: no ``copy``, ``copy-start``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` instruction, fused or not, has a whole layer
+    stack of pools or one layer's pool as its shape. The step's rows are
+    scattered into the pools in place, and decode attention reads them
+    where they lie."""
+    text, pools = _decode_step_text(config, one_chip, monkeypatch,
+                                    horizon=horizon, num_pages=CELL_POOL,
+                                    donate=True)
+    shapes = set()
+    for leaf in jax.tree.leaves(pools):
+        assert leaf.dtype == BF and leaf.ndim in (4, 5), leaf
+        # a pool [P, page, Hkv, D], or a stack [L, P, page, Hkv, D]
+        for dims in {leaf.shape, leaf.shape[-4:]}:
+            shapes.add(f"bf16[{','.join(map(str, dims))}]")
+    assert len(shapes) >= 2, shapes
+    moves = {"copy", "copy-start", "dynamic-slice", "dynamic-update-slice"}
+    found = [(name, op, out) for name, out, op in _INSTR.findall(text)
+             if op in moves and any(sh in out.replace(" ", "")
+                                    for sh in shapes)]
+    assert not found, found
+    assert "paged_decode_attention" in text
+
+
 def test_moe_decode_step_names_its_kernels(one_chip, no_persistent_cache,
                                            monkeypatch):
     """The continuous engine's decode step for deepseek-moe-16b's share
@@ -217,35 +303,8 @@ def test_moe_decode_step_names_its_kernels(one_chip, no_persistent_cache,
     ``_moe_experts_kernel`` and its attention instruction to
     ``_paged_decode_kernel``, each kernel's own source names and no other
     kernel's (traces JAX caches can carry those across kernels)."""
-    import dataclasses
-
-    from repro.configs import get_config
-    from repro.kernels.decode_attention import ops as attn_ops
-    from repro.kernels.fused_layernorm import ops as norm_ops
-    from repro.kernels.fused_lm_head import ops as head_ops
-    from repro.kernels.fused_sampling import ops as filt_ops
-    from repro.models import build_model
-    from repro.serving import ContinuousEngine
-    for mod in (attn_ops, norm_ops, head_ops, filt_ops, experts):
-        monkeypatch.setattr(mod, "supported", lambda: True)
-    arch = dataclasses.replace(get_config("deepseek-moe-16b-ep4"),
-                               num_layers=3)
-    model = build_model(arch)
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    engine = ContinuousEngine(model, params, num_slots=CELL_SLOTS,
-                              num_pages=8, page_size=PAGE,
-                              max_seq_len=CELL_MAX_PAGES * PAGE,
-                              sanitize=False)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-    z = jax.ShapeDtypeStruct((CELL_SLOTS,), I32, sharding=one_chip)
-    table = jax.ShapeDtypeStruct((CELL_SLOTS, CELL_MAX_PAGES), I32,
-                                 sharding=one_chip)
-    text = engine._decode_fn(True, True).lower(
-        on_chip(params), on_chip(engine.pools), table, z, z,
-        *on_chip(engine._null_sampling)).compile().as_text()
+    text, _ = _decode_step_text("deepseek-moe-16b-ep4", one_chip,
+                                monkeypatch)
     names = _bench_trace().kernel_names(text)
     found = {}
     for key, kernel in names.items():
