@@ -15,14 +15,10 @@ With it on, the shared prompt's K/V pages are computed once and refcounted
 into every request's page table, so prefill tokens computed, time-to-first-
 token, and peak pages-in-use all drop.
 
-The third section prices stochastic decoding: the same trace served greedy,
-with per-request temperature/top-k/top-p (chat-shaped traffic) through the
-fused sort-free sampler, and once more through the sort-based reference
-filter — so the sampler's overhead shows up as a tok/s delta instead of a
-guess, the fused kernel's win over the twin-sort epilogue is priced in the
-same table, and any fused-vs-reference token divergence
-(``diverged_streams``, pinned at 0 by the determinism contract) fails the
-``check_bench`` gate.
+The third section prices stochastic decoding: the same trace served greedy
+and with per-request temperature/top-k/top-p (chat-shaped traffic) through
+the sort-free sampler, so the sampler's overhead shows up as a tok/s delta
+instead of a guess.
 
 The ``families`` section serves the non-dense architectures the decode-state
 protocol opened up — pure-SSM mamba2, hybrid jamba, and token-choice
@@ -37,15 +33,6 @@ It records tok/s, host dispatches per decode token (hard-bounded in-bench at
 ``< 1.1/N`` — a deterministic count), the host-sync reduction factor, and
 ``diverged_streams`` vs N=1 (the determinism contract pins it at 0).
 ``tools/check_bench.py`` requires this section too.
-
-The ``decode_fusion`` section prices the fused decode residual stream +
-streaming LM-head epilogue: the same mixed trace served with
-``fused_decode`` off and on (plus fused at decode_steps=4), recording the
-fused/unfused throughput ratio, ``diverged_streams`` (the bit-parity
-contract pins fused-vs-unfused mismatches at exactly 0), and the analytic
-per-decode-token HBM bytes the fusion removes on an accelerator (the f32
-``[1, V]`` logits round-trip plus one hidden-width round-trip per fused
-residual+norm site). Required by ``tools/check_bench.py`` as well.
 
 With ``--tp N`` (N > 1; needs N devices — on CPU set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) a fourth section
@@ -176,8 +163,7 @@ def run_static(model, params, requests, batch_size):
 
 
 def run_continuous(model, params, requests, slots, *, prefix_cache=False,
-                   tp=1, fused_sampling=None, warmup=None, decode_steps=1,
-                   spare_pages=0, fused_decode=None):
+                   tp=1, warmup=None, decode_steps=1, spare_pages=0):
     """Serve ``requests`` through one ContinuousEngine sized for the trace.
     Returns (uid -> token_times, full results dict, wall seconds, engine) —
     every section (rates / shared-prefix / sampled / tp) goes through here
@@ -197,9 +183,7 @@ def run_continuous(model, params, requests, slots, *, prefix_cache=False,
                               num_pages=num_pages, page_size=PAGE_SIZE,
                               max_seq_len=max_seq + PAGE_SIZE,
                               prefix_cache=prefix_cache, tp=tp,
-                              fused_sampling=fused_sampling,
-                              decode_steps=decode_steps,
-                              fused_decode=fused_decode)
+                              decode_steps=decode_steps)
     if warmup:
         wres = engine.run(list(warmup))
         werrors = {uid: r["error"] for uid, r in wres.items()
@@ -289,16 +273,13 @@ def run_shared_prefix(model, params, n_requests, slots, results):
 
 
 def run_sampled(model, params, n_requests, slots, results):
-    """Same trace served greedy, sampled (fused filter), and sampled with
-    the sort-based reference filter (per-request temperature/top-k/top-p,
-    seed = uid): tok/s and inter-token latency for each, the fused sampler's
-    relative overhead over greedy, how many streams actually diverged from
-    greedy (at these settings nearly all should), and ``diverged_streams``
-    — fused-vs-reference token mismatches, which the determinism contract
-    pins at exactly 0. Each engine serves a tiny warmup trace before its
-    timed pass (see ``run_continuous``): the overhead percentages price the
-    sampler math per step, not the one-time compile of the sampled jit
-    variants."""
+    """Same trace served greedy and sampled (per-request temperature/top-k/
+    top-p, seed = uid): tok/s and inter-token latency for each, the
+    sampler's relative overhead over greedy, and how many streams actually
+    diverged from greedy (at these settings nearly all should). Each engine
+    serves a tiny warmup trace before its timed pass (see
+    ``run_continuous``): the overhead percentage prices the sampler math per
+    step, not the one-time compile of the sampled jit variants."""
     base = make_trace(n_requests, float("inf"))
     sampled = [Request(uid=r.uid, prompt=r.prompt,
                        max_new_tokens=r.max_new_tokens, arrival=r.arrival,
@@ -320,36 +301,25 @@ def run_sampled(model, params, n_requests, slots, results):
 
     out = {}
     tokens = {}
-    for tag, trace, fused in (("greedy", base, None),
-                              ("sampled", sampled, True),
-                              ("sampled_ref", sampled, False)):
-        times, res, wall, _ = run_continuous(model, params, trace, slots,
-                                             prefix_cache=True,
-                                             fused_sampling=fused,
-                                             warmup=warmup_trace(fused
-                                                                 is not None))
+    for tag, trace in (("greedy", base), ("sampled", sampled)):
+        times, res, wall, _ = run_continuous(
+            model, params, trace, slots, prefix_cache=True,
+            warmup=warmup_trace(trace is sampled))
         tokens[tag] = {uid: r["tokens"] for uid, r in res.items()}
         out[tag] = summarize(times, wall)
         emit(f"serve_{tag}_decode", wall * 1e6 / max(1, n_requests),
              f"{out[tag]['tok_s']:.1f}tok/s_p50={out[tag]['p50_ms']:.1f}ms")
     out["sampler_overhead_pct"] = 100.0 * (
         out["greedy"]["tok_s"] / max(out["sampled"]["tok_s"], 1e-9) - 1.0)
-    out["sampler_overhead_pct_ref"] = 100.0 * (
-        out["greedy"]["tok_s"] / max(out["sampled_ref"]["tok_s"], 1e-9) - 1.0)
     out["diverged_requests"] = sum(
         1 for uid in tokens["greedy"]
         if tokens["greedy"][uid] != tokens["sampled"][uid])
-    out["diverged_streams"] = sum(
-        1 for uid in tokens["sampled"]
-        if tokens["sampled"][uid] != tokens["sampled_ref"][uid])
     print(f"[serving] sampled trace ({n_requests} requests, temp=0.8 "
           f"top_k=40 top_p=0.95): greedy {out['greedy']['tok_s']:.1f} tok/s "
-          f"vs fused {out['sampled']['tok_s']:.1f} tok/s "
-          f"({out['sampler_overhead_pct']:.1f}% sampler overhead, "
-          f"ref {out['sampler_overhead_pct_ref']:.1f}%), "
+          f"vs sampled {out['sampled']['tok_s']:.1f} tok/s "
+          f"({out['sampler_overhead_pct']:.1f}% sampler overhead), "
           f"{out['diverged_requests']}/{n_requests} streams diverged from "
-          f"greedy, {out['diverged_streams']}/{n_requests} fused-vs-ref "
-          f"token mismatches (must be 0)")
+          f"greedy")
     results["sampled"] = out
 
 
@@ -483,100 +453,6 @@ def run_multistep(model, params, n_requests, slots, results):
     results["multistep"] = out
 
 
-def run_decode_fusion(model, params, n_requests, slots, results):
-    """Decode residual-stream fusion section: the same mixed greedy/sampled
-    trace served with ``fused_decode`` off and on (and once more fused at
-    decode_steps=4, composing the two tentpoles). Records tok/s each way,
-    the fused/unfused throughput ratio, ``diverged_streams`` — fused-vs-
-    unfused token mismatches, pinned at exactly 0 by the bit-parity
-    contract — and the ANALYTIC per-decode-token HBM bytes the fusion
-    removes on an accelerator:
-
-    * ``logits_bytes``: the unfused head writes the f32 ``[1, V]`` logits
-      row to HBM and the sampler reads it back; the streaming epilogue
-      carries sampling statistics in accumulators instead (2 * 4 * V_padded
-      bytes per token).
-    * ``residual_bytes``: each fused residual+norm site folds a separate
-      hidden-width add (write + read of one ``[1, D]`` row in model dtype)
-      into the norm's pass. Sites per stack: every layer's ln2 pair for
-      attention/MoE families (the SSM family defers the mixer output
-      directly), plus every layer's ln1 except the first of each period
-      (whose pre-norm has no pending delta to fold).
-
-    On this CPU bench the ratio prices parity, not speed: bit-identity off
-    accelerators is achieved by keeping the fused graph op-identical to the
-    unfused one (see ``engine._fused_head``), so tok/s lands near 1x
-    (typically ~0.8-1.0x — the op-identical CPU graphs buy no memory win
-    and pay a little pair-carry bookkeeping) and the gate is a noise floor;
-    the bytes saved are the accelerator story."""
-    from repro.models.layers import pad_vocab
-    from repro.models.transformer import layer_kinds
-
-    base = make_trace(n_requests, float("inf"))
-    trace = [Request(uid=r.uid, prompt=r.prompt,
-                     max_new_tokens=r.max_new_tokens, arrival=r.arrival,
-                     sampling=chat_sampling(r.uid)
-                     if r.uid % 2 else SamplingParams())
-             for r in base]
-
-    def warmup_trace():
-        rng = np.random.default_rng(555)
-        prompts = rng.integers(5, 500, (2, 72))
-        return [Request(uid=9200 + i, prompt=[int(t) for t in prompts[i]],
-                        max_new_tokens=6,
-                        sampling=chat_sampling(9200 + i) if i
-                        else SamplingParams())
-                for i in range(2)]
-
-    out = {}
-    tokens = {}
-    for tag, fd, n in (("unfused", False, 1), ("fused", True, 1),
-                       ("fused_n4", True, 4)):
-        times, res, wall, engine = run_continuous(
-            model, params, trace, slots, prefix_cache=False, fused_decode=fd,
-            decode_steps=n, warmup=warmup_trace(),
-            spare_pages=(2 * slots if n > 1 else 0))
-        if fd and not engine.fused_decode:
-            raise EngineError("decode_fusion section expects a fusable arch; "
-                              f"engine fell back: "
-                              f"{engine.fused_decode_off_reason}")
-        tokens[tag] = {uid: r["tokens"] for uid, r in res.items()}
-        out[tag] = summarize(times, wall)
-        emit(f"serve_fusion_{tag}", wall * 1e6 / max(1, n_requests),
-             f"{out[tag]['tok_s']:.1f}tok/s_p50={out[tag]['p50_ms']:.1f}ms")
-    out["speedup_vs_unfused"] = (
-        out["fused"]["tok_s"] / max(out["unfused"]["tok_s"], 1e-9))
-    out["diverged_streams"] = sum(
-        1 for tag in ("fused", "fused_n4") for uid in tokens["unfused"]
-        if tokens["unfused"][uid] != tokens[tag][uid])
-
-    arch = model.arch
-    kinds = layer_kinds(arch)
-    n_periods = arch.num_layers // len(kinds)
-    ln1_sites = arch.num_layers - n_periods
-    ln2_sites = 0 if arch.family == "ssm" else arch.num_layers
-    dt_bytes = jnp.dtype(arch.dtype).itemsize
-    logits_bytes = 2 * 4 * pad_vocab(arch.vocab_size)
-    residual_bytes = (ln1_sites + ln2_sites) * 2 * arch.d_model * dt_bytes
-    out["hbm_accounting"] = {
-        "logits_bytes_per_token": logits_bytes,
-        "residual_bytes_per_token": residual_bytes,
-        "fused_norm_sites": ln1_sites + ln2_sites,
-    }
-    out["hbm_bytes_saved_per_token"] = logits_bytes + residual_bytes
-    print(f"[serving] decode-fusion trace ({n_requests} requests, mixed "
-          f"greedy/sampled): unfused {out['unfused']['tok_s']:.1f} tok/s vs "
-          f"fused {out['fused']['tok_s']:.1f} tok/s "
-          f"({out['speedup_vs_unfused']:.2f}x; N=4 fused "
-          f"{out['fused_n4']['tok_s']:.1f} tok/s), "
-          f"{out['diverged_streams']} diverged streams (must be 0), "
-          f"{out['hbm_bytes_saved_per_token'] / 1e3:.1f} KB HBM saved per "
-          f"decode token on-accelerator "
-          f"({out['hbm_accounting']['fused_norm_sites']} fused norm sites + "
-          f"the [1, V] logits row)")
-    results["decode_fusion"] = out
-
-
 def run_tp(model, params, n_requests, slots, tp, results):
     """Tensor-parallel section: the same mixed greedy/sampled trace served
     at tp=1 and tp=N. Streams must not diverge (head-sharded TP is an
@@ -629,8 +505,7 @@ def run_tp(model, params, n_requests, slots, tp, results):
 
 def run(arch_name="llama3.2-3b", n_requests=16, slots=4,
         rates=(4.0, 16.0, float("inf")), json_path=None, tp=1,
-        tp_only=False, sampled_only=False, multistep_only=False,
-        decode_fusion_only=False) -> dict:
+        tp_only=False, sampled_only=False, multistep_only=False) -> dict:
     arch = smoke_config(arch_name)
     model = build_model(arch)
     params = model.init(jax.random.key(0))
@@ -643,15 +518,12 @@ def run(arch_name="llama3.2-3b", n_requests=16, slots=4,
         run_sampled(model, params, n_requests, slots, results)
     elif multistep_only:
         run_multistep(model, params, n_requests, slots, results)
-    elif decode_fusion_only:
-        run_decode_fusion(model, params, n_requests, slots, results)
     elif not tp_only:
         run_rates(model, params, n_requests, slots, rates, results)
         run_shared_prefix(model, params, n_requests, slots, results)
         run_sampled(model, params, n_requests, slots, results)
         run_families(n_requests, slots, results)
         run_multistep(model, params, n_requests, slots, results)
-        run_decode_fusion(model, params, n_requests, slots, results)
     if tp > 1:
         run_tp(model, params, n_requests, slots, tp, results)
     # jit-cache closure census across every engine the run built: ``excess``
@@ -689,7 +561,7 @@ def main() -> None:
                          "single-device sections the tier1 job covers")
     ap.add_argument("--sampled-only", action="store_true",
                     help="run ONLY the sampled-traffic section (greedy vs "
-                         "fused vs reference filter) — the nightly CI job "
+                         "sampled) — the nightly CI job "
                          "uses this with a larger trace to watch the "
                          "sampler tax without re-running the full bench")
     ap.add_argument("--multistep-only", action="store_true",
@@ -697,28 +569,19 @@ def main() -> None:
                          "(decode_steps N in {1,4,16}) — the nightly CI job "
                          "uses this with a larger trace to watch host-sync "
                          "reduction without re-running the full bench")
-    ap.add_argument("--decode-fusion-only", action="store_true",
-                    help="run ONLY the decode residual-stream fusion section "
-                         "(fused_decode off/on + fused at decode_steps=4) — "
-                         "the nightly CI job uses this with a larger trace "
-                         "to watch the fused/unfused ratio and the pinned "
-                         "zero-divergence gate without re-running the full "
-                         "bench")
     ap.add_argument("--json", default="",
                     help="also write the full results dict to this path")
     args = ap.parse_args()
     if args.tp_only and args.tp <= 1:
         ap.error("--tp-only requires --tp > 1")
-    if sum((args.tp_only, args.sampled_only, args.multistep_only,
-            args.decode_fusion_only)) > 1:
-        ap.error("--tp-only/--sampled-only/--multistep-only/"
-                 "--decode-fusion-only are mutually exclusive")
+    if sum((args.tp_only, args.sampled_only, args.multistep_only)) > 1:
+        ap.error("--tp-only/--sampled-only/--multistep-only are mutually "
+                 "exclusive")
     print("name,us_per_call,derived")
     try:
         run(args.arch, args.requests, args.slots, json_path=args.json or None,
             tp=args.tp, tp_only=args.tp_only, sampled_only=args.sampled_only,
-            multistep_only=args.multistep_only,
-            decode_fusion_only=args.decode_fusion_only)
+            multistep_only=args.multistep_only)
     except Exception as e:  # noqa: BLE001 — any engine failure must fail CI
         # no JSON is written on this path: a partial artifact uploaded by CI
         # reads as a healthy run with silently missing sections

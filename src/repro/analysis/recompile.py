@@ -1,7 +1,7 @@
 """Static recompilation auditor for the continuous serving engine.
 
 The engine's jit cache is *lazy per variant* (decode/prefill × sampled ×
-filtered × fused × final): each variant compiles once, on the first traffic that
+filtered × final): each variant compiles once, on the first traffic that
 needs it, and the whole serving design rests on the cache then being
 **closed** — fixed batch shapes, fixed chunk shapes, static flags — so
 steps 2..N of any trace add zero new traces. That closure is also exactly
@@ -21,17 +21,14 @@ retrace — e.g. mutating the chunk size mid-trace — fails loudly
 (``tests/test_recompile_audit.py`` seeds exactly that).
 
 Coverage matrix (``python -m repro.analysis.recompile`` runs all of it; the
-tests pin representative cells). Every row runs once per fused-decode
-setting (``fd`` ∈ {True, False} — both halves of the bit-parity contract
-must keep a closed cache):
+tests pin representative cells):
 
-    every servable family   × tp ∈ {1, ..devices}  × fused sampler × N=1
-    dense                   × tp ∈ {1, ..devices}  × ref sampler   × N=1
-    every servable family   × tp=1                 × fused sampler × N=4
-    dense                   × tp ∈ {2, ..devices}  × fused sampler × N=4
+    every servable family   × tp ∈ {1, ..devices}  × N=1
+    every servable family   × tp=1                 × N=4
+    dense                   × tp ∈ {2, ..devices}  × N=4
 
 The N=4 rows audit the multi-step compiled decode loop: its decode keys
-gain the horizon element (``("decode", sampled, filtered, fused, fd, N)``)
+gain the horizon element (``("decode", sampled, filtered, N)``)
 and the per-dispatch predicate arrays (active mask, budgets, page capacity,
 EOS ids) must not perturb the traced signature. tp > 1 audits shard-map
 the abstract step over a real device mesh, so they need
@@ -191,22 +188,16 @@ def _audit_requests(vocab: int, seed: int = 0) -> List[Request]:
     return reqs
 
 
-def audit_family(family: str, *, tp: int = 1, fused_sampling: bool = True,
-                 decode_steps: int = 1, fused_decode: Optional[bool] = None,
+def audit_family(family: str, *, tp: int = 1, decode_steps: int = 1,
                  requests: Optional[Sequence[Request]] = None) -> AuditReport:
     """Abstract-serve one family's smoke arch and assert cache closure.
 
     The pool is deliberately starved (2 slots, 12 pages) so the trace also
     covers page growth, prefix eviction, CoW tail copies, and forced-replay
     preemption — the paths where a retrace bug would hide behind rare
-    traffic. ``fused_sampling=False`` audits the sort-based reference
-    filter's variants (same key arity, ``fused`` element pinned False).
-    ``decode_steps > 1`` audits the multi-step compiled decode loop's
-    variants instead (decode keys gain the horizon element; the per-dispatch
-    predicate arrays must not perturb the traced signature).
-    ``fused_decode`` pins the fused-decode flag (None = the engine's
-    default resolution), auditing the fused residual-stream + streaming-head
-    step variants — same key arity, ``fd`` element pinned."""
+    traffic. ``decode_steps > 1`` audits the multi-step compiled decode
+    loop's variants instead (decode keys gain the horizon element; the
+    per-dispatch predicate arrays must not perturb the traced signature)."""
     arch_name = FAMILY_ARCHS[family]
     arch = smoke_config(arch_name)
     if tp > 1 and arch.num_kv_heads % tp and tp % arch.num_kv_heads:
@@ -215,9 +206,7 @@ def audit_family(family: str, *, tp: int = 1, fused_sampling: bool = True,
     params = model.init(jax.random.key(0))
     engine = AuditEngine(model, params, num_slots=2, num_pages=12,
                          page_size=4, max_seq_len=40, tp=tp,
-                         fused_sampling=fused_sampling,
-                         decode_steps=decode_steps,
-                         fused_decode=fused_decode)
+                         decode_steps=decode_steps)
     reqs = list(requests) if requests is not None \
         else _audit_requests(arch.vocab_size)
     results = engine.run(reqs)
@@ -238,31 +227,20 @@ def main() -> int:
         tps.append(2)
     print(f"[recompile-audit] families={list(SERVABLE_FAMILIES)} tps={tps}")
     failed = 0
-    # dense also audits the sort-based reference filter (fused off) so BOTH
-    # filtered-variant implementations prove closure, not just the default;
     # every family re-audits at decode_steps=4 so the multi-step compiled
     # decode loop's horizon-keyed variants prove closure too (dense also at
-    # every tp the mesh supports). Every (family, tp) cell audits BOTH
-    # fused-decode settings: the fused residual-stream + streaming-head
-    # variants and the reference variants are separate jit keys (the ``fd``
-    # element) and each must keep a closed cache.
-    jobs = [(f, tp, True, 1, fd) for tp in tps for f in SERVABLE_FAMILIES
-            for fd in (True, False)]
-    jobs += [("dense", tp, False, 1, None) for tp in tps]
-    jobs += [(f, 1, True, 4, fd) for f in SERVABLE_FAMILIES
-             for fd in (True, False)]
-    jobs += [("dense", tp, True, 4, None) for tp in tps if tp > 1]
-    for family, tp, fused, steps, fd in jobs:
+    # every tp the mesh supports)
+    jobs = [(f, tp, 1) for tp in tps for f in SERVABLE_FAMILIES]
+    jobs += [(f, 1, 4) for f in SERVABLE_FAMILIES]
+    jobs += [("dense", tp, 4) for tp in tps if tp > 1]
+    for family, tp, steps in jobs:
         try:
-            report = audit_family(family, tp=tp, fused_sampling=fused,
-                                  decode_steps=steps, fused_decode=fd)
+            report = audit_family(family, tp=tp, decode_steps=steps)
         except AuditError as e:
             failed += 1
             print(f"FAIL {e}")
         else:
-            tag = "" if fused else " [sampler=ref]"
-            tag += f" [decode_steps={steps}]" if steps > 1 else ""
-            tag += "" if fd is None else f" [fused_decode={fd}]"
+            tag = f" [decode_steps={steps}]" if steps > 1 else ""
             print(f"ok   {report.summary()}{tag}")
     if failed:
         print(f"[recompile-audit] {failed} audit(s) FAILED — the jit cache "

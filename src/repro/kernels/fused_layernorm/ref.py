@@ -10,9 +10,10 @@ Two flavors with different numerics contracts:
   ``models.layers._apply_norm`` / ``models.ssm._gated_rmsnorm`` operation
   for operation (duplicated here rather than imported to keep the kernels
   layer import-cycle-free), so the fused decode stack is BIT-identical to
-  the unfused one — the property the engine's ``fused_decode`` flag
-  guarantees. Input shapes are preserved (no flattening) so the fp32 row
-  reductions see exactly the shapes the unfused path reduces.
+  the unfused one — the property that lets the serving engine's paged
+  stack emit the static engine's tokens. Input shapes are preserved (no
+  flattening) so the fp32 row reductions see exactly the shapes the
+  unfused path reduces.
 """
 from __future__ import annotations
 

@@ -31,8 +31,8 @@ V / tp / RED_TILE]`` which every shard refolds in canonical global tile
 order. (A psum of per-shard folded totals would NOT be bit-exact — float
 folds do not reassociate — which is why partials cross the wire instead.)
 Requires ``(V / tp) % RED_TILE == 0`` so shard boundaries land on canonical
-tile boundaries; the engine checks :func:`tp_fusable` and serves the
-unfused path otherwise.
+tile boundaries; the engine checks :func:`tp_fusable` and materializes the
+logits otherwise.
 """
 from __future__ import annotations
 

@@ -4,8 +4,8 @@ top-k / top-p filtering semantics are defined.
 This module replaces the twin ``jnp.sort`` code paths that used to live
 inline in ``serving.sampling`` (one full-vocab sort for the top-k threshold,
 a second for the nucleus cumsum). It is the parity oracle the fused kernel
-is tested against bit-for-bit, and the fallback the serving sampler keeps
-available (``sample_tokens(..., fused=False)``).
+is tested against bit-for-bit (``serving.sampling.sample_tokens`` always
+runs the fused filter).
 
 Canonical filtering semantics (shared with ``ops.py`` / ``kernel.py``)
 ----------------------------------------------------------------------

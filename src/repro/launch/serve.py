@@ -36,17 +36,9 @@ import numpy as np
 
 from ..configs import get_config, smoke_config
 from ..models import build_model
-from ..serving.sampling import (SamplingParams, fused_sampling_enabled,
-                                sample_tokens)
+from ..serving.sampling import SamplingParams, sample_tokens
 from ..train.steps import serve_params
 from .compile_cache import enable_compile_cache
-
-
-def _fused(args) -> bool:
-    """--sampler beats the REPRO_FUSED_SAMPLING env default."""
-    if args.sampler is not None:
-        return args.sampler == "fused"
-    return fused_sampling_enabled()
 
 
 def _request_seed(args, i: int) -> int:
@@ -80,17 +72,14 @@ def _run_static(model, params, args, arch) -> dict:
     decode = jax.jit(model.decode_step, donate_argnums=(1,))
     if args.temperature > 0:
         filtered = args.top_k > 0 or args.top_p < 1.0
-        fused = _fused(args) and filtered
-        sample = jax.jit(sample_tokens,
-                         static_argnames=("filtered", "fused"))
+        sample = jax.jit(sample_tokens, static_argnames=("filtered",))
         seeds, temps, top_ks, top_ps = _sampling_arrays(args, b)
 
         def pick(logits, pos):
             # the sampler folds each request's stream position into its key,
             # matching the continuous engine draw for draw
             return sample(logits, seeds, jnp.full((b,), pos, jnp.int32),
-                          temps, top_ks, top_ps, filtered=filtered,
-                          fused=fused)
+                          temps, top_ks, top_ps, filtered=filtered)
     else:
         # greedy stays a pure argmax — no sampler sorts/keys on the default
         # path (bit-identical by the sampler's temperature-0 contract, and
@@ -140,9 +129,7 @@ def _run_continuous(model, params, args, arch) -> dict:
                               max_seq_len=max_seq + args.page_size,
                               prefix_cache=args.prefix_cache,
                               prefill_chunk=args.prefill_chunk or None,
-                              tp=args.tp, fused_sampling=_fused(args),
-                              decode_steps=args.decode_steps,
-                              fused_decode=args.fused_decode)
+                              tp=args.tp, decode_steps=args.decode_steps)
     reqs = [Request(uid=i, prompt=[int(t) for t in prompt[i]],
                     max_new_tokens=glen,
                     sampling=SamplingParams(temperature=args.temperature,
@@ -215,12 +202,6 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0,
                     help="base PRNG seed: params init + per-request "
                          "sampling seeds (--seed + request index)")
-    ap.add_argument("--sampler", choices=("fused", "ref"), default=None,
-                    help="top-k/top-p filter implementation: the sort-free "
-                         "streaming kernel (default) or the sort-based "
-                         "reference. Token streams are bit-identical; 'ref' "
-                         "is a fallback/debugging path (default from "
-                         "REPRO_FUSED_SAMPLING, unset = fused)")
     # continuous-engine knobs
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree over a 1-D device mesh "
@@ -249,15 +230,6 @@ def main(argv=None) -> dict:
                          "EOS/budget/page exhaustion, cutting host syncs by "
                          "~N while keeping token streams bit-identical "
                          "(continuous engine only)")
-    ap.add_argument("--fused-decode", action=argparse.BooleanOptionalAction,
-                    default=None,
-                    help="fused decode residual stream + streaming LM-head "
-                         "epilogue (no [S, V] logits buffer; token streams "
-                         "bit-identical either way). Default from "
-                         "REPRO_FUSED_DECODE (unset = on); auto-falls back "
-                         "with a recorded reason for post-norm stacks, MLM "
-                         "heads, and non-tile-aligned TP vocab shards "
-                         "(continuous engine only)")
     args = ap.parse_args(argv)
     # one validation for BOTH engines (the static path reads raw args, so
     # without this it would silently reinterpret e.g. --top-p 0)
@@ -276,9 +248,6 @@ def main(argv=None) -> dict:
     if args.decode_steps > 1 and args.engine != "continuous":
         ap.error("--decode-steps requires --engine continuous (the static "
                  "driver decodes in lock-step, one token per dispatch)")
-    if args.fused_decode is not None and args.engine != "continuous":
-        ap.error("--fused-decode requires --engine continuous (the static "
-                 "driver always materializes full logits)")
 
     arch = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     assert not arch.bidirectional, "encoder-only archs have no decode step"

@@ -142,10 +142,10 @@ def _init_lead_stack(key, arch: ArchConfig, fuse_qkv: bool, dtype,
 
 def fused_blocks_enabled() -> bool:
     """Training/prefill block fusion (``fused_residual_layernorm`` +
-    ``bias_gelu``) — default OFF. Unlike fused *decode* this is a
-    tolerance-parity path, not a bit-parity one: the residual+norm kernel
-    adds in fp32 where the unfused block adds in model dtype, so bf16
-    training losses match to rounding, not bitwise."""
+    ``bias_gelu``) — default OFF. Unlike the paged serving stack's
+    residual fusion this is a tolerance-parity path, not a bit-parity one:
+    the residual+norm kernel adds in fp32 where the unfused block adds in
+    model dtype, so bf16 training losses match to rounding, not bitwise."""
     return os.environ.get("REPRO_FUSED_BLOCKS", "0") == "1"
 
 
@@ -364,7 +364,7 @@ def init_paged_caches(arch: ArchConfig, num_pages: int, page_size: int,
 
 def _decode_block_mix(arch: ArchConfig, blk: PyTree, x: jax.Array, mix_fn
                       ) -> Tuple[jax.Array, PyTree]:
-    """Shared pre/post-norm residual wrapping of a decode mixer.
+    """Pre/post-norm residual wrapping of a static-engine decode mixer.
     ``mix_fn(h) -> (y, new_cache)``."""
     eps = arch.norm_eps
     h = x if arch.post_norm else apply_norm(arch.norm, blk["ln1"], x, eps)
@@ -374,34 +374,20 @@ def _decode_block_mix(arch: ArchConfig, blk: PyTree, x: jax.Array, mix_fn
     return x, new_c
 
 
-def _decode_block_ffn(arch: ArchConfig, blk: PyTree, x: jax.Array,
-                      tp_axis: Optional[str] = None,
-                      moe_eff_cap: Optional[jax.Array] = None,
-                      rows: Optional[jax.Array] = None
-                      ) -> Tuple[jax.Array, jax.Array]:
-    """Shared MoE/MLP tail of a decode block (no-op for mamba2 blocks).
-    ``tp_axis``: serving tensor parallelism — the MLP runs on Megatron
-    shards and psums its row-parallel output; a MoE block runs its
-    expert-parallel path (experts sharded on the leading axis, one psum on
-    the combine). ``moe_eff_cap`` (prefill chunks): the full prompt's
-    capacity, so drops match the static engine's full-prompt dispatch
-    rather than a bucket inflated by the chunk's padded shape. ``rows``
-    [B, S]: the rows that hold a token (MoE layers route only those).
-    Returns ``(x, counts)``: the MoE layer's expert counters
-    (``moe.serve_moe``), zeros for any other block."""
-    counts = NO_COUNTS
+def _decode_block_ffn(arch: ArchConfig, blk: PyTree, x: jax.Array
+                      ) -> jax.Array:
+    """MoE/MLP tail of a static-engine decode block (no-op for mamba2
+    blocks)."""
     if arch.family == "ssm":
-        return x, counts
+        return x
     eps = arch.norm_eps
     h = x if arch.post_norm else apply_norm(arch.norm, blk["ln2"], x, eps)
     if "moe" in blk:
-        y, counts = moe_lib.serve_moe(arch, blk["moe"], h, tp_axis,
-                                      moe_eff_cap, rows)
+        y, _ = moe_lib.serve_moe(arch, blk["moe"], h)
     else:
-        y = apply_mlp(arch.mlp, blk["mlp"], h, tp_axis)
-    x = apply_norm(arch.norm, blk["ln2"], x + y, eps) if arch.post_norm \
+        y = apply_mlp(arch.mlp, blk["mlp"], h)
+    return apply_norm(arch.norm, blk["ln2"], x + y, eps) if arch.post_norm \
         else x + y
-    return x, counts
 
 
 def _fused_residual_norm(arch: ArchConfig, ln: PyTree, d: jax.Array,
@@ -418,10 +404,17 @@ def _fused_block_delta(arch: ArchConfig, blk: PyTree, h: jax.Array,
                        moe_eff_cap: Optional[jax.Array] = None,
                        rows: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array]:
-    """MoE/MLP tail of a fused decode block: returns the residual *delta*
-    (the add is deferred into the next in-period layer's fused pre-norm, or
-    into the period-end boundary add for the last layer) and the expert
-    counters, as ``_decode_block_ffn``."""
+    """MoE/MLP tail of a paged decode or prefill block: returns the
+    residual *delta* (the add is deferred into the next in-period layer's
+    fused pre-norm, or into the period-end boundary add for the last layer)
+    and the MoE layer's expert counters (``moe.serve_moe``), zeros for an
+    MLP. ``tp_axis``: serving tensor parallelism — the MLP runs on Megatron
+    shards and psums its row-parallel output; a MoE block runs its
+    expert-parallel path (experts sharded on the leading axis, one psum on
+    the combine). ``moe_eff_cap`` (prefill chunks): the full prompt's
+    capacity, so drops match the static engine's full-prompt dispatch
+    rather than a bucket inflated by the chunk's padded shape. ``rows``
+    [B, S]: the rows that hold a token (MoE layers route only those)."""
     if "moe" in blk:
         return moe_lib.serve_moe(arch, blk["moe"], h, tp_axis, moe_eff_cap,
                                  rows)
@@ -432,32 +425,27 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                         x: jax.Array, page_table: jax.Array,
                         seq_lens: jax.Array, mrope_positions=None,
                         tp_axis: Optional[str] = None,
-                        fused: bool = False,
                         z: Optional[jax.Array] = None
                         ) -> Tuple[jax.Array, PyTree, jax.Array]:
-    """One period of single-token decode. ``fused=True`` carries the
-    residual stream through the period as an ``(x, pending-delta)`` pair:
-    every residual-add + pre-norm pair collapses into one
-    ``decode_residual_norm`` pass (the mixer add at each ln2 site; the
-    previous layer's MLP delta at each ln1 site of a multi-layer period),
-    so the residual stream makes one HBM round-trip per fused site instead
-    of three (add-out, norm-read, delta-write). The pending delta is folded
-    by a plain add before returning — the period's carry interface (and,
-    bitwise, its result: the fused kernels duplicate the unfused op
-    sequence exactly, and the boundary add sits at the same graph position
-    as the unfused path's, which keeps XLA's context-sensitive fusion
-    choices identical across the two variants) matches ``fused=False``.
-    Pre-norm stacks only. Returns ``(x, new cache, expert counters)``: the
-    period's MoE counters summed (``moe.serve_moe``; empty slots route
-    nowhere).
+    """One period of single-token decode. The residual stream rides
+    through the period as an ``(x, pending-delta)`` pair: every
+    residual-add + pre-norm pair collapses into one ``decode_residual_norm``
+    pass (the mixer add at each ln2 site; the previous layer's MLP delta at
+    each ln1 site of a multi-layer period), so the residual stream makes
+    one HBM round-trip per fused site instead of three (add-out, norm-read,
+    delta-write). The pending delta is folded by a plain add before
+    returning, so the period's carry is the completed residual. The fused
+    kernels duplicate the plain add + norm op sequence exactly
+    (``kernels.fused_layernorm.ref``). Pre-norm stacks only. Returns
+    ``(x, new cache, expert counters)``: the period's MoE counters summed
+    (``moe.serve_moe``; empty slots route nowhere).
 
     With ``z`` (the layer scan's period index) ``cache`` is the scan's whole
     stack of periods, every leaf with a leading period axis, and comes back
     as that stack with period ``z``'s state updated: attention pools are
     written and read at ``z`` in place, mamba slot state (a few KB a slot)
     is read at ``z`` and set back there."""
-    if fused:
-        assert not arch.post_norm, (arch.name, "fused decode is pre-norm only")
+    assert not arch.post_norm, (arch.name, "paged decode is pre-norm only")
     new_cache: PyTree = {}
     counts = NO_COUNTS
     d: Optional[jax.Array] = None     # pending in-period residual delta
@@ -468,65 +456,51 @@ def paged_decode_period(arch: ArchConfig, p: PyTree, cache: PyTree,
     for i, (mixer, _) in enumerate(layer_kinds(arch)):
         x = constrain(x, "batch", None, None)
         blk = p[f"layer_{i}"]
-
-        def mix(h, blk=blk, i=i, mixer=mixer):
-            c = cache[f"layer_{i}"]
-            if mixer == "attn":
-                return attn_lib.paged_decode_attention_layer(
-                    arch, blk["attn"], h, c, page_table, seq_lens,
-                    mrope_positions, tp_axis, layer=z)
-            if z is None:
-                return ssm_lib.paged_decode_mamba_layer(
-                    arch, blk["mamba"], h, c, active)
+        c = cache[f"layer_{i}"]
+        if d is None:
+            h = apply_norm(arch.norm, blk["ln1"], x, arch.norm_eps)
+        else:
+            h, x = _fused_residual_norm(arch, blk["ln1"], d, x)
+        if mixer == "attn":
+            y, new_cache[f"layer_{i}"] = attn_lib.paged_decode_attention_layer(
+                arch, blk["attn"], h, c, page_table, seq_lens,
+                mrope_positions, tp_axis, layer=z)
+        elif z is None:
+            y, new_cache[f"layer_{i}"] = ssm_lib.paged_decode_mamba_layer(
+                arch, blk["mamba"], h, c, active)
+        else:
             y, new = ssm_lib.paged_decode_mamba_layer(
                 arch, blk["mamba"], h, jax.tree.map(lambda a: a[z], c),
                 active)
-            return y, jax.tree.map(lambda a, n: a.at[z].set(n), c, new)
-        if fused:
-            if d is None:
-                h = apply_norm(arch.norm, blk["ln1"], x, arch.norm_eps)
-            else:
-                h, x = _fused_residual_norm(arch, blk["ln1"], d, x)
-            y, new_cache[f"layer_{i}"] = mix(h)
-            if arch.family == "ssm":
-                d = y  # mamba2 blocks have no MLP: y is the pending delta
-            else:
-                h2, x = _fused_residual_norm(arch, blk["ln2"], y, x)
-                d, c = _fused_block_delta(arch, blk, h2, tp_axis, rows=rows)
-                counts = counts + c
+            new_cache[f"layer_{i}"] = jax.tree.map(
+                lambda a, n: a.at[z].set(n), c, new)
+        if arch.family == "ssm":
+            d = y  # mamba2 blocks have no MLP: y is the pending delta
         else:
-            x, new_cache[f"layer_{i}"] = _decode_block_mix(arch, blk, x, mix)
-            x, c = _decode_block_ffn(arch, blk, x, tp_axis, rows=rows)
-            counts = counts + c
-    if fused:
-        x = x + d
-    return x, new_cache, counts
+            h2, x = _fused_residual_norm(arch, blk["ln2"], y, x)
+            d, n = _fused_block_delta(arch, blk, h2, tp_axis, rows=rows)
+            counts = counts + n
+    return x + d, new_cache, counts
 
 
 def paged_decode_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
                        x: jax.Array, page_table: jax.Array,
                        seq_lens: jax.Array, mrope_positions=None,
-                       tp_axis: Optional[str] = None,
-                       fused: bool = False):
-    """Single-token decode through the whole stack. ``fused=True`` runs the
-    residual+norm-fused period bodies; the carry between periods is the
-    plain completed residual either way (bit-identical to ``fused=False`` —
-    the fused body keeps every residual add at the same graph position, so
-    XLA's context-sensitive lowering of the norm reductions matches).
-    Pre-norm stacks only. Returns ``(x, new caches, expert counters)``:
-    int32 [2], the token-to-held-expert assignments of the step's MoE
-    layers and, summed over layers, the held experts with at least one
-    (zeros without MoE layers)."""
-    if fused:
-        assert not arch.post_norm, (arch.name, "fused decode is pre-norm only")
+                       tp_axis: Optional[str] = None):
+    """Single-token decode through the whole stack of residual+norm-fused
+    period bodies (``paged_decode_period``); the carry between periods is
+    the plain completed residual. Pre-norm stacks only. Returns ``(x, new
+    caches, expert counters)``: int32 [2], the token-to-held-expert
+    assignments of the step's MoE layers and, summed over layers, the held
+    experts with at least one (zeros without MoE layers)."""
     if lead_layers(arch):
         lead, main = split_lead(arch)
         x, lc, c0 = paged_decode_stack(lead, stacked["lead"], caches["lead"],
                                        x, page_table, seq_lens,
-                                       mrope_positions, tp_axis, fused)
+                                       mrope_positions, tp_axis)
         x, mc, c1 = paged_decode_stack(main, stacked["main"], caches["main"],
                                        x, page_table, seq_lens,
-                                       mrope_positions, tp_axis, fused)
+                                       mrope_positions, tp_axis)
         return x, {"lead": lc, "main": mc}, c0 + c1
     if isinstance(stacked, dict) and any(k.startswith("period_") for k in stacked):
         new_caches: PyTree = {}
@@ -534,8 +508,7 @@ def paged_decode_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
         for z in range(len(stacked)):
             x, nc, c = paged_decode_period(
                 arch, stacked[f"period_{z}"], caches[f"period_{z}"],
-                x, page_table, seq_lens, mrope_positions,
-                tp_axis, fused=fused)
+                x, page_table, seq_lens, mrope_positions, tp_axis)
             new_caches[f"period_{z}"] = nc
             counts = counts + c
         return x, new_caches, counts
@@ -547,7 +520,7 @@ def paged_decode_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
         h, counts, z, caches = carry
         h, caches, c = paged_decode_period(
             arch, period_params, caches, h, page_table, seq_lens,
-            mrope_positions, tp_axis, fused=fused, z=z)
+            mrope_positions, tp_axis, z=z)
         return (h, counts + c, z + 1, caches), None
     (x, counts, _, caches), _ = jax.lax.scan(
         scan_body, (x, NO_COUNTS, jnp.zeros((), jnp.int32), caches), stacked)
@@ -566,16 +539,15 @@ def paged_decode_loop(arch: ArchConfig, stacked: PyTree, caches: PyTree,
                       tokens: jax.Array, page_table: jax.Array,
                       seq_lens: jax.Array, active: jax.Array,
                       budget: jax.Array, page_limit: jax.Array,
-                      eos_ids: jax.Array, *, horizon: int, embed, unembed,
-                      select, probe: bool = False,
-                      tp_axis: Optional[str] = None, fused_head=None):
+                      eos_ids: jax.Array, *, horizon: int, embed, head,
+                      probe: bool = False, tp_axis: Optional[str] = None):
     """Up to ``horizon`` decode iterations in one on-device ``lax.while_loop``.
 
     The loop body is exactly one single-step decode (``paged_decode_stack``
-    + LM head + the caller's token selection) with the carry advanced the
+    + the caller's LM head and token selection) with the carry advanced the
     way the host would have between dispatches: ``seq_lens`` increments for
     active slots each iteration, and the sampling position handed to
-    ``select`` is the carried ``seq_lens + 1`` — so the (seed, position)
+    ``head`` is the carried ``seq_lens + 1`` — so the (seed, position)
     PRNG key of every draw matches the single-step engine bit-for-bit at
     any horizon, including across forced-replay preemption.
 
@@ -603,15 +575,10 @@ def paged_decode_loop(arch: ArchConfig, stacked: PyTree, caches: PyTree,
 
     Returns ``(buf [horizon, S], steps, reasons [S], caches[, ok])`` with
     ``steps >= 1`` (the host guarantees iteration 0's predicates hold).
-    ``embed``/``unembed`` are the model's token embedding / LM head;
-    ``select(logits [S, V], positions [S]) -> int32 [S]`` picks tokens
-    (argmax or the fused-sampling epilogue) from the in-carry positions.
-    ``fused_head(x, positions) -> (tokens [S], ok [S])`` replaces
-    ``unembed`` + ``select`` on the fused-decode path: the final hidden
-    state from ``paged_decode_stack(fused=True)`` goes straight into the
-    streaming final-norm + LM-head epilogue, no [S, V] logits buffer ever
-    exists, and the finite probe rides out of the epilogue's in-register
-    sweep instead of scanning materialized logits.
+    ``embed`` is the model's token embedding; ``head(x, positions) ->
+    (tokens [S], ok [S])`` takes the stack's final hidden state [S, 1, D]
+    and the in-carry sampling positions to the emitted tokens and the
+    per-row finite probe (``ok`` is read only under ``probe``).
     Inactive slots (mid-prefill or empty, masked to the null page) never
     advance ``seq_lens``, never set exit bits, and their junk draws are
     discarded by the host.
@@ -626,25 +593,13 @@ def paged_decode_loop(arch: ArchConfig, stacked: PyTree, caches: PyTree,
     def _body(carry):
         i, tok, lens, caches, buf, reasons, ok = carry
         x = embed(tok[:, None])
-        if fused_head is not None:
-            x, caches, _ = paged_decode_stack(
-                arch, stacked, caches, x, page_table, lens, tp_axis=tp_axis,
-                fused=True)
-            new, ok_rows = fused_head(x, lens + 1)
-            if probe:
-                # row-wise finite probe from the epilogue's streaming sweep
-                # — boolean-identical to scanning the full logits row
-                ok = ok & jnp.all(ok_rows | ~active)
-        else:
-            x, caches, _ = paged_decode_stack(arch, stacked, caches, x,
-                                              page_table, lens,
-                                              tp_axis=tp_axis)
-            logits = unembed(x)
-            new = select(logits, lens + 1)
-            if probe:
-                # inactive slots read the null page and may legitimately
-                # produce junk — probe only the live rows
-                ok = ok & jnp.all(jnp.isfinite(logits) | ~active[:, None])
+        x, caches, _ = paged_decode_stack(arch, stacked, caches, x,
+                                          page_table, lens, tp_axis=tp_axis)
+        new, ok_rows = head(x, lens + 1)
+        if probe:
+            # inactive slots read the null page and may legitimately
+            # produce junk — probe only the live rows
+            ok = ok & jnp.all(ok_rows | ~active)
         buf = buf.at[i].set(new)
         reasons = reasons \
             | jnp.where(active & (new == eos_ids), EXIT_EOS, 0) \
@@ -669,10 +624,11 @@ def paged_prefill_period(arch: ArchConfig, p: PyTree, cache: PyTree,
                          total_len: jax.Array, slot: jax.Array,
                          moe_cap: Optional[jax.Array] = None,
                          mrope_positions=None,
-                         tp_axis: Optional[str] = None,
-                         fused: bool = False) -> Tuple[jax.Array, PyTree]:
-    if fused:
-        assert not arch.post_norm, (arch.name, "fused prefill is pre-norm only")
+                         tp_axis: Optional[str] = None
+                         ) -> Tuple[jax.Array, PyTree]:
+    """One period of a prompt chunk, with the residual stream carried as
+    ``paged_decode_period`` carries it. Pre-norm stacks only."""
+    assert not arch.post_norm, (arch.name, "paged prefill is pre-norm only")
     new_cache: PyTree = {}
     d: Optional[jax.Array] = None     # pending in-period residual delta
     # MoE capacity for a prompt chunk: the FULL context's bucket (computed
@@ -689,34 +645,25 @@ def paged_prefill_period(arch: ArchConfig, p: PyTree, cache: PyTree,
     for i, (mixer, _) in enumerate(layer_kinds(arch)):
         x = constrain(x, "batch", None, None)
         blk = p[f"layer_{i}"]
-
-        def mix(h, blk=blk, i=i, mixer=mixer):
-            if mixer == "attn":
-                return attn_lib.paged_prefill_attention_layer(
-                    arch, blk["attn"], h, cache[f"layer_{i}"], page_row,
-                    start, total_len, mrope_positions, tp_axis)
-            return ssm_lib.paged_prefill_mamba_layer(
+        if d is None:
+            h = apply_norm(arch.norm, blk["ln1"], x, arch.norm_eps)
+        else:
+            h, x = _fused_residual_norm(arch, blk["ln1"], d, x)
+        if mixer == "attn":
+            y, new_cache[f"layer_{i}"] = attn_lib.paged_prefill_attention_layer(
+                arch, blk["attn"], h, cache[f"layer_{i}"], page_row, start,
+                total_len, mrope_positions, tp_axis)
+        else:
+            y, new_cache[f"layer_{i}"] = ssm_lib.paged_prefill_mamba_layer(
                 arch, blk["mamba"], h, cache[f"layer_{i}"], slot, start,
                 total_len)
-        if fused:
-            if d is None:
-                h = apply_norm(arch.norm, blk["ln1"], x, arch.norm_eps)
-            else:
-                h, x = _fused_residual_norm(arch, blk["ln1"], d, x)
-            y, new_cache[f"layer_{i}"] = mix(h)
-            if arch.family == "ssm":
-                d = y
-            else:
-                h2, x = _fused_residual_norm(arch, blk["ln2"], y, x)
-                d, _ = _fused_block_delta(arch, blk, h2, tp_axis, moe_eff_cap,
-                                          rows)
+        if arch.family == "ssm":
+            d = y
         else:
-            x, new_cache[f"layer_{i}"] = _decode_block_mix(arch, blk, x, mix)
-            x, _ = _decode_block_ffn(arch, blk, x, tp_axis,
-                                     moe_eff_cap=moe_eff_cap, rows=rows)
-    if fused:
-        x = x + d
-    return x, new_cache
+            h2, x = _fused_residual_norm(arch, blk["ln2"], y, x)
+            d, _ = _fused_block_delta(arch, blk, h2, tp_axis, moe_eff_cap,
+                                      rows)
+    return x + d, new_cache
 
 
 def chunk_final_hidden(x: jax.Array, start: jax.Array,
@@ -734,29 +681,26 @@ def paged_prefill_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
                         total_len: jax.Array, slot: jax.Array = None,
                         moe_cap: Optional[jax.Array] = None,
                         mrope_positions=None,
-                        tp_axis: Optional[str] = None,
-                        fused: bool = False):
+                        tp_axis: Optional[str] = None):
     """Chunked prefill: one prompt chunk [1, C, D] of one sequence through
     the stack — attention K/V written straight into the sequence's pages,
     mamba state advanced in the sequence's slot row (``slot``; only needed
     for SSM-bearing stacks), MoE layers dropping at the full context's
     capacity (``moe_cap``, host-computed; only read for MoE-bearing
     stacks). The caller slices the sampling position out of the returned
-    activations with ``chunk_final_hidden``. ``fused=True`` mirrors
-    ``paged_decode_stack``: residual+norm-fused period bodies, plain
-    completed residual as the carry, bit-identical to ``fused=False``."""
+    activations with ``chunk_final_hidden``. The period bodies are
+    residual+norm-fused like ``paged_decode_stack``'s, with the plain
+    completed residual as the carry."""
     if slot is None:
         slot = jnp.zeros((), jnp.int32)
-    if fused:
-        assert not arch.post_norm, (arch.name, "fused prefill is pre-norm only")
     if lead_layers(arch):
         lead, main = split_lead(arch)
         x, lc = paged_prefill_stack(lead, stacked["lead"], caches["lead"], x,
                                     page_row, start, total_len, slot, moe_cap,
-                                    mrope_positions, tp_axis, fused)
+                                    mrope_positions, tp_axis)
         x, mc = paged_prefill_stack(main, stacked["main"], caches["main"], x,
                                     page_row, start, total_len, slot, moe_cap,
-                                    mrope_positions, tp_axis, fused)
+                                    mrope_positions, tp_axis)
         return x, {"lead": lc, "main": mc}
     if isinstance(stacked, dict) and any(k.startswith("period_") for k in stacked):
         new_caches: PyTree = {}
@@ -764,17 +708,15 @@ def paged_prefill_stack(arch: ArchConfig, stacked: PyTree, caches: PyTree,
             x, nc = paged_prefill_period(
                 arch, stacked[f"period_{z}"], caches[f"period_{z}"],
                 x, page_row, start, total_len, slot,
-                moe_cap, mrope_positions, tp_axis, fused=fused)
+                moe_cap, mrope_positions, tp_axis)
             new_caches[f"period_{z}"] = nc
         return x, new_caches
 
     def scan_body(h, inputs):
         period_params, cache = inputs
-        h, new_cache = paged_prefill_period(
+        return paged_prefill_period(
             arch, period_params, cache, h, page_row,
-            start, total_len, slot, moe_cap, mrope_positions, tp_axis,
-            fused=fused)
-        return h, new_cache
+            start, total_len, slot, moe_cap, mrope_positions, tp_axis)
     x, new_caches = jax.lax.scan(scan_body, x, (stacked, caches))
     return x, new_caches
 
@@ -804,7 +746,7 @@ def decode_period(arch: ArchConfig, p: PyTree, cache: PyTree, x: jax.Array,
             enc_kv = (layer_cache["cross_k"], layer_cache["cross_v"])
             x = x + attn_lib.apply_cross_attention(arch, blk["xattn"], h, enc_kv)
 
-        x, _ = _decode_block_ffn(arch, blk, x)
+        x = _decode_block_ffn(arch, blk, x)
     return x, new_cache
 
 

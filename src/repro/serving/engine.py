@@ -79,33 +79,31 @@ capacity via ``Scheduler.extend_capacity`` — free pages only, never a
 preemption), then reconciles the returned ``(buffer, k, reasons)`` through
 the ordinary finish/admit/preempt path. Invariants the tests pin:
 
-- jit-cache key: ``("decode", sampled, filtered, fused, fd)`` at N=1 (the
+- jit-cache key: ``("decode", sampled, filtered)`` at N=1 (the
   single-step path is literally unchanged) and
-  ``("decode", sampled, filtered, fused, fd, N)`` at N>1 — prefill keys
-  never carry the horizon. ``fd`` is the engine's ``fused_decode`` flag
-  (below). ``analysis/recompile.py`` audits both shapes closed.
+  ``("decode", sampled, filtered, N)`` at N>1 — prefill keys
+  (``("prefill", final, sampled, filtered)``) never carry the horizon.
+  ``analysis/recompile.py`` audits both shapes closed.
 - ``steps`` counts loop iterations, ``decode_dispatches`` host dispatches,
   ``decode_exits`` why each dispatch returned; at N=1 the two counters are
   equal and no exit accounting runs.
 - a preemption can only land *between* dispatches; forced replay re-derives
   every key from stream position, so the horizon is token-invisible.
 
-Fused decode (``fused_decode = True``, the default where supported) removes
-the residual-stream HBM round-trips at every fused norm site and the [S, V]
-logits buffer entirely: inside each layer period the residual rides as an
-(x, pending-delta) pair folded by the fused residual+norm kernels
-(``kernels.fused_layernorm.decode_residual_norm``) and completed by a plain
-add at the period boundary (so the scan carry — and XLA's context-sensitive
-lowering of the norm reductions — matches the unfused body exactly), and
-the LM head + token selection collapse into a vocab-tiled streaming
-epilogue (``kernels.fused_lm_head``) that carries max/argmax, the top-k/
-top-p bisection counts, softmax masses, and the inverse-CDF draw in the
-GEMM accumulator. Token streams are bit-identical to the unfused path —
-greedy and seeded-sampled, across preemption replay, decode_steps horizons,
-and tp — because every float reduction is the same canonically-tiled sum on
-both paths and every residual add sits at the same graph position.
-Unsupported layouts (post-norm stacks, MLM heads, TP shards off the
-reduction tile) fall back with ``fused_decode_off_reason`` set.
+The decode step and the prefill chunk carry the residual stream as an
+(x, pending-delta) pair inside each layer period, folded by the fused
+residual+norm kernels (``kernels.fused_layernorm.decode_residual_norm``)
+and completed by a plain add at the period boundary, so no residual add
+makes its own HBM round-trip. The LM head and token selection are one
+vocab-tiled streaming epilogue (``kernels.fused_lm_head``) that carries
+max/argmax, the top-k/top-p bisection counts, softmax masses, and the
+inverse-CDF draw in the GEMM accumulator — no [S, V] logits buffer — on
+a backend that has the kernel and where each tp shard's vocab slice is a
+whole number of 128-wide reduction tiles (``fused_decode``). Elsewhere
+``_head`` materializes the logits and runs ``sample_tokens``: the same
+tokens, because every float reduction is the same canonically-tiled sum
+in both. Post-norm stacks and MLM-transform heads are refused at
+construction.
 """
 from __future__ import annotations
 
@@ -131,8 +129,7 @@ from ..models.model import Model
 from ..models.moe import capacity_per_row
 from ..parallel import sharding as shardlib
 from .kv_cache import pages_needed
-from .sampling import (fused_decode_enabled, fused_sampling_enabled,
-                       sample_tokens)
+from .sampling import sample_tokens
 from .scheduler import Request, Scheduler, SequenceState
 
 SERVABLE_FAMILIES = ("dense", "moe", "vlm", "ssm", "hybrid")
@@ -219,14 +216,19 @@ class ContinuousEngine:
                  max_seq_len: int = 512, prefix_cache: bool = True,
                  prefill_chunk: Optional[int] = None, tp: int = 1,
                  mesh=None, sanitize: Optional[bool] = None,
-                 fused_sampling: Optional[bool] = None,
-                 decode_steps: int = 1,
-                 fused_decode: Optional[bool] = None):
+                 decode_steps: int = 1):
         arch = model.arch
         assert arch.family in SERVABLE_FAMILIES, \
             (f"continuous engine serves families {SERVABLE_FAMILIES}; "
              f"{arch.name} is {arch.family!r}")
         assert not arch.bidirectional, "encoder-only archs have no decode step"
+        # the paged stack defers every residual add into the next pre-norm
+        # (tf.paged_decode_period) and the head streams straight off the
+        # final norm: neither has a post-norm or an MLM-transform form
+        assert not arch.post_norm, \
+            f"the paged serving stack is pre-norm only ({arch.name})"
+        assert not arch.mlm_transform, \
+            f"the serving head has no MLM transform ({arch.name})"
         kinds = tf.layer_kinds(arch)
         self.has_attn = any(m == "attn" for m, _ in kinds)
         self.has_ssm = any(m == "mamba" for m, _ in kinds)
@@ -256,13 +258,6 @@ class ContinuousEngine:
         # retrace of this one.
         self.sanitize = sanitize_enabled() if sanitize is None \
             else bool(sanitize)
-        # sort-free streaming top-k/top-p filter (repro.kernels.
-        # fused_sampling) vs the sort-based reference — bit-identical token
-        # streams either way; the flag is a fallback + parity-test hook.
-        # Static per engine like `sanitize`: it names the filter
-        # implementation inside the compiled filtered variants.
-        self.fused_sampling = fused_sampling_enabled() if fused_sampling \
-            is None else bool(fused_sampling)
         # multi-step compiled decode: decode_steps > 1 dispatches up to N
         # iterations as one on-device lax.while_loop (tf.paged_decode_loop)
         # and resyncs with the host only on an exit event (EOS, token/page
@@ -272,33 +267,15 @@ class ContinuousEngine:
         # (positions advance in-carry exactly as the host would have).
         assert decode_steps >= 1, decode_steps
         self.decode_steps = int(decode_steps)
-        # fused decode residual stream + streaming LM head: the decode/
-        # final-prefill steps fold each residual-add + pre-norm pair into
-        # one fused kernel pass inside the layer period and run the unembed
-        # GEMM as a vocab-tiled streaming epilogue that samples
-        # in-accumulator — no [S, V] logits buffer ever reaches HBM.
-        # Bit-identical token
-        # streams by construction (tests pin greedy + seeded-sampled parity
-        # incl. preemption replay), so the flag only changes memory traffic.
-        # Static per engine and part of every step's jit-cache key. Falls
-        # back (with a recorded reason) where the fusion's preconditions
-        # fail: post-norm stacks, MLM heads, and TP shard widths that don't
-        # land on the canonical reduction tile.
-        want_fd = fused_decode_enabled() if fused_decode is None \
-            else bool(fused_decode)
-        self.fused_decode_off_reason: Optional[str] = None
-        if want_fd:
-            if arch.post_norm:
-                self.fused_decode_off_reason = \
-                    "fused decode requires a pre-norm stack"
-            elif arch.mlm_transform:
-                self.fused_decode_off_reason = \
-                    "fused decode does not support MLM-transform heads"
-            elif not head_ops.tp_fusable(pad_vocab(arch.vocab_size), tp):
-                self.fused_decode_off_reason = (
-                    f"vocab shard {pad_vocab(arch.vocab_size)}/{tp} does not "
-                    f"land on the {head_ops.RED_TILE}-wide reduction tile")
-        self.fused_decode = want_fd and self.fused_decode_off_reason is None
+        # the streaming LM head (kernels.fused_lm_head) serves where the
+        # backend has it and each tp shard's vocab slice is whole reduction
+        # tiles; otherwise `_head` materializes the logits (same tokens)
+        self.fused_decode = head_ops.tp_fusable(pad_vocab(arch.vocab_size),
+                                                tp)
+        self.fused_decode_off_reason: Optional[str] = None if \
+            self.fused_decode else (
+                f"vocab shard {pad_vocab(arch.vocab_size)}/{tp} does not "
+                f"land on the {head_ops.RED_TILE}-wide reduction tile")
         # prefix caching shares *pages*; a mamba mixer's recurrent state is
         # not page-decomposable (a cached KV page is useless without the SSM
         # state at its boundary), so SSM-bearing archs gate it off — loudly:
@@ -435,17 +412,11 @@ class ContinuousEngine:
                        donate_argnums=donate if self._donate_pools else ())
 
     def _decode_fn(self, sampled: bool, filtered: bool):
-        # `fused` names the filter implementation, so it only exists in
-        # variants that filter at all — greedy/temperature-only variants
-        # stay shared between fused and reference engines. `fd` (fused
-        # decode) reshapes the whole step — residual-stream pair carry plus
-        # the streaming LM-head epilogue — so it keys every variant.
-        fused = self.fused_sampling and filtered
-        key = ("decode", sampled, filtered, fused, self.fused_decode)
+        key = ("decode", sampled, filtered)
         if key not in self._jit_cache:
             impl = _named(functools.partial(
-                self._decode_impl, sampled=sampled, filtered=filtered,
-                fused=fused, fd=self.fused_decode), "serve_decode_step")
+                self._decode_impl, sampled=sampled, filtered=filtered),
+                "serve_decode_step")
             in_specs = (self._param_specs, self._pool_specs, P(None, None),
                         P(None), P(None), P(None), P(None), P(None), P(None))
             out_specs = (P(None), self._pool_specs)
@@ -460,13 +431,10 @@ class ContinuousEngine:
         ``_decode_fn`` plus the horizon N, which keys the jit cache — an
         engine at ``decode_steps=N`` compiles (lazily, per sampling
         variant) loops of exactly that horizon and nothing else."""
-        fused = self.fused_sampling and filtered
-        key = ("decode", sampled, filtered, fused, self.fused_decode,
-               self.decode_steps)
+        key = ("decode", sampled, filtered, self.decode_steps)
         if key not in self._jit_cache:
             impl = _named(functools.partial(
                 self._decode_multi_impl, sampled=sampled, filtered=filtered,
-                fused=fused, fd=self.fused_decode,
                 horizon=self.decode_steps), "serve_decode_loop")
             in_specs = (self._param_specs, self._pool_specs, P(None, None)) \
                 + (P(None),) * 10
@@ -478,13 +446,11 @@ class ContinuousEngine:
         return self._jit_cache[key]
 
     def _prefill_fn(self, final: bool, sampled: bool, filtered: bool):
-        fused = self.fused_sampling and filtered
-        key = ("prefill", final, sampled, filtered, fused, self.fused_decode)
+        key = ("prefill", final, sampled, filtered)
         if key not in self._jit_cache:
             impl = _named(functools.partial(
                 self._prefill_impl, final=final, sampled=sampled,
-                filtered=filtered, fused=fused, fd=self.fused_decode),
-                "serve_prefill_chunk")
+                filtered=filtered), "serve_prefill_chunk")
             in_specs = (self._param_specs, self._pool_specs, P(None, None),
                         P(None), P(), P(), P(), P(), P(), P(), P(), P())
             out_specs = (P(), self._pool_specs)
@@ -516,34 +482,30 @@ class ContinuousEngine:
         return self._psums_per_step * payload * 2 * (self.tp - 1) // self.tp
 
     # ------------------------------------------------------------- jitted fns ---
-    def _fused_head(self, params, x, positions, seeds, temps, top_ks,
-                    top_ps, *, sampled, filtered, fused):
-        """Fused final-norm + streaming LM head: final hidden ``x``
-        [S, 1, D] -> ``(tokens [S], ok [S])`` with no [S, V] logits buffer.
+    def _head(self, params, x, positions, seeds, temps, top_ks, top_ps, *,
+              sampled, filtered):
+        """Final norm + LM head + token selection: final hidden ``x``
+        [S, 1, D] -> ``(tokens [S], ok [S])``, ``ok`` the per-row
+        all-finite probe of the logits.
 
-        On TPU the unembed GEMM streams over vocab tiles with the sampling
-        statistics (max/argmax, filter-threshold bisections, softmax
-        masses, the inverse-CDF draw) carried in the accumulator —
-        bit-identical to materializing the logits and running
-        ``sample_tokens`` (the ``fused_decode`` contract; the tiled
-        reductions are the canonical ones both paths share). ``ok`` is the
-        per-row finite probe from the same streaming sweep. Under TP each
-        shard streams its own vocab slice and the combines move
-        O(S * V / RED_TILE) statistics, never logits.
+        Greedy slots take a raw argmax, sampled slots a per-slot (seed,
+        position)-keyed categorical draw; ``sampled``/``filtered`` are the
+        static variant flags (an all-greedy step compiles to a pure
+        argmax, temperature-only steps skip the top-k/top-p filter).
 
-        Off-TPU the fallback is the *op-identical* unfused tail (full
-        unembed + ``sample_tokens``), not the jnp streaming emulation: XLA
-        CPU lowers float reductions context-sensitively, so two graphs
-        that differ anywhere downstream of a norm or GEMM can round the
-        SAME math to ulp-different values — the only structure that
-        guarantees the fused_decode bit-parity contract on CPU is one
-        whose HLO is identical. The streaming emulation stays covered by
-        the standalone and interpret-mode parity tests (where jit-vs-jit
-        equality holds because both sides are whole graphs)."""
+        Where the backend has the streaming kernel and the vocab shard fits
+        (``fused_decode``), the unembed GEMM streams over vocab tiles with
+        the sampling statistics (max/argmax, filter-threshold bisections,
+        softmax masses, the inverse-CDF draw) carried in the accumulator:
+        no [S, V] logits buffer. Under TP each shard streams its own vocab
+        slice and the combines move O(S * V / RED_TILE) statistics, never
+        logits. Elsewhere the logits are materialized and drawn from by
+        ``sample_tokens`` — the same tokens, since both take the same
+        canonically-tiled reductions (``kernels.fused_lm_head.ref``)."""
         arch = self.arch
         x = shardlib.constrain(x, "batch", None, None)
         hidden = apply_norm(arch.norm, params["final_norm"], x, arch.norm_eps)
-        if not head_ops.supported():
+        if not (head_ops.supported() and self.fused_decode):
             tied = params["embed"]["embedding"] if arch.tie_embeddings \
                 else None
             logits = unembed(params.get("out", {}), hidden, tied,
@@ -552,8 +514,7 @@ class ContinuousEngine:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             else:
                 tok = sample_tokens(logits, seeds, positions, temps,
-                                    top_ks, top_ps, filtered=filtered,
-                                    fused=fused)
+                                    top_ks, top_ps, filtered=filtered)
             return tok, jnp.isfinite(logits).all(axis=-1)
         if arch.tie_embeddings:
             w = params["embed"]["embedding"].astype(hidden.dtype).T
@@ -569,72 +530,38 @@ class ContinuousEngine:
             tp=self.tp)
 
     def _decode_impl(self, params, pools, page_table, seq_lens, tokens,
-                     seeds, temps, top_ks, top_ps, *, sampled, filtered,
-                     fused, fd):
+                     seeds, temps, top_ks, top_ps, *, sampled, filtered):
         """tokens [S] -> (next token [S], new pools). S == num_slots. For a
         MoE stack the token vector carries the step's two expert counters
         after the S tokens (``[S + 2]``: token-to-held-expert assignments
         and held experts with at least one, summed over MoE layers), so
         they reach the host with the tokens.
 
-        Selection stays on device — greedy slots take a raw argmax, sampled
-        slots a per-slot (seed, position)-keyed categorical draw — so only
-        the [S] token vector ever crosses to the host, never [S, vocab]
-        logits. ``sampled``/``filtered``/``fused`` are static: an all-greedy
-        step compiles to a pure argmax (today's default traffic pays zero
-        sampler work — no filtering, no key fold-ins), temperature-only
-        batches skip the filtering epilogue, filtered batches run either the
-        streaming fused filter or the sort-based reference, and each extra
-        variant compiles only once the matching traffic shows up.
-
-        ``fd`` (fused decode) swaps both halves of the step: the stack runs
-        the residual+norm-fused layer bodies, and the final-norm + LM head
-        + selection collapse into the streaming vocab-tiled epilogue of
-        ``_fused_head`` — same tokens, same probe semantics, no [S, V]
-        logits round-trip."""
+        Selection stays on device (``_head``), so only the [S] token vector
+        ever crosses to the host, never [S, vocab] logits. The stream
+        position of the token this step emits is derived ON DEVICE: every
+        earlier token of the sequence is cached except the step's input
+        token, so position = seq_lens + 1 — slot- and batch-independent
+        (the determinism contract), with no per-step position transfer.
+        Mid-prefill slots are masked to seq_lens 0 and temperature 0; their
+        draws are discarded on the host."""
         x = self.model._embed(params, tokens[:, None])
-        if fd:
-            x, pools, counts = tf.paged_decode_stack(
-                self.arch, params["blocks"], pools, x, page_table, seq_lens,
-                tp_axis=self.tp_axis, fused=True)
-            tok, ok = self._fused_head(params, x, seq_lens + 1, seeds,
-                                       temps, top_ks, top_ps, sampled=sampled,
-                                       filtered=filtered, fused=fused)
-            if self.has_moe:
-                tok = jnp.concatenate([tok, counts])
-            if self.sanitize:
-                return tok, pools, jnp.all(ok | (seq_lens == 0))
-            return tok, pools
         x, pools, counts = tf.paged_decode_stack(
             self.arch, params["blocks"], pools, x, page_table, seq_lens,
             tp_axis=self.tp_axis)
-        logits = self.model._logits(params, x)[:, 0]
-        if not sampled:
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            # stream position of the token this step emits, derived ON
-            # DEVICE: every earlier token of the sequence is cached except
-            # the step's input token, so position = seq_lens + 1. Slot- and
-            # batch-independent (the determinism contract), and it spares
-            # sampled steps any per-step position transfer. Mid-prefill
-            # slots are masked to seq_lens 0 and temperature 0; their draws
-            # are discarded on the host.
-            positions = seq_lens + 1
-            tok = sample_tokens(logits, seeds, positions, temps, top_ks,
-                                top_ps, filtered=filtered, fused=fused)
+        tok, ok = self._head(params, x, seq_lens + 1, seeds, temps, top_ks,
+                             top_ps, sampled=sampled, filtered=filtered)
         if self.has_moe:
             tok = jnp.concatenate([tok, counts])
         if self.sanitize:
             # inactive slots read the null page and may legitimately produce
             # junk — probe only rows with at least one real token resident
-            live = jnp.isfinite(logits) | (seq_lens[:, None] == 0)
-            return tok, pools, live.all()
+            return tok, pools, jnp.all(ok | (seq_lens == 0))
         return tok, pools
 
     def _decode_multi_impl(self, params, pools, page_table, seq_lens, tokens,
                            active, budget, page_limit, eos_ids, seeds, temps,
-                           top_ks, top_ps, *, sampled, filtered, fused, fd,
-                           horizon):
+                           top_ks, top_ps, *, sampled, filtered, horizon):
         """tokens [S] -> (emitted tokens [horizon, S], steps executed,
         exit-reason bits [S], new pools). One ``lax.while_loop`` around the
         exact single-step body (``tf.paged_decode_loop``): up to ``horizon``
@@ -650,69 +577,34 @@ class ContinuousEngine:
         def embed(tok):
             return self.model._embed(params, tok)
 
-        def unembed(x):
-            return self.model._logits(params, x)[:, 0]
-
-        def select(logits, positions):
-            if not sampled:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return sample_tokens(logits, seeds, positions, temps, top_ks,
-                                 top_ps, filtered=filtered, fused=fused)
-
-        def fused_head(x, positions):
-            # the loop body's LM head on the fused-decode path: streaming
-            # epilogue straight off the final hidden, finite probe included
-            return self._fused_head(params, x, positions, seeds,
-                                    temps, top_ks, top_ps, sampled=sampled,
-                                    filtered=filtered, fused=fused)
+        def head(x, positions):
+            return self._head(params, x, positions, seeds, temps, top_ks,
+                              top_ps, sampled=sampled, filtered=filtered)
 
         return tf.paged_decode_loop(
             self.arch, params["blocks"], pools, tokens, page_table, seq_lens,
             active, budget, page_limit, eos_ids, horizon=horizon, embed=embed,
-            unembed=unembed, select=select, probe=self.sanitize,
-            tp_axis=self.tp_axis, fused_head=fused_head if fd else None)
+            head=head, probe=self.sanitize, tp_axis=self.tp_axis)
 
     def _prefill_impl(self, params, pools, tokens, page_row, slot, start,
                       total, moe_cap, seed, temp, top_k, top_p, *, final,
-                      sampled, filtered, fused, fd):
+                      sampled, filtered):
         """One prompt chunk of one sequence. tokens [1, C] (padded past
         ``total - start`` valid tokens) -> (token after the chunk's last
         valid token [scalar], new pools). One compiled shape (variants on
         the static flags only: non-final chunks exist to fill pages and skip
-        the LM head entirely; a final chunk pays the head plus either a raw
-        argmax or the sampler, like ``_decode_impl``). ``slot`` addresses
+        the LM head entirely; a final chunk slices the sampling position and
+        pays the same ``_head`` as the decode step). ``slot`` addresses
         the sequence's per-slot SSM state rows, ``moe_cap`` is the full
         context's MoE capacity (host-computed with the static engine's exact
         math; attention-only / MoE-free stacks ignore them). The emitted
         token's stream position is ``total``, so its sampling key matches
         the decode step that would have produced it in an uninterrupted run
-        — the forced-replay invariant.
-
-        ``fd``: the chunk runs the residual+norm-fused layer bodies; a
-        final chunk slices the sampling position and runs the same fused
-        final-norm + streaming-head epilogue as the decode step."""
+        — the forced-replay invariant."""
         x = self.model._embed(params, tokens)
-        if fd:
-            x, pools = tf.paged_prefill_stack(
-                self.arch, params["blocks"], pools, x, page_row, start,
-                total, slot, moe_cap, tp_axis=self.tp_axis, fused=True)
-            if not final:
-                if self.sanitize:
-                    pos = start + jnp.arange(x.shape[1])
-                    live = jnp.isfinite(x) | (pos >= total)[None, :, None]
-                    return jnp.zeros((), jnp.int32), pools, live.all()
-                return jnp.zeros((), jnp.int32), pools
-            xl = tf.chunk_final_hidden(x, start, total)
-            toks, ok = self._fused_head(
-                params, xl, total[None], seed[None], temp[None],
-                top_k[None], top_p[None], sampled=sampled, filtered=filtered,
-                fused=fused)
-            if self.sanitize:
-                return toks[0], pools, ok[0]
-            return toks[0], pools
-        x, pools = tf.paged_prefill_stack(self.arch, params["blocks"], pools,
-                                          x, page_row, start, total, slot,
-                                          moe_cap, tp_axis=self.tp_axis)
+        x, pools = tf.paged_prefill_stack(
+            self.arch, params["blocks"], pools, x, page_row, start, total,
+            slot, moe_cap, tp_axis=self.tp_axis)
         if not final:
             if self.sanitize:
                 # chunk-boundary probe: activations of the chunk's valid
@@ -722,16 +614,12 @@ class ContinuousEngine:
                 return jnp.zeros((), jnp.int32), pools, live.all()
             return jnp.zeros((), jnp.int32), pools
         xl = tf.chunk_final_hidden(x, start, total)
-        logits = self.model._logits(params, xl)[:, 0]
-        if not sampled:
-            tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-        else:
-            tok = sample_tokens(logits, seed[None], total[None], temp[None],
-                                top_k[None], top_p[None], filtered=filtered,
-                                fused=fused)[0]
+        toks, ok = self._head(params, xl, total[None], seed[None], temp[None],
+                              top_k[None], top_p[None], sampled=sampled,
+                              filtered=filtered)
         if self.sanitize:
-            return tok, pools, jnp.isfinite(logits).all()
-        return tok, pools
+            return toks[0], pools, ok[0]
+        return toks[0], pools
 
     def _copy_page_impl(self, pools, src, dst):
         """Copy-on-write: duplicate one physical page across every attention

@@ -34,49 +34,28 @@ then top-k, then top-p (nucleus) on the rescaled distribution, then one
 draw. ``temperature == 0`` short-circuits to raw ``argmax`` on the unscaled
 logits — bit-identical to the historical greedy path.
 
-The top-k/top-p masking itself lives in ``repro.kernels.fused_sampling``:
-``fused=True`` (the default) streams it sort-free (Pallas on TPU, a bit-key
-bisection in jnp elsewhere), ``fused=False`` runs the single sort-based
-reference. The two are bit-identical by construction — they share one
-decision predicate — so the flag changes speed, never tokens.
+The top-k/top-p masking itself lives in ``repro.kernels.fused_sampling``,
+which streams it sort-free (Pallas on TPU, a bit-key bisection in jnp
+elsewhere). Its sort-based ``ref.filter_logits_ref`` is the test oracle:
+the two share one decision predicate, so they mask bit-identically.
 
 The draw itself is the canonical inverse-CDF walk of
 ``repro.kernels.fused_lm_head.ref``: one ``jax.random.uniform`` from the
 ``fold_in(key(seed), position)`` key, then the first vocab index whose
 (canonically tiled) prefix softmax mass exceeds ``uniform * Z``. Exact
 categorical sampling, and — unlike the Gumbel-noise formulation — needing
-no per-vocab-entry randomness, so the fused decode epilogue can reproduce
-the identical token while streaming the unembed GEMM over vocab blocks.
+no per-vocab-entry randomness, so the LM-head epilogue can reproduce the
+identical token while streaming the unembed GEMM over vocab blocks.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.fused_lm_head import ref as head_ref
 from repro.kernels.fused_sampling import ops as fused_ops
-from repro.kernels.fused_sampling import ref as fused_ref
-
-
-def fused_sampling_enabled() -> bool:
-    """Env default for the engines' ``fused_sampling`` flag: set
-    ``REPRO_FUSED_SAMPLING=0`` to fall back to the sort-based reference
-    filter everywhere. A debugging escape hatch — the two implementations
-    draw bit-identical tokens, so the toggle only changes step latency."""
-    return os.environ.get("REPRO_FUSED_SAMPLING", "1") not in ("", "0")
-
-
-def fused_decode_enabled() -> bool:
-    """Env default for the continuous engine's ``fused_decode`` flag: set
-    ``REPRO_FUSED_DECODE=0`` to serve the unfused decode path (separate
-    residual adds / norms and a materialized-logits sampler). Like the
-    sampler flag, the fused and unfused paths emit bit-identical token
-    streams by construction, so the toggle only changes memory traffic and
-    step latency."""
-    return os.environ.get("REPRO_FUSED_DECODE", "1") not in ("", "0")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,8 +100,7 @@ class SamplingParams:
 
 def sample_tokens(logits: jax.Array, seeds: jax.Array, positions: jax.Array,
                   temperatures: jax.Array, top_k: jax.Array,
-                  top_p: jax.Array, *, filtered: bool = True,
-                  fused: bool = True) -> jax.Array:
+                  top_p: jax.Array, *, filtered: bool = True) -> jax.Array:
     """Draw one token per row of ``logits`` [B, V] -> int32 [B].
 
     All parameter arrays are per-row [B]: ``seeds`` uint32, ``positions``
@@ -135,12 +113,8 @@ def sample_tokens(logits: jax.Array, seeds: jax.Array, positions: jax.Array,
     top_k and top_p disabled to skip the filtering epilogue entirely — for
     finite logits the disabled filters are exact no-ops, so both variants
     draw the identical token for the same (seed, position, logits).
-
-    ``fused`` (static) picks the filter implementation: the sort-free
-    streaming kernel package (default) or the sort-based reference oracle.
-    Bit-identical outputs either way; the flag exists for fallback and for
-    divergence regression tests. Traceable/jittable in every combination;
-    nothing bigger than the [B] token vector ever crosses to the host.
+    Traceable/jittable either way; nothing bigger than the [B] token
+    vector ever crosses to the host.
     """
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -149,8 +123,8 @@ def sample_tokens(logits: jax.Array, seeds: jax.Array, positions: jax.Array,
     lg = logits.astype(jnp.float32) / safe_t[:, None]
 
     if filtered:
-        fn = fused_ops.filter_logits if fused else fused_ref.filter_logits_ref
-        lg = fn(lg, top_k.astype(jnp.int32), top_p.astype(jnp.float32))
+        lg = fused_ops.filter_logits(lg, top_k.astype(jnp.int32),
+                                     top_p.astype(jnp.float32))
 
     rs = head_ref.row_uniforms(seeds, positions)
     sampled = head_ref.draw_tokens(lg, rs)

@@ -14,16 +14,17 @@ Three layers of acceptance:
    ``f32 [S, V]`` logits buffer (that buffer's absence IS the optimization);
    the materializing oracle is the positive control proving the assertion
    can fail. This is asserted on the STREAMING implementation's graph: on
-   CPU the engine intentionally serves the materializing fallback (an
-   op-identical graph is the only way XLA CPU reproduces the unfused
-   reduction lowerings bit-for-bit — see ``engine._fused_head``), so the
-   engine's own CPU HLO is out of scope here by design.
+   CPU the engine serves the materializing head (``engine._head``), so the
+   engine's own CPU HLO is out of scope here by design
+   (``tests/test_tpu_compile.py`` compiles the engine's step for a v5e).
 
-3. Engine invisibility — ``fused_decode=True`` must emit token streams
-   bit-identical to the unfused engine for every servable family, at
-   decode horizon N=1 and N=4, across forced-preemption replay, and under
-   tp=2 — plus the construction-time gates (post-norm stacks, MLM heads,
-   non-tile-aligned TP vocab shards) that fall back with a recorded reason.
+3. Engine invisibility — the engine's one decode path (residual+norm-fused
+   period bodies, then ``_head``) emits the float32 static engine's token
+   streams for every servable family, the same streams at decode horizon
+   N=1 and N=4, across forced-preemption replay, and under tp=2, where a
+   vocab shard off the reduction tile serves through the materializing
+   head — plus the construction-time refusals of post-norm stacks and MLM
+   heads.
 """
 import dataclasses
 import subprocess
@@ -47,8 +48,8 @@ from repro.kernels.fused_lm_head import kernel as head_kernel
 from repro.kernels.fused_lm_head import ops as head_ops
 from repro.kernels.fused_lm_head import ref as head_ref
 from repro.models import build_model
-from repro.serving import ContinuousEngine, Request
-from repro.serving.sampling import SamplingParams, fused_decode_enabled
+from repro.serving import ContinuousEngine, Request, sample_tokens
+from repro.serving.sampling import SamplingParams
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -270,13 +271,30 @@ def _smoke_model(name):
     return arch, model, model.init(jax.random.key(0))
 
 
-def _requests(arch, n=5, seed=7):
+@lru_cache(maxsize=None)
+def _fp32_model(name):
+    """The float32 smoke model the static engine is compared against. MoE
+    capacity is raised until no token drops: capacity dispatch (jamba's)
+    routes a whole slot batch per decode step and the static engine one
+    request, so under a binding capacity the two drop different tokens and
+    no static stream can match (the dropless deepseek-moe layer is
+    unaffected)."""
+    arch = dataclasses.replace(smoke_config(name), dtype="float32",
+                               param_dtype="float32")
+    if arch.moe is not None:
+        arch = dataclasses.replace(
+            arch, moe=dataclasses.replace(arch.moe, capacity_factor=8.0))
+    model = build_model(arch)
+    return arch, model, model.init(jax.random.key(0))
+
+
+def _requests(arch, n=5, seed=7, max_prompt=17):
     """Mixed greedy / seeded-sampled / filtered traffic, ragged lengths."""
     rng = np.random.default_rng(seed)
     reqs = []
     for i in range(n):
-        prompt = list(map(int, rng.integers(5, arch.vocab_size,
-                                            int(rng.integers(6, 18)))))
+        prompt = list(map(int, rng.integers(
+            5, arch.vocab_size, int(rng.integers(6, max_prompt + 1)))))
         sp = (SamplingParams(),
               SamplingParams(temperature=0.8, seed=100 + i),
               SamplingParams(temperature=0.9, top_k=8, top_p=0.9,
@@ -296,39 +314,67 @@ def _serve(model, params, reqs, **kw):
     return engine, {uid: r["tokens"] for uid, r in res.items()}
 
 
+def _static_streams(model, params, reqs):
+    """Per-request static decode (batch 1, full logits) through the shared
+    sampler: the reference the paged engine must reproduce draw for draw."""
+    prefill = jax.jit(model.prefill)
+    decode = jax.jit(model.decode_step)
+    sample = jax.jit(sample_tokens)
+    out = {}
+    for r in reqs:
+        sp, plen = r.sampling, len(r.prompt)
+
+        def draw(logits, pos):
+            return int(sample(logits[:, -1],
+                              jnp.asarray([sp.seed], jnp.uint32),
+                              jnp.asarray([pos], jnp.int32),
+                              jnp.asarray([sp.temperature], jnp.float32),
+                              jnp.asarray([sp.top_k], jnp.int32),
+                              jnp.asarray([sp.top_p], jnp.float32))[0])
+        caches = model.init_caches(None, 1, plen + r.max_new_tokens)
+        logits, caches = prefill(params, caches,
+                                 {"tokens": jnp.asarray([r.prompt])})
+        ids = [draw(logits, plen)]
+        for s in range(r.max_new_tokens - 1):
+            logits, caches = decode(
+                params, caches,
+                {"tokens": jnp.asarray([[ids[-1]]]),
+                 "positions": jnp.full((1,), plen + s, jnp.int32)})
+            ids.append(draw(logits, plen + 1 + s))
+        out[r.uid] = ids
+    return out
+
+
 @pytest.mark.parametrize("family", sorted(FAMILY_ARCHS))
 def test_fused_decode_bit_parity_all_families(family):
-    """fused_decode=True streams bit-identical to the unfused engine for
-    every servable family, at decode horizon N=1 and N=4, on mixed
-    greedy/sampled/filtered traffic with the sanitizer on. This is the bit
-    contract (not tolerance): the fused residual stream keeps every add at
-    the same graph position as the unfused stack, so even bf16 smoke
-    models must not flip a single draw."""
-    arch, model, params = _smoke_model(FAMILY_ARCHS[family])
-    reqs = _requests(arch)
-    e_ref, ref = _serve(model, params, reqs, decode_steps=1,
-                        fused_decode=False)
-    assert e_ref.fused_decode is False
-    for n in (1, 4):
-        e, toks = _serve(model, params, reqs, decode_steps=n,
-                         fused_decode=True)
-        assert e.fused_decode, e.fused_decode_off_reason
-        assert toks == ref, f"{family} fused decode diverged at N={n}"
+    """Every servable family, in float32, on mixed greedy/sampled/filtered
+    traffic with the sanitizer on: the engine's streams equal the static
+    engine's (full logits, unfused blocks, the shared sampler), and the
+    decode horizon N=4 emits exactly the N=1 streams."""
+    arch, model, params = _fp32_model(FAMILY_ARCHS[family])
+    # the static engine's SSD scan prefills a whole prompt in one call and
+    # takes lengths up to one scan chunk (16) or multiples of it
+    reqs = _requests(arch, max_prompt=16)
+    e1, toks = _serve(model, params, reqs, decode_steps=1)
+    assert e1.fused_decode and e1.fused_decode_off_reason is None
+    assert toks == _static_streams(model, params, reqs), \
+        f"{family} paged decode diverged from the static engine"
+    _, toks4 = _serve(model, params, reqs, decode_steps=4)
+    assert toks4 == toks, f"{family} decode diverged at N=4"
 
 
 def test_fused_decode_preemption_replay_parity():
-    """A forced preemption mid-stream under the fused multi-step engine must
-    replay token-identically vs an unpreempted unfused N=1 run: the forced
-    replay re-derives every PRNG key from the stream position, and the
-    fused head derives the same ``rs`` uniforms from the same keys."""
+    """A forced preemption mid-stream under the multi-step engine must
+    replay token-identically vs an unpreempted N=1 run: the forced replay
+    re-derives every PRNG key from the stream position, and the head
+    derives the same ``rs`` uniforms from the same keys."""
     arch, model, params = _smoke_model("llama3.2-3b")
     reqs = [dataclasses.replace(r, max_new_tokens=8)
             for r in _requests(arch, seed=29)]
-    _, ref = _serve(model, params, reqs, decode_steps=1, fused_decode=False)
+    _, ref = _serve(model, params, reqs, decode_steps=1)
     engine = ContinuousEngine(model, params, num_slots=3, num_pages=64,
                               page_size=4, max_seq_len=64, prefix_cache=False,
-                              sanitize=True, decode_steps=4,
-                              fused_decode=True)
+                              sanitize=True, decode_steps=4)
     sched = engine.scheduler
     orig = sched.ensure_capacity
     fired = []
@@ -348,7 +394,7 @@ def test_fused_decode_preemption_replay_parity():
     res = engine.run(list(reqs))
     assert fired == [1], "forced preemption must actually fire"
     assert {u: r["tokens"] for u, r in res.items()} == ref, \
-        "preempted fused multi-step stream diverged from unfused N=1"
+        "preempted multi-step stream diverged from N=1"
 
 
 # ------------------------------------------------------------------ tp = 2 ----
@@ -366,12 +412,7 @@ def _run_subprocess(body: str):
     return r.stdout
 
 
-def test_tp2_fused_decode_parity_and_shard_gate():
-    """tp=2 with fused decode streams token-identical to the unfused tp=1
-    engine (stats combine across shards, never logits), and a vocab whose
-    per-shard slice misses the 128-wide reduction tile falls back with the
-    recorded off-reason instead of serving wrong."""
-    out = _run_subprocess(r"""
+_TP_PRELUDE = r"""
 import dataclasses
 import jax, numpy as np
 from repro.configs import smoke_config
@@ -379,93 +420,89 @@ from repro.models import build_model
 from repro.serving import ContinuousEngine, Request
 from repro.serving.sampling import SamplingParams
 
-arch = dataclasses.replace(smoke_config("llama3.2-3b"), num_kv_heads=4,
-                           dtype="float32", param_dtype="float32")
-model = build_model(arch)
-params = model.init(jax.random.key(0))
-rng = np.random.default_rng(7)
-reqs = [Request(uid=i,
-                prompt=list(map(int, rng.integers(5, arch.vocab_size, 10))),
-                max_new_tokens=6,
-                sampling=(SamplingParams() if i % 2 == 0 else
-                          SamplingParams(temperature=0.8, top_k=12,
-                                         top_p=0.9, seed=100 + i)))
-        for i in range(4)]
+def requests(arch):
+    rng = np.random.default_rng(7)
+    return [Request(uid=i,
+                    prompt=list(map(int, rng.integers(5, arch.vocab_size,
+                                                      10))),
+                    max_new_tokens=6,
+                    sampling=(SamplingParams() if i % 2 == 0 else
+                              SamplingParams(temperature=0.8, top_k=12,
+                                             top_p=0.9, seed=100 + i)))
+            for i in range(4)]
 
-def serve(**kw):
+def serve(arch, **kw):
+    model = build_model(arch)
+    params = model.init(jax.random.key(0))
     eng = ContinuousEngine(model, params, num_slots=3, num_pages=64,
                            page_size=8, max_seq_len=64, prefix_cache=False,
                            **kw)
-    res = eng.run(list(reqs))
+    res = eng.run(requests(arch))
     return eng, {u: r["tokens"] for u, r in res.items()}
 
-_, ref = serve(fused_decode=False)
-for tp in (1, 2):
-    eng, toks = serve(tp=tp, fused_decode=True)
-    assert eng.fused_decode, (tp, eng.fused_decode_off_reason)
-    assert toks == ref, (tp, toks, ref)
+arch = dataclasses.replace(smoke_config("llama3.2-3b"), num_kv_heads=4,
+                           dtype="float32", param_dtype="float32")
+"""
 
-# shard-width gate: pad_vocab(384) = 384, 384/2 = 192 is not a whole
-# number of 128-wide reduction tiles -> fused decode off, reason recorded
-arch2 = dataclasses.replace(arch, vocab_size=384)
-model2 = build_model(arch2)
-params2 = model2.init(jax.random.key(0))
-eng2 = ContinuousEngine(model2, params2, num_slots=2, num_pages=32,
-                        page_size=8, max_seq_len=32, prefix_cache=False,
-                        tp=2, fused_decode=True)
-assert not eng2.fused_decode
-assert "reduction tile" in eng2.fused_decode_off_reason
+
+def test_tp2_fused_decode_parity_and_shard_gate():
+    """tp=2 streams token-identical to the tp=1 engine (the head's
+    statistics combine across shards, never logits), with the streaming
+    head's shard gate open at both: 512 / 2 is a whole number of 128-wide
+    reduction tiles."""
+    out = _run_subprocess(_TP_PRELUDE + r"""
+eng1, ref = serve(arch, tp=1)
+eng2, toks = serve(arch, tp=2)
+for eng in (eng1, eng2):
+    assert eng.fused_decode and eng.fused_decode_off_reason is None
+assert toks == ref, (toks, ref)
 print("TP-FUSED-OK")
 """)
     assert "TP-FUSED-OK" in out
 
 
+def test_tp2_misaligned_vocab_serves_materializing_head():
+    """pad_vocab(384) = 384, and 384 / 2 = 192 is not a whole number of
+    128-wide reduction tiles: the tp=2 engine records why the streaming
+    head cannot serve (``fused_decode`` False), serves through the
+    materializing head, and still emits the tp=1 engine's streams."""
+    out = _run_subprocess(_TP_PRELUDE + r"""
+arch = dataclasses.replace(arch, vocab_size=384)
+eng1, ref = serve(arch, tp=1)
+assert eng1.fused_decode
+eng2, toks = serve(arch, tp=2)
+assert not eng2.fused_decode
+assert "reduction tile" in eng2.fused_decode_off_reason
+assert toks == ref, (toks, ref)
+print("TP-MATERIALIZED-OK")
+""")
+    assert "TP-MATERIALIZED-OK" in out
+
+
 # --------------------------------------------------------- construction gates --
 
-def test_fused_decode_off_reasons():
-    """Post-norm stacks and MLM-transform heads must fall back at
-    construction with a recorded reason; an explicit fused_decode=False is
-    a choice, not a fallback, so no reason is recorded."""
-    arch, model, params = _smoke_model("llama3.2-3b")
-    kw = dict(num_slots=2, num_pages=32, page_size=4, max_seq_len=32,
-              prefix_cache=False)
-
-    post = dataclasses.replace(arch, post_norm=True)
-    mpost = build_model(post)
-    e = ContinuousEngine(mpost, mpost.init(jax.random.key(0)), **kw)
-    assert not e.fused_decode
-    assert "pre-norm" in e.fused_decode_off_reason
-
-    mlm = dataclasses.replace(arch, mlm_transform=True)
-    mmlm = build_model(mlm)
-    e = ContinuousEngine(mmlm, mmlm.init(jax.random.key(0)), **kw)
-    assert not e.fused_decode
-    assert "MLM" in e.fused_decode_off_reason
-
-    e = ContinuousEngine(model, params, fused_decode=False, **kw)
-    assert not e.fused_decode and e.fused_decode_off_reason is None
-
-    e = ContinuousEngine(model, params, fused_decode=True, **kw)
-    assert e.fused_decode and e.fused_decode_off_reason is None
+def _abstract(arch):
+    model = build_model(arch)
+    return model, jax.eval_shape(model.init, jax.random.key(0))
 
 
-def test_fused_decode_env_default(monkeypatch):
-    """REPRO_FUSED_DECODE drives the engine default (unset = on); an
-    explicit ctor flag beats the env."""
-    monkeypatch.delenv("REPRO_FUSED_DECODE", raising=False)
-    assert fused_decode_enabled() is True
-    monkeypatch.setenv("REPRO_FUSED_DECODE", "0")
-    assert fused_decode_enabled() is False
+_KW = dict(num_slots=2, num_pages=32, page_size=4, max_seq_len=32,
+           prefix_cache=False)
 
-    arch, model, params = _smoke_model("llama3.2-3b")
-    kw = dict(num_slots=2, num_pages=32, page_size=4, max_seq_len=32,
-              prefix_cache=False)
-    e = ContinuousEngine(model, params, **kw)
-    assert not e.fused_decode and e.fused_decode_off_reason is None
-    e = ContinuousEngine(model, params, fused_decode=True, **kw)
-    assert e.fused_decode
-    monkeypatch.setenv("REPRO_FUSED_DECODE", "1")
-    assert fused_decode_enabled() is True
+
+def test_engine_refuses_post_norm_stack():
+    """The paged stack defers every residual add into the next pre-norm,
+    which a post-norm block does not have: the engine refuses the stack at
+    construction instead of serving another path."""
+    post = dataclasses.replace(smoke_config("llama3.2-3b"), post_norm=True)
+    with pytest.raises(AssertionError, match="pre-norm"):
+        ContinuousEngine(*_abstract(post), **_KW)
+
+
+def test_engine_refuses_mlm_transform_head():
+    mlm = dataclasses.replace(smoke_config("llama3.2-3b"), mlm_transform=True)
+    with pytest.raises(AssertionError, match="MLM"):
+        ContinuousEngine(*_abstract(mlm), **_KW)
 
 
 def test_tp_fusable_predicate():
@@ -480,14 +517,12 @@ def test_tp_fusable_predicate():
 
 # --------------------------------------------------------------- serve CLI ----
 
-def test_serve_cli_fused_decode_flag(capsys):
+def test_serve_cli_reports_the_head():
+    """The serve driver returns the engine's head facts, which the chip
+    smoke check prints."""
     from repro.launch import serve
-    with pytest.raises(SystemExit):
-        serve.main(["--arch", "llama3.2-3b", "--smoke", "--engine", "static",
-                    "--fused-decode"])
-    assert "requires --engine continuous" in capsys.readouterr().err
     out = serve.main(["--arch", "llama3.2-3b", "--smoke", "--engine",
                       "continuous", "--batch", "2", "--prompt-len", "8",
-                      "--gen-len", "3", "--no-fused-decode"])
-    assert out["fused_decode"] is False
+                      "--gen-len", "3"])
+    assert out["fused_decode"] is True
     assert out["fused_decode_off_reason"] is None

@@ -8,12 +8,13 @@ Three layers of parity, all bit-exact:
    ``top_k >= V``, pre-masked ``-inf`` entries, all-``-inf`` rows,
    float32-tight nucleus boundaries, signed zeros): hypothesis sweep plus a
    pinned no-hypothesis instance per edge case.
-2. sampler level — ``sample_tokens(..., fused=True)`` vs ``fused=False``
-   draw identical tokens, and both agree with the retired twin-sort
-   implementation (kept verbatim below as ``_legacy_filter``) away from its
-   float32 cumsum boundaries.
-3. engine level — fused and reference continuous engines serve identical
-   sampled token streams, and each compiles its own named filter variant.
+2. sampler level — ``sample_tokens`` draws the tokens that the sort-based
+   oracle filter plus the same canonical draw (``_oracle_tokens``) picks,
+   and the filter agrees with the retired twin-sort implementation (kept
+   verbatim below as ``_legacy_filter``) away from its float32 cumsum
+   boundaries.
+3. engine level — the continuous engine serves the same sampled token
+   streams with its filter and with the oracle swapped in for it.
 
 Conventions mirror ``test_sampling.py`` (optional hypothesis with a pinned
 fallback, the fp32 smoke llama fixture, ``_mixed_requests``-style traffic).
@@ -31,6 +32,7 @@ except ImportError:  # optional dev dep (requirements-dev.txt)
     given = settings = st = None
 
 from repro.configs import smoke_config
+from repro.kernels.fused_lm_head import ref as head_ref
 from repro.kernels.fused_sampling import kernel, ops, ref
 from repro.models import build_model
 from repro.serving import (ContinuousEngine, Request, SamplingParams,
@@ -225,27 +227,59 @@ def _arrs(rows, seed=0, pos=0, temp=1.0, top_k=0, top_p=1.0):
             vec(top_p, np.float32))
 
 
+def _oracle_tokens(logits, seeds, positions, temps, top_k, top_p):
+    """``sample_tokens`` rebuilt from its oracles: temperature scaling, the
+    sort-based reference filter, then the canonical inverse-CDF draw;
+    temperature-0 rows take the raw argmax."""
+    temps = temps.astype(jnp.float32)
+    lg = logits.astype(jnp.float32) / jnp.where(temps > 0, temps, 1.0)[:, None]
+    lg = ref.filter_logits_ref(lg, top_k, top_p)
+    drawn = head_ref.draw_tokens(lg, head_ref.row_uniforms(seeds, positions))
+    return jnp.where(temps > 0, drawn,
+                     jnp.argmax(logits, axis=-1).astype(jnp.int32))
+
+
 def test_sample_tokens_fused_flag_is_token_invisible():
-    """Identical draws from the fused and reference filters across seeds,
-    positions, and filter settings — the flag changes speed, never tokens."""
+    """``sample_tokens`` draws what the oracle filter plus the same draw
+    picks, across seeds, positions, and filter settings: the streaming
+    filter changes speed, never tokens."""
     rng = np.random.default_rng(8)
     logits = jnp.asarray(rng.normal(size=(6, V)).astype(np.float32))
     for pos in range(12):
         for tk, tp in ((40, 0.95), (8, 1.0), (0, 0.7), (1, 0.5)):
             args = _arrs(6, seed=range(6), pos=pos, temp=0.8, top_k=tk,
                          top_p=tp)
-            a = sample_tokens(logits, *args, fused=True)
-            b = sample_tokens(logits, *args, fused=False)
+            a = sample_tokens(logits, *args)
+            b = _oracle_tokens(logits, *args)
             assert (np.asarray(a) == np.asarray(b)).all(), (pos, tk, tp)
+
+
+@pytest.mark.parametrize("top_p", [0.5, 1.0])
+@pytest.mark.parametrize("top_k", [0, 1, V])
+def test_sample_tokens_matches_reference_filter_corners(top_k, top_p):
+    """The filter's corners — top_k off, 1 and the whole vocab, against a
+    binding and an off nucleus — with greedy and sampled rows co-batched:
+    ``sample_tokens`` equals the oracle filter plus the same draw at every
+    position."""
+    rng = np.random.default_rng(100 + top_k)
+    logits_np = rng.normal(size=(4, V)).astype(np.float32) * 3.0
+    logits = jnp.asarray(logits_np)
+    for pos in range(8):
+        args = _arrs(4, seed=[3, 4, 5, 6], pos=pos,
+                     temp=[0.0, 0.6, 1.0, 1.7], top_k=top_k, top_p=top_p)
+        a = np.asarray(sample_tokens(logits, *args))
+        b = np.asarray(_oracle_tokens(logits, *args))
+        assert (a == b).all(), (pos, a, b)
+        assert a[0] == np.argmax(logits_np[0])          # the greedy row
+        if top_k == 1:
+            assert (a == np.argmax(logits_np, -1)).all()
 
 
 def test_sample_tokens_fused_temp_zero_is_bitwise_argmax():
     rng = np.random.default_rng(9)
     logits = jnp.asarray(rng.normal(size=(5, V)).astype(np.float32))
-    for fused in (True, False):
-        toks = sample_tokens(logits, *_arrs(5, temp=0.0, top_k=40,
-                                            top_p=0.9), fused=fused)
-        assert (np.asarray(toks) == np.argmax(np.asarray(logits), -1)).all()
+    toks = sample_tokens(logits, *_arrs(5, temp=0.0, top_k=40, top_p=0.9))
+    assert (np.asarray(toks) == np.argmax(np.asarray(logits), -1)).all()
 
 
 def test_sample_tokens_fused_restricts_support():
@@ -258,7 +292,7 @@ def test_sample_tokens_fused_restricts_support():
     drawn = set()
     for pos in range(40):
         toks = sample_tokens(logits, *_arrs(1, seed=9, pos=pos, temp=1.5,
-                                            top_k=5), fused=True)
+                                            top_k=5))
         drawn.add(int(toks[0]))
     assert drawn <= top and len(drawn) > 1
 
@@ -287,33 +321,23 @@ def _sampled_requests(arch, rng, n=4):
     return reqs
 
 
-def test_fused_and_reference_engines_serve_identical_streams(fp32_llama):
+def test_fused_and_reference_engines_serve_identical_streams(fp32_llama,
+                                                           monkeypatch):
+    """The engine serves the same sampled streams with its streaming filter
+    and with the sort-based oracle swapped in for it, and compiles one
+    filtered decode variant either way."""
     arch, model, params = fp32_llama
     rng = np.random.default_rng(51)
     reqs = _sampled_requests(arch, rng)
     kw = dict(num_slots=4, num_pages=48, page_size=8, max_seq_len=64,
               prefix_cache=False)
     tokens = {}
-    for fused in (True, False):
-        engine = ContinuousEngine(model, params, fused_sampling=fused, **kw)
+    for oracle in (False, True):
+        if oracle:
+            monkeypatch.setattr(ops, "filter_logits", ref.filter_logits_ref)
+        engine = ContinuousEngine(model, params, **kw)
         res = engine.run([dataclasses.replace(r) for r in reqs])
-        tokens[fused] = [res[i]["tokens"] for i in range(len(reqs))]
-        # the engine compiled the filter variant it was asked for, and the
-        # variant key names the implementation
-        fd = engine.fused_decode
-        assert ("decode", True, True, fused, fd) in engine._jit_cache
-        assert ("decode", True, True, not fused, fd) not in engine._jit_cache
-    assert tokens[True] == tokens[False], \
-        "fused filter diverged from the sort-based reference in serving"
-
-
-def test_env_toggle_selects_reference_filter(fp32_llama, monkeypatch):
-    arch, model, params = fp32_llama
-    monkeypatch.setenv("REPRO_FUSED_SAMPLING", "0")
-    engine = ContinuousEngine(model, params, num_slots=2, num_pages=16,
-                              page_size=8, max_seq_len=32)
-    assert engine.fused_sampling is False
-    monkeypatch.setenv("REPRO_FUSED_SAMPLING", "1")
-    engine = ContinuousEngine(model, params, num_slots=2, num_pages=16,
-                              page_size=8, max_seq_len=32)
-    assert engine.fused_sampling is True
+        tokens[oracle] = [res[i]["tokens"] for i in range(len(reqs))]
+        assert ("decode", True, True) in engine._jit_cache
+    assert tokens[False] == tokens[True], \
+        "streaming filter diverged from the sort-based oracle in serving"

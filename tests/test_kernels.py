@@ -50,8 +50,8 @@ def test_decode_residual_norm_kernel_bitwise(shape, dtype, kind):
     """The decode-path residual+norm fusion is a BIT-exactness contract,
     not a tolerance one: the kernel adds in the model dtype and duplicates
     ``_apply_norm`` op-for-op, so jit'd kernel (interpret) and jit'd ref
-    must agree exactly — this is what lets the engine's ``fused_decode``
-    flag promise token-identical streams."""
+    must agree exactly — this is what lets the engine's paged stack
+    promise the static engine's token streams."""
     y = jax.random.normal(jax.random.key(0), shape, dtype)
     x = jax.random.normal(jax.random.key(1), shape, dtype)
     scale = jnp.linspace(0.8, 1.2, shape[-1]).astype(jnp.float32)

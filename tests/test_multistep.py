@@ -203,12 +203,12 @@ def test_dispatch_accounting_and_exit_reasons():
 
 def test_recompile_audit_covers_multistep_variants():
     """decode_steps=4 re-keys every decode variant on the horizon (key arity
-    6 — the trailing elements are the fused-decode flag then N) and the jit
-    cache still closes: steps 2..N of the audit trace add zero traces."""
+    4 — the trailing element is N) and the jit cache still closes: steps
+    2..N of the audit trace add zero traces."""
     report = audit_family("dense", decode_steps=4)
     decode_keys = [k for k in report.variants if k and k[0] == "decode"]
     assert decode_keys, "audit trace exercised no decode variant"
-    assert all(len(k) == 6 and k[-1] == 4 for k in decode_keys), decode_keys
+    assert all(len(k) == 4 and k[-1] == 4 for k in decode_keys), decode_keys
     # prefill variants must not be re-keyed by the decode horizon: their key
     # set is identical to what the same trace produces at N=1
     ref = audit_family("dense", decode_steps=1)

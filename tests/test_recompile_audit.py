@@ -42,46 +42,23 @@ def test_dense_cache_closed_and_fully_exercised():
     # engine can build: both prefill finalities, all decode sampling
     # variants, and the CoW tail copy
     assert kinds == {"decode", "prefill", "copy"}
-    fd = True   # fused decode is the default where supported
-    assert ("prefill", False, False, False, False, fd) in report.variants, \
+    assert ("prefill", False, False, False) in report.variants, \
         "non-final prefill chunk variant never exercised"
-    # the filtered variants name their filter implementation (fused by
-    # default); unfiltered variants pin the fused element False so they stay
-    # shared between fused and reference engines. Every key's trailing
-    # element is the engine's fused-decode flag.
-    assert ("decode", True, True, True, fd) in report.variants
-    assert ("prefill", True, True, True, True, fd) in report.variants
-    assert all(len(sigs) == 1 for sigs in report.signatures.values())
-
-
-def test_dense_reference_sampler_cache_closed():
-    """fused_sampling=False audits the sort-based reference filter: same
-    variant census, with the fused element of the filtered keys False."""
-    report = audit_family("dense", fused_sampling=False)
-    fd = True
-    assert ("decode", True, True, False, fd) in report.variants
-    assert ("prefill", True, True, True, False, fd) in report.variants
-    assert ("decode", True, True, True, fd) not in report.variants
-    assert all(len(sigs) == 1 for sigs in report.signatures.values())
-
-
-def test_fused_decode_off_cache_closed():
-    """fused_decode=False audits the reference decode/prefill variants: the
-    same census with the trailing fd element pinned False — the unfused
-    half of the bit-parity contract must keep a closed cache too."""
-    report = audit_family("dense", fused_decode=False)
-    assert ("decode", True, True, True, False) in report.variants
-    assert ("prefill", True, True, True, True, False) in report.variants
-    assert not any(k[-1] is True for k in report.variants if k[0] != "copy")
+    # the keys hold the step kind and the static sampling flags, nothing
+    # else: one decode path, one filter
+    assert ("decode", True, True) in report.variants
+    assert ("prefill", True, True, True) in report.variants
+    assert {len(k) for k in report.variants if k[0] == "decode"} == {3}
+    assert {len(k) for k in report.variants if k[0] == "prefill"} == {4}
     assert all(len(sigs) == 1 for sigs in report.signatures.values())
 
 
 def test_fused_decode_multistep_cache_closed_all_families():
-    """Every servable family: the fused-decode multi-step loop's
-    horizon-keyed variants (('decode', ..., fd, N)) stay closed."""
+    """Every servable family: the multi-step loop's horizon-keyed variants
+    (('decode', sampled, filtered, N)) stay closed."""
     for family in sorted(FAMILY_ARCHS):
-        report = audit_family(family, decode_steps=4, fused_decode=True)
-        assert ("decode", True, True, True, True, 4) in report.variants, \
+        report = audit_family(family, decode_steps=4)
+        assert ("decode", True, True, 4) in report.variants, \
             (family, report.variants)
         assert all(len(s) == 1 for s in report.signatures.values())
 
@@ -125,11 +102,9 @@ def test_planted_shape_retrace_is_detected():
         report.check()
     # and the census pinpoints the culprit: the final-prefill variant holds
     # two distinct signatures, decode still one
-    fd = engine.fused_decode
-    final_prefill = engine.signatures[
-        ("prefill", True, False, False, False, fd)]
+    final_prefill = engine.signatures[("prefill", True, False, False)]
     assert len(final_prefill) == 2
-    assert len(engine.signatures[("decode", False, False, False, fd)]) == 1
+    assert len(engine.signatures[("decode", False, False)]) == 1
 
 
 def test_empty_trace_is_an_audit_failure():
@@ -146,10 +121,9 @@ def test_tp2_caches_closed_over_device_mesh():
     out = _run_subprocess(r"""
 from repro.analysis.recompile import audit_family
 for family in ("dense", "hybrid"):
-    for fd in (True, False):
-        report = audit_family(family, tp=2, fused_decode=fd)
-        print("closed", family, fd, len(report.signatures))
+    report = audit_family(family, tp=2)
+    print("closed", family, len(report.signatures))
 print("AUDIT_TP2_OK")
 """)
     assert "AUDIT_TP2_OK" in out
-    assert out.count("closed") == 4
+    assert out.count("closed") == 2

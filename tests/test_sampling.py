@@ -296,11 +296,14 @@ def test_replay_exact_preemption_mid_decode(fp32_llama):
                      prefix_cache=False, seed=41)
 
 
-def test_replay_parity_fused_vs_reference_sampler(fp32_llama):
-    """The filter implementation (fused bisection kernel vs sort-based
-    reference) and a forced mid-decode preemption are BOTH token-invisible:
-    an unpreempted fused-sampler run and a preempted+replayed
-    reference-sampler run of the same requests emit identical streams."""
+def test_replay_parity_fused_vs_reference_sampler(fp32_llama, monkeypatch):
+    """The filter implementation (the engine's streaming bisection filter
+    vs the sort-based oracle swapped in for it) and a forced mid-decode
+    preemption are BOTH token-invisible: an unpreempted run and a
+    preempted+replayed run through the oracle filter of the same requests
+    emit identical streams."""
+    from repro.kernels.fused_sampling import ops as filt_ops
+    from repro.kernels.fused_sampling import ref as filt_ref
     arch, model, params = fp32_llama
     rng = np.random.default_rng(53)
     prompts, gens, sps = _mixed_requests(arch, rng, share_prefix=True)
@@ -309,20 +312,23 @@ def test_replay_parity_fused_vs_reference_sampler(fp32_llama):
               prefix_cache=False)
     reqs = [Request(uid=i, prompt=prompts[i], max_new_tokens=gens[i],
                     sampling=sps[i]) for i in range(len(prompts))]
-    _, fused_clean = _run_engine(model, params, prompts, gens, sps,
-                                 fused_sampling=True, **kw)
+    _, fused_clean = _run_engine(model, params, prompts, gens, sps, **kw)
+    oracle_calls = []
+
+    def oracle(lg, top_k, top_p):
+        oracle_calls.append(lg.shape)
+        return filt_ref.filter_logits_ref(lg, top_k, top_p)
+    monkeypatch.setattr(filt_ops, "filter_logits", oracle)
     engine, fired = _forced_preempt_engine(
-        model, params, uid=1, when=lambda seq: len(seq.generated) >= 2,
-        fused_sampling=False, **kw)
+        model, params, uid=1, when=lambda seq: len(seq.generated) >= 2, **kw)
     res = engine.run(reqs)
     assert fired == [1], "forced preemption must actually fire"
     ref_forced = [res[i]["tokens"] for i in range(len(prompts))]
     assert ref_forced == fused_clean, \
         "reference-sampler replay diverged from the fused engine"
-    # the reference engine really traced the ref filter variant
-    fd = engine.fused_decode
-    assert ("decode", True, True, False, fd) in engine._jit_cache
-    assert ("decode", True, True, True, fd) not in engine._jit_cache
+    # the replayed engine really traced the oracle into its filtered step
+    assert oracle_calls
+    assert ("decode", True, True) in engine._jit_cache
 
 
 def test_replay_exact_preemption_mid_prefill(fp32_llama):

@@ -116,14 +116,16 @@ print("TP_PARITY_OK")
 
 
 def test_tp2_fused_vs_reference_sampler_parity():
-    """The fused filter kernel and the sort-based reference must emit
-    bit-identical sampled streams at tp=2 (logits are replicated post-psum,
-    so the filter sees the same rows on every shard): fused tp=2 == ref
-    tp=2 == fused tp=1."""
+    """The streaming filter and the sort-based oracle swapped in for it
+    must emit bit-identical sampled streams at tp=2 (logits are replicated
+    post-psum, so the filter sees the same rows on every shard): tp=2 ==
+    oracle tp=2 == tp=1."""
     out = _run_subprocess(r"""
 import dataclasses
 import jax, numpy as np
 from repro.configs import smoke_config
+from repro.kernels.fused_sampling import ops as filt_ops
+from repro.kernels.fused_sampling import ref as filt_ref
 from repro.models import build_model
 from repro.serving import ContinuousEngine, Request
 from repro.serving.sampling import SamplingParams
@@ -142,16 +144,16 @@ sps = [SamplingParams(temperature=0.9, top_k=16 if i % 2 else 0,
 reqs = [Request(uid=i, prompt=prompts[i], max_new_tokens=gens[i],
                 sampling=sps[i]) for i in range(4)]
 
-def serve(tp, fused):
+def serve(tp):
     eng = ContinuousEngine(model, params, num_slots=4, num_pages=64,
-                           page_size=8, max_seq_len=64, tp=tp,
-                           fused_sampling=fused)
+                           page_size=8, max_seq_len=64, tp=tp)
     res = eng.run(list(reqs))
     return [res[i]["tokens"] for i in range(4)]
 
-ref = serve(1, True)
-assert serve(2, True) == ref, "fused tp=2 diverged from fused tp=1"
-assert serve(2, False) == ref, "reference sampler tp=2 diverged"
+ref = serve(1)
+assert serve(2) == ref, "tp=2 diverged from tp=1"
+filt_ops.filter_logits = filt_ref.filter_logits_ref
+assert serve(2) == ref, "tp=2 through the oracle filter diverged"
 print("TP2_SAMPLER_PARITY_OK")
 """)
     assert "TP2_SAMPLER_PARITY_OK" in out

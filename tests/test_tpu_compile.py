@@ -275,7 +275,8 @@ def test_decode_step_moves_no_pool(config, horizon, one_chip,
     ``dynamic-update-slice`` instruction, fused or not, has a whole layer
     stack of pools or one layer's pool as its shape. The step's rows are
     scattered into the pools in place, and decode attention reads them
-    where they lie."""
+    where they lie. The step is the engine's one decode path: the fused
+    residual+norm kernel and the streaming LM head are in it."""
     text, pools = _decode_step_text(config, one_chip, monkeypatch,
                                     horizon=horizon, num_pages=CELL_POOL,
                                     donate=True)
@@ -291,7 +292,9 @@ def test_decode_step_moves_no_pool(config, horizon, one_chip,
              if op in moves and any(sh in out.replace(" ", "")
                                     for sh in shapes)]
     assert not found, found
-    assert "paged_decode_attention" in text
+    for kernel in ("paged_decode_attention", "decode_residual_norm",
+                   "fused_lm_head"):
+        assert kernel in text, kernel
 
 
 def test_moe_decode_step_names_its_kernels(one_chip, no_persistent_cache,

@@ -8,17 +8,13 @@ metric:
 - ``rates.<rate>.continuous.tok_s``      (throughput: lower is a regression)
 - ``shared_prefix.{off,on}.tok_s``
 - ``shared_prefix.{off,on}.ttft_ms``     (mean TTFT: higher is a regression)
-- ``sampled.{greedy,sampled,sampled_ref}.tok_s``
-- ``sampled.sampler_overhead_pct``       (fused sampler tax over greedy, in
+- ``sampled.{greedy,sampled}.tok_s``
+- ``sampled.sampler_overhead_pct``       (sampler tax over greedy, in
                                           percentage points: current may
                                           exceed baseline by at most
                                           100 * tolerance points — a
                                           relative gate on a near-zero
                                           percentage would flap on noise)
-- ``sampled.diverged_streams``           (fused vs reference filter token
-                                          mismatches: must be exactly 0 —
-                                          divergence is a determinism bug,
-                                          not a perf number)
 - ``families.<arch>.tok_s``              (hybrid/SSM/MoE serving sweep)
 - ``multistep.n<N>.tok_s``               (multi-step compiled decode loop at
                                           decode_steps N in {1,4,16})
@@ -33,18 +29,6 @@ metric:
 - ``multistep.diverged_streams``         (N>1 vs N=1 token mismatches: must
                                           be exactly 0 — determinism bug,
                                           not a perf number)
-- ``decode_fusion.{unfused,fused,fused_n4}.tok_s``
-                                         (decode residual-stream fusion)
-- ``decode_fusion.speedup_vs_unfused``   (fused over unfused throughput in
-                                          the SAME artifact: a noise floor —
-                                          on CPU the fused graph is
-                                          op-identical, so ~0.8-1.0x is
-                                          healthy and only a real cliff
-                                          fails)
-- ``decode_fusion.diverged_streams``     (fused vs unfused token mismatches:
-                                          must be exactly 0 — the fusion's
-                                          whole contract is bit-identical
-                                          streams)
 - ``recompiles.excess``                  (jit cache misses after warmup:
                                           must be exactly 0 — a retrace is
                                           a correctness bug, not a perf
@@ -92,8 +76,7 @@ Metric = Tuple[str, float, str]
 
 # sections the BASELINE must carry: absence means it predates the coverage
 # (and would silently un-gate it) — regenerate and commit a fresh artifact
-REQUIRED_SECTIONS = ("families", "recompiles", "sampled", "multistep",
-                     "decode_fusion")
+REQUIRED_SECTIONS = ("families", "recompiles", "sampled", "multistep")
 
 
 def iter_metrics(baseline: dict) -> Iterator[Metric]:
@@ -107,7 +90,7 @@ def iter_metrics(baseline: dict) -> Iterator[Metric]:
         if d:
             yield f"shared_prefix.{tag}.tok_s", d["tok_s"], "higher"
             yield f"shared_prefix.{tag}.ttft_ms", d["ttft_ms"], "lower"
-    for tag in ("greedy", "sampled", "sampled_ref"):
+    for tag in ("greedy", "sampled"):
         d = baseline.get("sampled", {}).get(tag)
         if d:
             yield f"sampled.{tag}.tok_s", d["tok_s"], "higher"
@@ -115,9 +98,6 @@ def iter_metrics(baseline: dict) -> Iterator[Metric]:
     if "sampler_overhead_pct" in sampled:
         yield ("sampled.sampler_overhead_pct",
                sampled["sampler_overhead_pct"], "lower_points")
-    if "diverged_streams" in sampled:
-        yield ("sampled.diverged_streams",
-               sampled["diverged_streams"], "zero")
     for arch, d in baseline.get("families", {}).items():
         if "tok_s" in d:
             yield f"families.{arch}.tok_s", d["tok_s"], "higher"
@@ -135,17 +115,6 @@ def iter_metrics(baseline: dict) -> Iterator[Metric]:
     if "diverged_streams" in multistep:
         yield ("multistep.diverged_streams",
                multistep["diverged_streams"], "zero")
-    fusion = baseline.get("decode_fusion", {})
-    for tag in ("unfused", "fused", "fused_n4"):
-        d = fusion.get(tag)
-        if d and "tok_s" in d:
-            yield f"decode_fusion.{tag}.tok_s", d["tok_s"], "higher"
-    if "speedup_vs_unfused" in fusion:
-        yield ("decode_fusion.speedup_vs_unfused",
-               fusion["speedup_vs_unfused"], "higher")
-    if "diverged_streams" in fusion:
-        yield ("decode_fusion.diverged_streams",
-               fusion["diverged_streams"], "zero")
     if "recompiles" in baseline:
         yield ("recompiles.excess",
                baseline["recompiles"].get("excess", 0), "zero")
